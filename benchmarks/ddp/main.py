@@ -12,7 +12,10 @@ Run (single host, N processes):
 
 Each worker process coordinates through a FileStore; on a real multi-host
 pod, run one process per host with jax.distributed initialized instead and
-drop --nprocs.
+drop --nprocs. The N workers of one host run on the CPU platform (a chip
+belongs to one process at a time, so N processes cannot share it); the
+result names the platform the workers reported, so a host-staging number
+is never read as a device one.
 
 Aggregate throughput scales with the number of *independent storage
 channels*: on a parallel filesystem or object store (the reference used
@@ -41,9 +44,15 @@ def _worker(
     incremental_frac=None,
 ):
     # snap_path may be any storage URL (fs path, memory://..., gs://...).
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    # N workers on one host cannot share a chip: pinned to CPU, and the
+    # platform they actually got is part of the result.
+    os.environ["JAX_PLATFORMS"] = "cpu"
     import jax
     import jax.numpy as jnp
+
+    from torchsnapshot_tpu.utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache(REPO_ROOT)
 
     from torchsnapshot_tpu import Snapshot
     from torchsnapshot_tpu.coord import FileStore, NoOpCoordinator, StoreCoordinator
@@ -115,7 +124,14 @@ def _worker(
             and not k[len(prefix) :].startswith(".snapshot")
         )
     out_queue.put(
-        (rank, elapsed, model.total_bytes(), rank_bytes, inc_elapsed)
+        (
+            rank,
+            elapsed,
+            model.total_bytes(),
+            rank_bytes,
+            inc_elapsed,
+            jax.default_backend(),
+        )
     )
 
 
@@ -149,15 +165,18 @@ def run(
         if p.exitcode != 0:
             raise RuntimeError(f"worker failed with exit code {p.exitcode}")
     results = [q.get(timeout=10) for _ in range(nprocs)]
-    elapsed = next(e for r, e, _, _, _ in results if r == 0)
+    elapsed = next(e for r, e, *_ in results if r == 0)
     nbytes = results[0][2]
-    per_rank = {r: b for r, _, _, b, _ in results if b is not None}
+    per_rank = {r: b for r, _, _, b, *_ in results if b is not None}
     out = {
         "nprocs": nprocs,
+        "platform": "+".join(sorted({res[5] for res in results})),
         "seconds": round(elapsed, 2),
         "GBps": round(nbytes / 1024**3 / elapsed, 3),
     }
-    inc_times = [i for r, _, _, _, i in results if r == 0 and i is not None]
+    inc_times = [
+        i for r, _, _, _, i, _ in results if r == 0 and i is not None
+    ]
     if inc_times:
         out["incremental_seconds"] = round(inc_times[0], 2)
         out["incremental_speedup"] = round(
@@ -224,6 +243,7 @@ def main() -> None:
                     "metric": "ddp_replicated_snapshot_speedup",
                     "value": round(speedup, 2),
                     "unit": f"x ({args.nprocs} procs vs 1)",
+                    "platform": results[-1]["platform"],
                     "runs": results,
                 }
             )

@@ -31,7 +31,8 @@ import sys
 import tempfile
 import time
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, _REPO_ROOT)
 
 _N_PARAMS = 24
 
@@ -43,6 +44,8 @@ def _total_bytes() -> int:
 
 
 def _worker_replicated(rank, nprocs, store_path, snap_path, out_dir):
+    # N workers on one host can never share a chip: pinned to CPU.
+    os.environ["JAX_PLATFORMS"] = "cpu"
     import numpy as np
 
     from torchsnapshot_tpu import Snapshot
@@ -80,6 +83,9 @@ def _worker_sharded(rank, nprocs, store_path, snap_path, out_dir, port):
 
     import jax
 
+    from torchsnapshot_tpu.utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache(_REPO_ROOT)
     jax.distributed.initialize(
         coordinator_address=f"localhost:{port}",
         num_processes=nprocs,

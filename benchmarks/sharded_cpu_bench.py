@@ -21,7 +21,8 @@ import sys
 import tempfile
 import time
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, _REPO_ROOT)
 
 
 def main() -> None:
@@ -31,6 +32,9 @@ def main() -> None:
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from torchsnapshot_tpu import Snapshot
+    from torchsnapshot_tpu.utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache(_REPO_ROOT)
     from torchsnapshot_tpu.io_preparer import MAX_CHUNK_SIZE_BYTES
     from torchsnapshot_tpu.manifest import ShardedArrayEntry
 

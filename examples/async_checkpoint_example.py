@@ -113,16 +113,15 @@ def main() -> None:
         ) or fresh_progress["step"] % args.snap_every == 0
 
     steady = float(np.median(step_times))
-    # Median: on a shared-tunnel host one interfered snapshot dispatch
-    # would otherwise dominate the mean.
+    # Median: one interfered snapshot dispatch would otherwise dominate
+    # the mean.
     stall = float(np.median(stall_times)) if stall_times else 0.0
     print(
         f"median step {steady*1e3:.1f} ms; async_take stall "
         f"{stall*1e3:.1f} ms (writes drained in background; the stall "
-        f"is per-take structure — clone dispatch + commit collectives — "
-        f"not payload-proportional, so against this toy model's "
-        f"{steady*1e3:.0f} ms steps it reads large while a real model's "
-        f"multi-second steps make it <1%)"
+        f"is the on-device clone plus the commit collectives when the "
+        f"clones fit in device memory, and a full device-to-host "
+        f"staging when they do not)"
     )
     print(f"snapshots in {work_dir}")
 

@@ -150,7 +150,9 @@ def main() -> int:
             "127.0.0.1:0",
             "--port-file",
             pf,
-        ]
+        ],
+        # A read-plane sidecar never takes the chip (one process per chip).
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
     )
     try:
         for _ in range(300):

@@ -74,7 +74,10 @@ def main() -> int:
                     "127.0.0.1:0",
                     "--port-file",
                     pf,
-                ]
+                ],
+                # Read-plane sidecars never take the chip (one process
+                # per chip).
+                env={**os.environ, "JAX_PLATFORMS": "cpu"},
             )
         )
         for _ in range(300):

@@ -1,8 +1,8 @@
 """Content-addressed cross-take chunk store (the dedup write plane).
 
-Since BENCH_r02 the take path has been pinned to the D2H probe ceiling
-(``take_vs_ceiling`` ≈ 1.0): the only way to make takes faster is to
-move FEWER bytes. ``incremental.py`` already skips whole leaves whose
+Where the take path runs at the device→host link's rate, the only way
+to make takes faster is to move FEWER bytes. ``incremental.py`` already
+skips whole leaves whose
 content fingerprint matches a ``base=`` snapshot; this module promotes
 that to sub-leaf granularity with no ``base=`` argument at all:
 

@@ -229,10 +229,10 @@ class JaxStore(Store):
         try:
             self._client.key_value_delete(key)
         except Exception:
-            # Best-effort: a delete that races service restart or an older
-            # jaxlib without key_value_delete must never fail a snapshot —
-            # but the failure is still visible at debug level so a GC that
-            # silently stops collecting is diagnosable.
+            # Best-effort: a delete that races a service restart must
+            # never fail a snapshot — but the failure is still visible at
+            # debug level so a GC that silently stops collecting is
+            # diagnosable.
             logger.debug(
                 f"coordination-service delete of {key} failed", exc_info=True
             )
@@ -240,9 +240,6 @@ class JaxStore(Store):
     def try_get(self, key: str) -> Optional[bytes]:
         try:
             val = self._client.key_value_try_get(key)
-        except AttributeError:
-            # Older jaxlib: fall back to the short blocking poll.
-            return super().try_get(key)
         except Exception as e:
             # Non-blocking probe: absence and transient failure both mean
             # "not observable now"; GC just defers (see Store.try_get).
@@ -676,14 +673,12 @@ def get_coordinator(coord: Optional[Coordinator] = None) -> Coordinator:
     with _default_coordinator_lock:
         if _default_coordinator is not None:
             return _default_coordinator
-        try:
-            import jax
-            from jax._src import distributed
+        # Imported plainly: if JAX's internals move, this raises instead
+        # of degrading a multi-host job to a world-size-1 coordinator.
+        import jax
+        from jax._src import distributed
 
-            client = distributed.global_state.client
-        except (ImportError, AttributeError):
-            # jax absent or its internals moved: single-process semantics.
-            client = None
+        client = distributed.global_state.client
         if client is None:
             # jax.distributed not initialized — single-process. Not cached,
             # so a later jax.distributed.initialize() is still honored

@@ -620,8 +620,10 @@ def spawn_peer(
     ]
     if capacity_bytes is not None:
         cmd += ["--capacity-bytes", str(capacity_bytes)]
+    # A peer is a host-RAM sidecar: pinned to CPU so it can never take
+    # the chip its parent trains on (one process per chip).
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.Popen(
         cmd,
         env=env,

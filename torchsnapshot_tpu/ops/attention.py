@@ -14,8 +14,9 @@ Kernel structure (see /opt/skills/guides/pallas_guide.md):
   sequentially on TPU, so the running max/denominator/accumulator live
   in VMEM scratch that persists across it;
 - accumulation in float32 regardless of input dtype (bf16-safe);
-- on CPU the kernel runs in interpreter mode, so the hermetic test suite
-  exercises the same code path bit-for-bit.
+- on a TPU backend the kernels compile through Mosaic or the call fails;
+  only the CPU backend interprets them (the hermetic test suite), see
+  :func:`resolve_interpret`.
 
 The backward pass is also tiled Pallas: the forward saves the per-row
 log-sum-exp, and two kernels reconstruct p = exp(s - lse) per tile to
@@ -64,18 +65,16 @@ def resolve_flash_block(seq_len: int) -> int:
 
     The cap is a VMEM-residency choice, not an MXU one: bigger tiles
     amortize the per-block online-softmax bookkeeping and k/v tile
-    revisits. Measured on one v5e chip (S=4096, D=128, bf16, causal):
-    128-wide tiles sustain ~10 TFLOP/s forward, 512 ~50, 1024 ~80 (and
-    ~6× on forward+backward); 2048² tiles exceed VMEM and fail to
-    compile. A 1024² f32 score tile is 4 MB — resident even on 16 MB
-    VMEM generations. Lengths whose power-of-two factor is below the
-    sublane minimum (8) are rejected — they would tile into sub-MXU
-    scalar-sized blocks, worse than einsum.
+    revisits, and a 1024² f32 score tile is 4 MiB. The backward holds
+    several such tiles live at once, so every ``pallas_call`` here
+    requests its VMEM explicitly (:func:`_compiler_params`, which says
+    when the default limit is and is not enough). Throughput per tile
+    size on a directly attached chip: not measured. Lengths whose
+    power-of-two factor is below the sublane minimum (8) are rejected —
+    they would tile into sub-MXU scalar-sized blocks, worse than einsum.
 
-    The numbers above are v5e; the backward pass holds several
-    [block, block] f32 intermediates live per tile, so a generation
-    with much smaller VMEM may need a smaller cap —
-    ``TPUSNAPSHOT_FLASH_BLOCK_CAP`` overrides it without code changes."""
+    ``TPUSNAPSHOT_FLASH_BLOCK_CAP`` lowers the cap without code changes
+    for a generation with less VMEM."""
     import math
 
     from ..utils.env import env_int
@@ -92,12 +91,45 @@ def resolve_flash_block(seq_len: int) -> int:
 
 
 def resolve_interpret() -> bool:
-    """Run the kernel in interpreter mode off-TPU (hermetic CPU tests).
+    """Whether the kernels run in Pallas interpreter mode: never on a
+    TPU backend (they compile through Mosaic or the call fails), always
+    on the CPU backend (the hermetic test suite). Any other backend is
+    an error — interpreting there would hide that the kernels, written
+    against the Mosaic lowering, never ran compiled."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"flash attention kernels target TPU (Mosaic); backend "
+        f"{backend!r} is neither 'tpu' (compiled) nor 'cpu' "
+        f"(interpreted for tests). Use the einsum path."
+    )
 
-    Any non-TPU backend interprets: the kernels are written against the
-    TPU Mosaic lowering, and compiling them on e.g. GPU would fail with
-    an opaque Mosaic error rather than fall back."""
-    return jax.default_backend() != "tpu"
+
+_MIB = 1024 * 1024
+
+
+def _compiler_params(block_q: int, block_k: int, d: int):
+    """Scoped-VMEM request for one kernel launch.
+
+    Live at once in the backward are s, p, dp, ds, the two causal
+    position grids and their mask — [block_q, block_k] 4-byte tiles —
+    beside the double-buffered q/k/v/dO row blocks and the lse/delta
+    columns, whose trailing dim of 1 pads to 128 lanes: ~38 MiB at
+    1024-row tiles. Observed on a v5e (libtpu 0.0.34): at the default
+    matmul precision all three kernels fit Mosaic's default scoped limit
+    at that tiling, but under ``jax.default_matmul_precision("highest")``
+    (multi-pass float32 matmuls — how a float32 reference check must
+    run) the backward is refused with ``RESOURCE_EXHAUSTED ... vmem``
+    unless this request is made. Capped below the 128 MiB a TensorCore
+    has."""
+    tiles = 8 * 4 * block_q * block_k
+    rows = 2 * 4 * (4 * max(block_q, block_k) * d + 2 * block_q * 128)
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=min(96 * _MIB, max(16 * _MIB, tiles + rows))
+    )
 
 
 def _flash_kernel(
@@ -396,6 +428,7 @@ def _flash_backward(q, k, v, g, lse, delta, causal, block_q, block_k, interpret)
         in_specs=[row_q, row_k, row_k, row_q, aux_q, aux_q],
         out_specs=row_q,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        compiler_params=_compiler_params(block_q, block_k, d),
         interpret=interpret,
     )(qf, kf, vf, gf, lsef, deltaf)
 
@@ -424,6 +457,7 @@ def _flash_backward(q, k, v, g, lse, delta, causal, block_q, block_k, interpret)
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
+        compiler_params=_compiler_params(block_q, block_k, d),
         interpret=interpret,
     )(qf, kf, vf, gf, lsef, deltaf)
 
@@ -532,6 +566,7 @@ def _flash_forward(
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
+        compiler_params=_compiler_params(block_q, block_k, d),
         interpret=interpret,
     )(qf, kf, vf)
     return out.reshape(b, h, s, d), lse.reshape(b, h, s, 1)

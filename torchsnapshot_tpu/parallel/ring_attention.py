@@ -39,31 +39,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """``jax.shard_map`` across jax versions: the top-level binding (and
-    its ``check_vma`` kwarg) only exists in newer releases; earlier ones
-    ship ``jax.experimental.shard_map.shard_map`` with the equivalent
-    ``check_rep`` kwarg. One shim so every call site works on both."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(
-            f,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as sm_experimental
-
-    return sm_experimental(
-        f,
-        mesh=mesh,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        check_rep=check_vma,
-    )
-
 _NEG_INF = -1e30
 
 
@@ -275,7 +250,7 @@ def ring_attention(
         acc = accumulate(n - 1, carry[:3], carry[3], carry[4])
         return _norm(acc).astype(qc.dtype)
 
-    shard_fn = shard_map_compat(
+    shard_fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(spec, spec, spec),
@@ -458,7 +433,7 @@ def ring_attention_zigzag(
             [_norm(acc_lo), _norm(acc_hi)], axis=2
         ).astype(qc.dtype)
 
-    shard_fn = shard_map_compat(
+    shard_fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(spec, spec, spec),

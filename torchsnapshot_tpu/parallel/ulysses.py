@@ -41,7 +41,7 @@ from ..ops.attention import (
     resolve_flash_block,
     resolve_interpret,
 )
-from .ring_attention import _resolve_spec, shard_map_compat
+from .ring_attention import _resolve_spec
 
 
 def ulysses_attention(
@@ -117,7 +117,7 @@ def ulysses_attention(
             out, axis, split_axis=2, concat_axis=1, tiled=True
         )
 
-    shard_fn = shard_map_compat(
+    shard_fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(spec, spec, spec),
