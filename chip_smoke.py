@@ -15,10 +15,11 @@ is cut (``N_LAYERS``); weights are random, made from a seed.
     python3 chip_smoke.py --cpu-rehearsal  # toy widths on CPU, NOT a chip run
 
 Every phase failure is a non-zero exit; nothing is recorded and carried
-on from. The last line of stdout is one JSON object::
+on from. The last two lines of stdout are JSON objects: the summary of
+what was observed, ending ``"claim": null``, and then — last, with exactly
+these keys, because the driver's chip check reads it — ::
 
-    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1},
-     ..., "claim": null}
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
 
 Every second it prints is a single-run observation on the named device,
 not a result. Snapshot payloads go to a scratch directory under the
@@ -723,8 +724,6 @@ def main() -> int:
     print(
         json.dumps(
             {
-                "ok": True,
-                "device": device_doc,
                 "chip_run": on_tpu,
                 "n_layers": cfg.n_layers,
                 **facts,
@@ -735,6 +734,8 @@ def main() -> int:
         ),
         flush=True,
     )
+    # The driver's line: exactly these keys, and nothing after it.
+    print(json.dumps({"ok": True, "device": device_doc}), flush=True)
     return 0
 
 

@@ -40,13 +40,19 @@ def test_cpu_rehearsal_runs_the_whole_flow(tmp_path):
     assert proc.returncode == 0, proc.stderr[-3000:]
     lines = proc.stdout.strip().splitlines()
     assert "NOT A CHIP RUN" in lines[0]
-    doc = json.loads(lines[-1])
-    assert doc["ok"] is True and doc["chip_run"] is False
-    assert doc["device"]["platform"] == "cpu"
+    # Last line: the driver's object, exactly these keys and types.
+    verdict = json.loads(lines[-1])
+    assert set(verdict) == {"ok", "device"} and verdict["ok"] is True
+    device = verdict["device"]
+    assert set(device) == {"platform", "kind", "count"}
+    assert device["platform"] == "cpu" and isinstance(device["kind"], str)
+    # Eight virtual devices: the sharded leg ran too.
+    assert type(device["count"]) is int and device["count"] == 8
+    # The line before it: what was observed, claiming nothing.
+    doc = json.loads(lines[-2])
+    assert doc["chip_run"] is False
     assert list(doc)[-1] == "claim" and doc["claim"] is None
     assert doc["capture_route"] in ("device clones", "host-staging fallback")
-    # Eight virtual devices: the sharded leg ran too.
-    assert doc["device"]["count"] == 8
     assert set(doc["sharded_restore_s"]) == {"2-way", "2x2"}
     out = proc.stdout
     assert "resumed losses equal the uninterrupted run's exactly" in out
