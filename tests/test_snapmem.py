@@ -5,17 +5,22 @@ the faultline ``mem_pressure`` rule deterministically tripping
 reconciliation, ``ops --mem`` fleet merging, and the doctor/slo rules
 (PR 20 acceptance criteria)."""
 
+import gc
 import json
 import os
 import subprocess
 import sys
+import threading
+import time
 
 import numpy as np
 import pytest
 
 from torchsnapshot_tpu import Snapshot, telemetry
+from torchsnapshot_tpu.staging_pool import StagingPool
 from torchsnapshot_tpu.telemetry import doctor as _doctor
 from torchsnapshot_tpu.telemetry import memwatch
+from torchsnapshot_tpu.telemetry import metrics as _metrics
 from torchsnapshot_tpu.telemetry import ops as scope_ops
 
 
@@ -546,3 +551,148 @@ def test_scheduler_registers_transient_write_domain(tmp_path):
         {"model": _Model({"w": np.zeros(16, dtype=np.float32)})},
     )
     assert "scheduler.write" not in memwatch.snapshot()["domains"]
+
+
+# -------------------------------------- collector callbacks take no lock
+#
+# The collector runs finalisers on whatever thread allocates, also
+# inside that thread's own critical sections. Each case plants a
+# collection INSIDE the metrics registry's lock and drops, beforehand,
+# something only the collector can free: a cyclic owner of a MemDomain,
+# or an unreleased lease in a cycle. A finaliser that asks for the
+# registry's lock there waits on its own thread for ever.
+
+
+class _CollectInsideItems(dict):
+    """Planted as ``REGISTRY._metrics``: ``items()`` and ``snapshot()``
+    walk it with the lock held."""
+
+    def items(self):
+        gc.collect()
+        time.sleep(0.05)  # let the other thread's calls queue on the lock
+        return super().items()
+
+
+class _CollectInsideInit(_metrics.Gauge):
+    """A metric constructor: ``_get_or_create`` calls it between its
+    check and its insert, with the lock held."""
+
+    def __init__(self):
+        gc.collect()
+        time.sleep(0.05)
+        super().__init__()
+
+
+def _drop_cyclic_pool():
+    pool = StagingPool(capacity_bytes=1 << 20)
+    pool.acquire(4096).release()  # 4096 B retained: the domain is not empty
+    pool.cycle = pool
+
+
+def _drop_cyclic_lease(pool):
+    box = [pool.acquire(4096)]
+    box.append(box)
+
+
+def _collect_inside(region, pool):
+    registry = _metrics.REGISTRY
+    if region == "get_or_create":
+        _drop_cyclic_pool()
+        registry._get_or_create("t_planted", _CollectInsideInit, {})
+        return
+    if region == "items":
+        _drop_cyclic_pool()
+    else:
+        _drop_cyclic_lease(pool)
+    planted = _CollectInsideItems(registry._metrics)
+    registry._metrics = planted
+    try:
+        registry.items() if region == "items" else registry.snapshot()
+    finally:
+        registry._metrics = dict(planted)
+
+
+@pytest.mark.time_limit(10)
+@pytest.mark.parametrize("thread", ["same", "second"])
+@pytest.mark.parametrize("region", ["items", "get_or_create", "lease_in_snapshot"])
+def test_collection_inside_the_registry_lock_does_not_deadlock(region, thread):
+    pool = StagingPool(capacity_bytes=1 << 20)
+    switch_interval = sys.getswitchinterval()
+    gc.collect()
+    gc.disable()  # the planted collection is the one that finds them
+    try:
+        if thread == "same":
+            _collect_inside(region, pool)
+        else:
+            # The drain is safe against ordinary calls made meanwhile by
+            # a thread that holds nothing.
+            t = threading.Thread(
+                target=_collect_inside, args=(region, pool), daemon=True
+            )
+            sys.setswitchinterval(1e-5)
+            t.start()
+            while t.is_alive():
+                memwatch.snapshot()
+                pool.acquire(512).release()
+                telemetry.gauge("t_other").set(1)
+            t.join(timeout=5)
+            assert not t.is_alive()
+    finally:
+        sys.setswitchinterval(switch_interval)
+        gc.enable()
+    # The next ordinary entries settle what the collector handed off,
+    # and every reading is what an immediate close / release gives.
+    stats = pool.stats()
+    entry = memwatch.snapshot()["domains"]["staging_pool"]
+    assert stats["in_use_bytes"] == 0
+    assert entry["instances"] == 1  # the dropped pool's domain is closed
+    assert entry["pinned_bytes"] == 0
+    assert entry["used_bytes"] == stats["free_bytes"]
+    assert memwatch._TOTAL_USED == stats["free_bytes"]
+    used = telemetry.gauge(_metrics.MEM_DOMAIN_USED, domain="staging_pool")
+    assert used.value == stats["free_bytes"]
+
+
+def test_dropped_owner_and_lease_settle_at_the_next_entry():
+    credited = []
+    pool = StagingPool(capacity_bytes=1 << 20)
+    lease = pool.acquire(4096)
+    lease.set_budget_release(credited.append, 4096)
+    del lease  # an error path dropping its plan: no release()
+    assert pool.stats() == {
+        "free_bytes": 4096,
+        "in_use_bytes": 0,
+        "capacity_bytes": 1 << 20,
+        "high_water_bytes": 4096,
+    }
+    assert credited == [4096]  # the budget re-credit fired, once
+    del pool
+    gc.collect()
+    assert "staging_pool" not in memwatch.snapshot()["domains"]
+    assert memwatch._TOTAL_USED == 0
+    used = telemetry.gauge(_metrics.MEM_DOMAIN_USED, domain="staging_pool")
+    assert used.value == 0
+
+
+def test_the_package_has_one_finaliser_and_one_del():
+    """The rule (docs/OBSERVABILITY.md, Metrics) holds because the
+    collector's callbacks are few enough to read: a new one is added
+    here, after its reach has been walked for locks."""
+    import re
+    from pathlib import Path
+
+    import torchsnapshot_tpu
+
+    root = Path(torchsnapshot_tpu.__file__).parent
+    callback = re.compile(
+        r"weakref\.(?:finalize|ref|proxy|Weak\w+)\(|def __del__\("
+    )
+    found = sorted(
+        (str(path.relative_to(root)), m.group(0))
+        for path in root.rglob("*.py")
+        for m in callback.finditer(path.read_text())
+    )
+    assert found == [
+        ("staging_pool.py", "def __del__("),
+        ("telemetry/memwatch.py", "weakref.finalize("),
+    ]

@@ -1,9 +1,14 @@
 """Watch the watchmen: the equality helpers are themselves tested
 (reference analog: tests/test_test_utils.py:27-108)."""
 
+import os
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from torchsnapshot_tpu.utils.test_utils import (
     assert_state_dict_eq,
@@ -91,3 +96,77 @@ def test_statefuls():
     assert fs.state_dict() == {"v": 1}
     fs.load_state_dict({"v": 42})
     assert box["v"] == 42
+
+
+# ------------------------------------------- the per-test limit of conftest.py
+
+_CHILD_TESTS = """
+import threading
+
+import pytest
+
+
+@pytest.mark.time_limit(2)
+def test_blocks():
+    lock = threading.Lock()
+    lock.acquire()
+    lock.acquire()  # BLOCKS HERE
+
+
+@pytest.mark.time_limit(2)
+def test_swallows():
+    lock = threading.Lock()
+    lock.acquire()
+    try:
+        lock.acquire()
+    except BaseException:
+        pass  # what the collector does with what a finaliser raises
+
+
+def test_after():
+    pass
+"""
+
+
+@pytest.fixture(scope="module")
+def limited_child(tmp_path_factory):
+    """A child pytest under this suite's conftest.py, on a file whose
+    first two tests block for ever on a lock they hold."""
+    tests_dir = os.path.dirname(os.path.abspath(__file__))
+    child_dir = tmp_path_factory.mktemp("limited_child")
+    (child_dir / "test_child.py").write_text(_CHILD_TESTS)
+    return subprocess.run(
+        [sys.executable, "-m", "pytest", "test_child.py", "-q", "-rA",
+         "-p", "conftest", "-p", "no:cacheprovider", "-p", "no:xdist"],
+        cwd=child_dir,
+        env=dict(os.environ, PYTHONPATH=tests_dir),
+        capture_output=True,
+        text=True,
+        timeout=60,
+    )
+
+
+def test_limit_fails_the_blocked_test_and_the_file_runs_on(limited_child):
+    out = limited_child.stdout
+    assert limited_child.returncode == 1, out + limited_child.stderr
+    assert "FAILED test_child.py::test_blocks" in out
+    assert "PASSED test_child.py::test_after" in out
+    assert "2 failed, 1 passed" in out
+
+
+def test_limit_dumps_every_stack_naming_the_blocking_line(limited_child):
+    blocking_line = 1 + _CHILD_TESTS.splitlines().index(
+        "    lock.acquire()  # BLOCKS HERE"
+    )
+    assert (
+        "test_child.py::test_blocks (call) exceeded its time limit of 2 s"
+        in limited_child.stderr
+    )
+    assert (
+        f'test_child.py", line {blocking_line} in test_blocks'
+        in limited_child.stderr
+    )
+
+
+def test_limit_fails_a_test_that_swallowed_the_expiry(limited_child):
+    assert "FAILED test_child.py::test_swallows" in limited_child.stdout

@@ -17,7 +17,6 @@ every instant (tests/test_snapserve.py hammers this from 16 threads).
 """
 
 import threading
-import weakref
 import zlib
 from collections import OrderedDict
 from typing import Dict, Optional, Tuple
@@ -56,9 +55,8 @@ class ByteLRU:
         # ByteLRUs in one process (multi-server tests) aggregate under
         # the one domain name.
         self._mem_domain = memwatch.register(
-            "snapserve.cache", cap_bytes=self.cap_bytes
+            "snapserve.cache", cap_bytes=self.cap_bytes, owner=self
         )
-        weakref.finalize(self, self._mem_domain.close)
 
     def get(self, key: str) -> Optional[bytes]:
         """The cached payload, fingerprint-verified, or None. A failed

@@ -56,7 +56,6 @@ import collections
 import logging
 import threading
 import time
-import weakref
 from typing import Any, Awaitable, Callable, Dict, List, Optional, Tuple
 
 from .. import telemetry, tracing, wiretap
@@ -147,8 +146,8 @@ class _ClientGate:
             cap_bytes=self._cap,
             transient=True,
             watch_residual="used",
+            owner=self,
         )
-        weakref.finalize(self, self._mem_domain.close)
 
     async def acquire(self, nbytes: int) -> None:
         begin = time.monotonic()
@@ -222,8 +221,8 @@ class TenantAdmission:
             "snapserve.tenant",
             transient=True,
             watch_residual="used",
+            owner=self,
         )
-        weakref.finalize(self, self._mem_domain.close)
 
     def _publish_mem_locked(self) -> None:
         total = sum(self._inflight.values())

@@ -38,10 +38,10 @@ Env knobs:
   a pipeline the budget already admitted.
 """
 
+import collections
 import threading
 import time
-import weakref
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Deque, Dict, List, Optional
 
 import numpy as np
 
@@ -59,6 +59,30 @@ _DEFAULT_POOL_WAIT_S = 5.0
 
 def pool_capacity_bytes() -> int:
     return env_int(_POOL_BYTES_ENV_VAR, _DEFAULT_POOL_BYTES)
+
+
+# Leases the garbage collector found unreleased. ``__del__`` can run on
+# any thread at any allocation, also inside a critical section of that
+# same thread (the metrics registry's, this pool's), so it takes no
+# lock: it parks the lease here (a deque append is atomic under the
+# GIL) and the next ordinary entry into a pool releases it.
+_dropped: Deque["StagingLease"] = collections.deque()
+
+
+def _release_dropped() -> None:
+    """Release the leases ``__del__`` parked. Called at the ordinary
+    entries (acquire / give-back / stats / pool reset) before any pool
+    lock is taken, never from the collector. The batch is taken out
+    first, so the give-back of each finds the queue empty and does not
+    nest one level per lease."""
+    batch: List[StagingLease] = []
+    while _dropped:
+        try:
+            batch.append(_dropped.popleft())
+        except IndexError:  # another thread drained it first
+            break
+    for lease in batch:
+        lease.release()
 
 
 class StagingLease:
@@ -123,11 +147,11 @@ class StagingLease:
         # plan mid-flight): an unreachable lease can have no live views
         # into its buffer from the pipeline that owned it, so returning
         # it keeps the pool's in-use accounting honest across repeated
-        # failure injections (faultline crash matrices).
-        try:
-            self.release()
-        except Exception:  # snapcheck: disable=swallowed-exception -- GC-time best effort
-            pass
+        # failure injections (faultline crash matrices). Nobody else can
+        # see an unreachable lease, so the flag is read without the
+        # lock; the release itself happens in _release_dropped().
+        if not self._released:
+            _dropped.append(self)
 
 
 class StagingPool:
@@ -158,8 +182,8 @@ class StagingPool:
             "staging_pool",
             cap_bytes=capacity_bytes,
             watch_residual="pinned",
+            owner=self,
         )
-        weakref.finalize(self, self._mem_domain.close)
 
     # ------------------------------------------------------------ acquire
     def acquire(
@@ -171,6 +195,7 @@ class StagingPool:
         ``max_wait_s`` — for a release, noting the wait into
         ``profile`` as the ``pool_wait`` sub-step; it then allocates
         past the cap rather than ever deadlocking the pipeline."""
+        _release_dropped()
         with self._cond:
             buf = self._take_free_locked(nbytes)
             if buf is None:
@@ -246,6 +271,7 @@ class StagingPool:
 
     # ------------------------------------------------------------ release
     def _give_back(self, buffer: bytearray, nbytes: int) -> None:
+        _release_dropped()
         with self._cond:
             self._in_use_bytes -= nbytes
             if self._free_bytes + nbytes <= self.capacity_bytes:
@@ -273,6 +299,7 @@ class StagingPool:
 
     # ------------------------------------------------------------- stats
     def stats(self) -> Dict[str, int]:
+        _release_dropped()
         with self._cond:
             return {
                 "free_bytes": self._free_bytes,
@@ -299,6 +326,7 @@ def get_staging_pool() -> Optional[StagingPool]:
 
 def reset_staging_pool() -> None:
     """Drop the memoized pool (tests re-read the env knobs)."""
+    _release_dropped()
     with _pool_lock:
         for pool in _pool:
             if pool is not None:

@@ -70,11 +70,13 @@ module may fail the operation it measures.
 """
 
 import argparse
+import collections
 import json
 import logging
 import sys
 import threading
-from typing import Any, Callable, Dict, List, Optional, Tuple
+import weakref
+from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 
 from ..utils.env import env_int
 from . import metrics as _m
@@ -111,6 +113,24 @@ _NEXT_WINDOW_ID = 1
 # updates (providers fold in at snapshot time only).
 _TOTAL_USED = 0
 _TOTAL_HWM = 0
+# Domains whose owner the garbage collector found. The collector runs a
+# finaliser on whatever thread happens to allocate, also inside that
+# thread's own critical sections (a registry lock it already holds), so
+# the finaliser takes no lock: it appends here (atomic under the GIL)
+# and the next ordinary entry into this module closes them.
+_COLLECTED: Deque["MemDomain"] = collections.deque()
+
+
+def _close_collected() -> None:
+    """Close the domains handed off by the collector. Called at every
+    ordinary entry (register / update / snapshot / window), never from
+    a finaliser."""
+    while _COLLECTED:
+        try:
+            d = _COLLECTED.popleft()
+        except IndexError:  # another thread drained it first
+            return
+        d.close()
 
 
 class MemDomain:
@@ -168,6 +188,7 @@ class MemDomain:
         delta). ``pinned_bytes`` defaults to sticky: unchanged if set
         before, else 0."""
         global _TOTAL_USED, _TOTAL_HWM
+        _close_collected()
         used = max(0, int(used_bytes))
         with _LOCK:
             if not self._alive:
@@ -208,7 +229,9 @@ class MemDomain:
 
     def close(self) -> None:
         """Unregister (idempotent). The domain's bytes leave the
-        committed total — a closed pool/cache no longer holds them."""
+        committed total — a closed pool/cache no longer holds them.
+        Takes the registry locks: never a collector callback (see
+        ``register(owner=...)``)."""
         global _TOTAL_USED
         with _LOCK:
             if not self._alive:
@@ -299,12 +322,17 @@ def register(
     transient: bool = False,
     watch_residual: Optional[str] = None,
     external: bool = False,
+    owner: Optional[object] = None,
 ) -> MemDomain:
     """Register one byte-capped subsystem instance. Call
     :meth:`MemDomain.close` when the instance goes away (pool reset,
-    server stop); a ``weakref.finalize`` on the owning object is the
-    idiomatic safety net."""
+    server stop). ``owner`` is the safety net: once it is collected
+    without a ``close()``, the domain is closed at the next entry into
+    this module — the finaliser itself only queues it."""
+    _close_collected()
     d = MemDomain(name, cap_bytes, transient, watch_residual, external)
+    if owner is not None:
+        weakref.finalize(owner, _COLLECTED.append, d)
     with _LOCK:
         _DOMAINS.setdefault(name, []).append(d)
         # Stamp cap/externality into already-open windows so a domain
@@ -361,6 +389,7 @@ def reset() -> None:
         _PROVIDERS.clear()
         _CAP_OVERRIDES.clear()
         _WINDOWS.clear()
+        _COLLECTED.clear()
         _TOTAL_USED = 0
         _TOTAL_HWM = 0
 
@@ -559,6 +588,7 @@ def snapshot() -> Dict[str, Any]:
     """One consistent cross-domain view: every domain's occupancy and
     lifetime high-water, the committed total (external domains
     excluded), and headroom against the host budget."""
+    _close_collected()
     with _LOCK:
         domains = _domains_locked()
         total_hwm = _TOTAL_HWM
@@ -628,6 +658,7 @@ def window_begin() -> int:
     the window still reports its standing bytes as the window
     high-water."""
     global _NEXT_WINDOW_ID
+    _close_collected()
     with _LOCK:
         w = _Window()
         domains = _domains_locked()
@@ -653,6 +684,7 @@ def window_collect(token: int) -> Dict[str, Any]:
     the aggregate window high-water, headroom at close, and any
     pressure forecasts recorded inside the window. ``{}`` when no
     domain was ever registered (the caller omits the block)."""
+    _close_collected()
     with _LOCK:
         w = _WINDOWS.pop(token, None)
         domains = _domains_locked()
