@@ -11,6 +11,7 @@ from torchsnapshot_tpu.io_types import (
     BufferStager,
     IOReq,
     ReadReq,
+    StoragePlugin,
     WriteReq,
 )
 from torchsnapshot_tpu.scheduler import (
@@ -19,6 +20,7 @@ from torchsnapshot_tpu.scheduler import (
     get_local_world_size,
     get_process_memory_budget_bytes,
 )
+from torchsnapshot_tpu.storage_plugins.fs import FSStoragePlugin
 from torchsnapshot_tpu.storage_plugins.memory import MemoryStoragePlugin
 
 
@@ -114,6 +116,76 @@ def test_write_error_propagates():
                 rank=0,
             )
         )
+
+
+class _GatedStorage(MemoryStoragePlugin):
+    """Every write blocks until the gate opens."""
+
+    def __init__(self, cap: int) -> None:
+        super().__init__()
+        self.max_write_concurrency = cap
+        self.gate = asyncio.Event()
+        self.in_flight = 0
+        self.max_in_flight = 0
+        self.started_behind_the_gate = []
+
+    async def write(self, io_req: IOReq) -> None:
+        self.in_flight += 1
+        self.max_in_flight = max(self.max_in_flight, self.in_flight)
+        if not self.gate.is_set():
+            self.started_behind_the_gate.append(io_req.path)
+        await self.gate.wait()
+        await super().write(io_req)
+        self.in_flight -= 1
+
+
+@pytest.mark.parametrize(
+    "cap",
+    sorted(
+        {
+            1,
+            2,
+            FSStoragePlugin.max_write_concurrency,
+            StoragePlugin.max_write_concurrency,
+        }
+    ),
+)
+def test_write_streams_reach_the_cap_and_never_pass_it(cap):
+    payloads = {f"p{i}": bytes([i]) * (i + 1) for i in range(2 * cap + 3)}
+    write_reqs = [
+        WriteReq(path=k, buffer_stager=_Stager(v)) for k, v in payloads.items()
+    ]
+    storage = _GatedStorage(cap)
+    stats = {}
+
+    async def _run():
+        pipeline = asyncio.ensure_future(
+            execute_write_reqs(
+                write_reqs, storage, 1 << 20, rank=0, stats=stats
+            )
+        )
+        # Every request stages at once and no write returns: the pipeline
+        # comes to rest with every slot taken, and stays there.
+        deadline = time.monotonic() + 30
+        while storage.in_flight < cap and time.monotonic() < deadline:
+            await asyncio.sleep(0.001)
+        await asyncio.sleep(0.05)
+        assert storage.in_flight == cap
+        assert not pipeline.done()
+        storage.gate.set()
+        return await pipeline
+
+    assert asyncio.run(_run()) == sum(len(v) for v in payloads.values())
+    assert storage.max_in_flight == cap
+    assert stats["write_concurrency"] == cap
+    # The first `cap` requests found a free slot; every other one queued.
+    queued = set(payloads) - set(storage.started_behind_the_gate)
+    assert len(queued) == len(payloads) - cap
+    waited = stats["ops"]["write_wait"]
+    assert waited["count"] == len(queued)
+    assert waited["bytes"] == sum(len(payloads[k]) for k in queued)
+    assert waited["seconds"] >= 0.05
+    assert stats["ops"]["write"]["count"] == len(payloads)
 
 
 def test_memory_budget_env_override(monkeypatch):

@@ -98,6 +98,84 @@ def test_fs_dir_fsyncs_batch_to_publish_point(tmp_path, monkeypatch):
     assert synced[-1] == str(tmp_path / "shard")
 
 
+def test_fs_concurrent_writes_keep_the_durability_order(tmp_path, monkeypatch):
+    # More data-object writes in flight at once than any caller admits
+    # (the scheduler holds to max_write_concurrency; delete, copy and
+    # other callers of the plugin do not go through it), into one
+    # directory, then a publish point. Each object:
+    # tmp -> fsync -> rename, the file's own fsync between the two hooks
+    # on the writing thread; the directory's dirents are fsynced after
+    # the last data rename and before the publishing rename.
+    import threading
+    from concurrent.futures import ThreadPoolExecutor
+
+    from torchsnapshot_tpu.io_types import (
+        add_storage_op_hook,
+        remove_storage_op_hook,
+    )
+    from torchsnapshot_tpu.storage_plugins import fs as fs_mod
+
+    n = max(8, FSStoragePlugin.max_write_concurrency)
+    data_paths = [f"shard/obj{i}" for i in range(n)]
+    events = []  # (what, path or directory, thread): appends are atomic
+    all_writing = threading.Barrier(n, timeout=60)
+
+    def hook(op, path):
+        events.append((op, path, threading.get_ident()))
+        if op == "fs.write.tmp" and path in data_paths:
+            all_writing.wait()  # every stream is inside its write at once
+
+    real_fsync, real_fsync_dir = os.fsync, fs_mod._fsync_dir
+
+    def fsync(fd):
+        real_fsync(fd)
+        events.append(("os.fsync", None, threading.get_ident()))
+
+    def fsync_dir(path):
+        real_fsync_dir(path)
+        events.append(("dirents", path, threading.get_ident()))
+
+    monkeypatch.setattr(fs_mod.os, "fsync", fsync)
+    monkeypatch.setattr(fs_mod, "_fsync_dir", fsync_dir)
+    plugin = FSStoragePlugin(root=str(tmp_path))
+
+    async def _run():
+        # The loop's own default pool is min(32, cores + 4) threads.
+        asyncio.get_running_loop().set_default_executor(ThreadPoolExecutor(n))
+        await asyncio.gather(
+            *(
+                plugin.write(IOReq(path=p, data=p.encode() * 1000))
+                for p in data_paths
+            )
+        )
+        await plugin.write(IOReq(path=".snapshot_metadata", data=b"m"))
+
+    add_storage_op_hook(hook)
+    try:
+        asyncio.run(_run())
+    finally:
+        remove_storage_op_hook(hook)
+
+    def index(what, path):
+        (i,) = [i for i, e in enumerate(events) if e[:2] == (what, path)]
+        return i
+
+    for p in data_paths + [".snapshot_metadata"]:
+        tmp, fsync_at, rename = (
+            index(f"fs.write.{step}", p) for step in ("tmp", "fsync", "rename")
+        )
+        assert tmp < fsync_at < rename
+        thread = events[fsync_at][2]
+        assert ("os.fsync", None, thread) in events[fsync_at:rename]
+        with open(tmp_path / p, "rb") as f:
+            assert f.read() == (b"m" if p.startswith(".") else p.encode() * 1000)
+    shard_dirents = index("dirents", str(tmp_path / "shard"))
+    assert max(index("fs.write.rename", p) for p in data_paths) < shard_dirents
+    assert shard_dirents < index("fs.write.rename", ".snapshot_metadata")
+    assert plugin._dirty_dirs == set()
+    assert not [name for name in os.listdir(tmp_path / "shard") if ".tmp" in name]
+
+
 def test_fs_fsyncs_created_root_ancestors(tmp_path, monkeypatch):
     # A root that does not exist yet (step dirs under a fresh job dir):
     # makedirs conjures the whole chain, and every created directory's

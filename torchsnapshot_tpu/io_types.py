@@ -363,9 +363,9 @@ def io_payload(io_req: "IOReq") -> BufferType:
 class StoragePlugin(abc.ABC):
     # How many concurrent IO ops this backend profits from, read by the
     # scheduler as its per-pipeline concurrency caps. Object stores
-    # (GCS/S3) want many parallel streams both ways; a local disk degrades
-    # under parallel *writeback* (the fs plugin lowers the write cap) while
-    # parallel reads still help (page cache / SSD queue depth).
+    # (GCS/S3) want many parallel streams both ways; the fs plugin writes
+    # one object at a time (measured, see its comment) and reads with the
+    # default fan-out.
     max_write_concurrency: int = 16
     max_read_concurrency: int = 16
 

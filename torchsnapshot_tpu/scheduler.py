@@ -174,11 +174,14 @@ async def execute_write_reqs(
     """Run the staged-write pipeline; returns total bytes written.
 
     ``stats`` (optional) accumulates this run's exact aggregates —
-    bytes, per-op count/seconds/bytes, budget stall seconds, budget
-    high-water — for the flight recorder; the same numbers also feed the
-    always-on process metrics. ``progress`` (optional ProgressPublisher)
-    is pulsed per op completion and cadence-published from this loop,
-    so watchers see live bytes/phase while the pipeline runs.
+    bytes, per-op count/seconds/bytes (``write_wait`` among the ops: the
+    requests that found every write slot taken), budget stall seconds,
+    budget high-water, and the write cap it ran under
+    (``write_concurrency``) — for the flight recorder; the same numbers
+    also feed the always-on process metrics. ``progress`` (optional
+    ProgressPublisher) is pulsed per op completion and cadence-published
+    from this loop, so watchers see live bytes/phase while the pipeline
+    runs.
     """
     begin_ts = time.monotonic()
     if progress is not None:
@@ -197,7 +200,7 @@ async def execute_write_reqs(
         # bytes_total (0 done), not a blank.
         await progress.async_tick(force=True)
     pending = deque(write_reqs)
-    staged: deque = deque()  # (WriteReq, buf)
+    staged: deque = deque()  # (WriteReq, buf, ready_t)
     staging: Dict[asyncio.Task, Tuple[WriteReq, int]] = {}
     io_tasks: Dict[asyncio.Task, int] = {}
     budget = memory_budget_bytes
@@ -206,6 +209,12 @@ async def execute_write_reqs(
     ops: Dict[str, Dict[str, Any]] = {}
     bytes_written = 0
     max_io = storage.max_write_concurrency
+    if stats is not None:
+        stats["write_concurrency"] = max_io
+    # Start of the newest wait on in-flight work: a staged buffer that
+    # was ready before it has sat through a wait with every write slot
+    # taken (the dispatch below holds a buffer back for nothing else).
+    wait_t0 = begin_ts
     executor = ThreadPoolExecutor(max_workers=_MAX_STAGING_THREADS)
     # Live budget gauges (snapscope): occupancy + stalled-right-now, so
     # the runtime sampler can see budget pressure while it happens
@@ -284,7 +293,17 @@ async def execute_write_reqs(
                     break
             # Dispatch storage writes up to the backend's concurrency cap.
             while staged and len(io_tasks) < max_io:
-                wr, buf = staged.popleft()
+                wr, buf, ready_t = staged.popleft()
+                if ready_t < wait_t0:
+                    # write_wait (the mirror of the read side's
+                    # read_wait): staged buffer ready -> write
+                    # dispatched, for the requests the cap held back.
+                    _observe_op(
+                        ops,
+                        "write_wait",
+                        time.monotonic() - ready_t,
+                        len(buf),
+                    )
                 io_req = IOReq(path=wr.path, data=buf)
                 # Progress credit in the SAME units bytes_total summed
                 # (cost / payload_nbytes, pre-compression) — len(buf)
@@ -334,7 +353,7 @@ async def execute_write_reqs(
                     wr, cost = staging.pop(task)
                     buf = task.result()
                     budget += cost - len(buf)
-                    staged.append((wr, buf))
+                    staged.append((wr, buf, time.monotonic()))
                 else:
                     buf_len = io_tasks.pop(task)
                     task.result()  # propagate storage errors

@@ -40,11 +40,16 @@ def _fsync_dir(path: str) -> None:
 
 
 class FSStoragePlugin(StoragePlugin):
-    # Local disks lose throughput to writeback contention under parallel
-    # write streams (measured ~2.5x slower at 4+ writers on cloud-VM
-    # disks); two keeps the device busy across file boundaries without
-    # thrashing. Reads keep the default fan-out (queue depth helps).
-    max_write_concurrency = 2
+    # One durable write stream (tmp -> write -> fsync -> rename) at a
+    # time. Measured on the TPU v5e host (PERF.md section 6, PR 26, the
+    # sweep of this cap at 1/2/4/8/16): the directory takes 0.78 GB/s
+    # from one stream and 0.8-1.2 GB/s from 2 to 16 together, and every
+    # stream beyond the first burns kernel CPU against the others,
+    # which a training loop on the same host pays in slowed steps (a
+    # step under the drain: 124-135 ms at 1, 189-286 ms at 2, 500-520 ms
+    # at 8, against 110 ms free) for a drain that ends about a second
+    # sooner. Reads keep the default fan-out.
+    max_write_concurrency = 1
 
     def __init__(self, root: str) -> None:
         self.root = root

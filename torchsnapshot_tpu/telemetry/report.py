@@ -44,6 +44,7 @@ Rank summary::
       "throughput_mbps": ...,
       "budget": {"high_water_bytes": ..., "stall_s": ...},
       "scheduler_ops": {"stage": {"count","seconds","bytes"}, ...},  # exact
+      "write_concurrency": n,              # takes: the write cap it ran under
       "storage_ops": {"<backend>/<op>": {"count","seconds","bytes"}},
       "retries": {"total": n, "backoff_s": s, "by_op": {...}},
       "faults": {"<kind>": n}
@@ -177,6 +178,13 @@ class FlightRecorder:
                 p.get("high_water_bytes", 0),
                 stats.get("budget_high_water_bytes", 0),
             )
+            if "write_concurrency" in stats:
+                # The cap the write pipeline ran under; the mean number
+                # of streams in flight is scheduler_ops.write.seconds
+                # over phases.write_s.
+                p.setdefault("extra", {})["write_concurrency"] = stats[
+                    "write_concurrency"
+                ]
             ops = p.setdefault("ops", {})
             for op, agg in (stats.get("ops") or {}).items():
                 acc = ops.setdefault(
