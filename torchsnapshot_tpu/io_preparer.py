@@ -111,34 +111,145 @@ def _parallel_read_threshold() -> int:
 _DEVICE_BUDGET_ENV_VAR = "TPUSNAPSHOT_DEVICE_BUDGET_BYTES"
 
 
+_KNOB_UNSET = object()
+
+
+def _device_budget_knob() -> Any:
+    """The explicit env knob: its bytes, None for 0 (unbounded), or
+    ``_KNOB_UNSET`` where it is absent or malformed. A malformed value
+    falls THROUGH to the autodetect (r5 review finding — mapping it to
+    "unbounded" would strip exactly the protection the operator
+    explicitly asked for)."""
+    if os.environ.get(_DEVICE_BUDGET_ENV_VAR) is None:
+        return _KNOB_UNSET
+    value = env_int(_DEVICE_BUDGET_ENV_VAR, -1)
+    if value > 0:
+        return value
+    return None if value == 0 else _KNOB_UNSET
+
+
+def _runtime_free_bytes(device: Any = None) -> Optional[int]:
+    """What the runtime reports free on ``device`` (default: the first)
+    right now. TPUs do; CPU/virtual devices usually report nothing →
+    None."""
+    try:
+        stats = (device or jax.devices()[0]).memory_stats() or {}
+        limit = stats.get("bytes_limit")
+        if limit:
+            return max(int(limit - stats.get("bytes_in_use", 0)), 0)
+    # memory_stats is an optional backend capability; absence means
+    # "no device budget", the documented unbounded default.
+    except Exception:  # snapcheck: disable=swallowed-exception -- capability probe
+        pass
+    return None
+
+
+def _device_free_bytes(device: Any = None) -> Optional[int]:
+    """HBM bytes a restore may fill on ``device``: the env knob where
+    set (it stands in for "free"), else what the runtime reports."""
+    knob = _device_budget_knob()
+    return _runtime_free_bytes(device) if knob is _KNOB_UNSET else knob
+
+
 def get_device_restore_budget_bytes() -> Optional[int]:
     """HBM bytes the restore pipeline may hold as in-flight streamed
     chunks awaiting assembly (SURVEY §7 hard-part 5). Explicit env knob
     wins (0 = unbounded); otherwise 90% of the device's currently free
     memory when the runtime reports it (TPUs do; CPU/virtual devices
     usually return None → unbounded)."""
-    raw = os.environ.get(_DEVICE_BUDGET_ENV_VAR)
-    if raw is not None:
-        # Sentinel default: a malformed value falls THROUGH to the
-        # autodetect below (r5 review finding — mapping it to
-        # "unbounded" would strip exactly the protection the operator
-        # explicitly asked for). An explicit 0 means unbounded.
-        value = env_int(_DEVICE_BUDGET_ENV_VAR, -1)
-        if value > 0:
-            return value
-        if value == 0:
-            return None
-    try:
-        stats = jax.devices()[0].memory_stats() or {}
-        limit = stats.get("bytes_limit")
-        in_use = stats.get("bytes_in_use", 0)
-        if limit:
-            return max(int(0.9 * (limit - in_use)), 256 * 1024 * 1024)
-    # memory_stats is an optional backend capability; absence means
-    # "no device budget", the documented unbounded default.
-    except Exception:  # snapcheck: disable=swallowed-exception -- capability probe
-        pass
+    knob = _device_budget_knob()
+    if knob is not _KNOB_UNSET:
+        return knob
+    free = _runtime_free_bytes()
+    if free is None:
+        return None
+    return max(int(0.9 * free), 256 * 1024 * 1024)
+
+
+def device_peak_bytes() -> Optional[int]:
+    """``peak_bytes_in_use`` of the fullest local device, None where
+    the backend reports none."""
+    peaks = []
+    for device in jax.local_devices():
+        try:
+            stats = device.memory_stats() or {}
+        except Exception:  # snapcheck: disable=swallowed-exception -- capability probe
+            continue
+        if "peak_bytes_in_use" in stats:
+            peaks.append(int(stats["peak_bytes_in_use"]))
+    return max(peaks, default=None)
+
+
+def template_placement(template: Any):
+    """``(sharding, [(device, index), ...])`` of a device restore
+    target: a ``jax.Array``, or a ``jax.ShapeDtypeStruct`` that carries
+    a sharding (what a Stateful holds once it released its template's
+    buffers, and what ``jax.eval_shape`` gives a caller that never made
+    one). None for any other template."""
+    if _is_jax_array(template):
+        return template.sharding, [
+            (shard.device, shard.index)
+            for shard in template.addressable_shards
+        ]
+    if (
+        isinstance(template, jax.ShapeDtypeStruct)
+        and getattr(template, "sharding", None) is not None
+    ):
+        indices = template.sharding.addressable_devices_indices_map(
+            tuple(template.shape)
+        )
+        return template.sharding, list(indices.items())
     return None
+
+
+def abstract_of(value: Any) -> Any:
+    """What a restore needs of a device template, without its buffers:
+    a ``jax.Array`` as a ``jax.ShapeDtypeStruct`` with its sharding.
+    Anything else, and typed PRNG keys (a few bytes, restored through
+    their key data's layout), as it is."""
+    if not _is_jax_array(value) or _is_prng_key_array(value):
+        return value
+    return jax.ShapeDtypeStruct(
+        value.shape, value.dtype, sharding=value.sharding
+    )
+
+
+def forget_device_templates(flattened: Dict[str, Any]) -> int:
+    """Replace every device array among ``flattened``'s values by its
+    :func:`abstract_of`; returns the bytes no longer referenced here."""
+    released = 0
+    for path, value in flattened.items():
+        abstract = abstract_of(value)
+        if abstract is not value:
+            released += int(value.nbytes)
+            flattened[path] = abstract
+    return released
+
+
+def template_crowds_device(templates: Any) -> bool:
+    """Whether the arrays about to land do not fit comfortably beside
+    the device templates they replace: on some device, the bytes
+    incoming plus one more copy of its largest shard (a streamed leaf's
+    chunks live beside their concatenation) take more than half of what
+    is free there now. Half, because the free bytes are not one block
+    and the process allocates meanwhile; a state a quarter of HBM in
+    size (template + landed = half) never comes near it. Devices that
+    report no memory never say yes."""
+    incoming: Dict[Any, int] = {}
+    largest: Dict[Any, int] = {}
+    for template in templates:
+        if not _is_jax_array(template):
+            continue
+        for shard in template.addressable_shards:
+            nbytes = int(shard.data.nbytes)
+            incoming[shard.device] = incoming.get(shard.device, 0) + nbytes
+            largest[shard.device] = max(largest.get(shard.device, 0), nbytes)
+    for device, nbytes in incoming.items():
+        free = _device_free_bytes(device)
+        if free is not None and 2 * (nbytes + largest[device]) > free:
+            return True
+    return False
+
 
 _PRIMITIVE_TYPES = (int, float, bool, str, complex, type(None))
 
@@ -1334,12 +1445,11 @@ class ArrayRestorePlan:
             # data view shares the keys' device layout, so use it as the
             # placement template and re-wrap after assembly.
             template = jax.random.key_data(template)
-        self._template_is_jax = _is_jax_array(template) and not isinstance(
-            template, np.ndarray
-        )
+        placement = template_placement(template)
+        self._template_is_jax = placement is not None
         self._sharding = None
         regions: Dict[Tuple[Tuple[int, ...], Tuple[int, ...]], _TargetRegion] = {}
-        if self._template_is_jax:
+        if placement is not None:
             if list(template.shape) != shape:
                 raise RuntimeError(
                     f"Cannot restore array of shape {shape} into a template "
@@ -1347,9 +1457,13 @@ class ArrayRestorePlan:
                     f"resharding (different mesh/partitioning) is supported, "
                     f"reshaping is not."
                 )
-            self._sharding = template.sharding
-            for shard in template.addressable_shards:
-                off, sz = index_to_offsets_sizes(shard.index, shape)
+            # The template is read here and never again: its sharding
+            # and where its shards lie. The plan keeps no reference to
+            # it, so whoever owns it may release its buffers before the
+            # replacement lands (snapshot._load_stateful).
+            self._sharding, shards = placement
+            for device, index in shards:
+                off, sz = index_to_offsets_sizes(index, shape)
                 key = (tuple(off), tuple(sz))
                 if key not in regions:
                     # Device-template region buffers are pool-backed:
@@ -1360,7 +1474,7 @@ class ArrayRestorePlan:
                     regions[key] = _TargetRegion(
                         off, sz, self._dtype, poolable=True
                     )
-                regions[key].devices.append(shard.device)
+                regions[key].devices.append(device)
         else:
             if template is not None and hasattr(template, "shape"):
                 if list(template.shape) != shape and self._prng_impl is None:

@@ -61,9 +61,12 @@ from .flatten import flatten, inflate
 from .io_preparer import (
     ArrayBufferStager,
     device_clone_write_reqs,
+    device_peak_bytes,
+    forget_device_templates,
     get_device_restore_budget_bytes,
     prepare_read,
     prepare_write,
+    template_crowds_device,
 )
 from .io_types import (
     IOReq,
@@ -654,12 +657,20 @@ class Snapshot:
             watch.set_phase("prestage")
             try:
                 with recorder.phase("prestage"):
-                    _prestage_write_reqs(
+                    staged_bytes = _prestage_write_reqs(
                         pending_write_reqs,
                         budget,
                         stage=stage,
                         coordinator=coordinator,
                     )
+                # The route the cut was captured by, as a field of the
+                # take's report: no reader has to count a warning.
+                recorder.note(
+                    capture_route="host_staging"
+                    if staged_bytes is not None
+                    else "device_clones",
+                    capture_host_staged_bytes=staged_bytes or 0,
+                )
             except BaseException:
                 # Failures before the drain thread exists must still
                 # tear down the chunk-store context.
@@ -957,6 +968,16 @@ class Snapshot:
         location must never fail the restore it describes."""
         assemble_s = read_stats.pop("assemble_s", 0.0)
         recorder.note_pipeline(read_stats)
+        # Whether a template had to make room (0: it fitted beside the
+        # landed arrays), and the fullest device's peak as the runtime
+        # reports it (None on a backend that reports none; a peak since
+        # the process began, so an upper bound on this restore's own).
+        recorder.note(
+            template_released_bytes=read_stats.pop(
+                "template_released_bytes", 0
+            ),
+            device_peak_bytes=device_peak_bytes(),
+        )
         ops = read_stats.get("ops") or {}
         consume_agg = ops.get("consume") or {}
         consume_s = consume_agg.get("seconds", 0.0)
@@ -2782,8 +2803,11 @@ def _prestage_write_reqs(
     budget: int,
     stage: str = "auto",
     coordinator: Optional[Coordinator] = None,
-) -> None:
+) -> Optional[int]:
     """Capture async take's consistent cut (device clones or host staging).
+
+    Returns None when the cut is held by device clones, else the bytes
+    staged to the host before returning.
 
     Device mode rebinds array stagers to on-device clones — the stall is
     one HBM copy, and the background drain stages from the clones (each
@@ -2804,7 +2828,7 @@ def _prestage_write_reqs(
     cloned = stage != "host" and device_clone_write_reqs(write_reqs)
     all_cloned = all(coordinator.all_gather_object(cloned))
     if all_cloned and stage != "host":
-        return
+        return None
     if stage == "device":
         # Collective raise: every rank saw the same gather and raises.
         raise RuntimeError(
@@ -2833,7 +2857,9 @@ def _prestage_write_reqs(
         for wr, buf in zip(write_reqs, bufs):
             wr.buffer_stager = _PreStagedStager(buf)
 
-    asyncio.run(_stage_all())
+    with tracing.span("capture_host_stage", bytes=total):
+        asyncio.run(_stage_all())
+    return total
 
 
 class _PreStagedStager:
@@ -2912,6 +2938,35 @@ def _load_stateful(
         reqs, fins = prepare_read(entry=entry, template=template, callback=_callback)
         read_reqs.extend(reqs)
         finalizers.extend(fins)
+
+    # Every plan has read its template (sharding, where the shards lie)
+    # and kept no reference to it. Where the arrays about to land do not
+    # fit beside the device templates they replace (a state above half
+    # of HBM), a Stateful that can let go of its template does so now:
+    # the peak is then the state plus what is in flight, not twice the
+    # state. A partial restore keeps its template (unselected leaves are
+    # handed back as they are). This frame's own references go first.
+    template = template_sd = None
+    release = getattr(stateful, "release_template", None)
+    if (
+        release is not None
+        and path_globs is None
+        and template_crowds_device(flattened.values())
+    ):
+        released = forget_device_templates(flattened)
+        release()
+        logger.info(
+            "restore of %r: the arrays to land do not fit beside the "
+            "template; released %d bytes of template before reading "
+            "(a restore that fails from here on leaves the Stateful "
+            "holding shapes, not arrays)",
+            key,
+            released,
+        )
+        if stats is not None:
+            stats["template_released_bytes"] = (
+                stats.get("template_released_bytes", 0) + released
+            )
 
     asyncio.run(
         execute_read_reqs(

@@ -43,6 +43,25 @@ class PytreeStateful:
         else:
             self.tree = state_dict
 
+    def release_template(self) -> None:
+        """Let go of the device buffers of the tree, keeping its
+        structure: every ``jax.Array`` leaf becomes a
+        ``jax.ShapeDtypeStruct`` of the same shape, dtype and sharding.
+
+        ``restore`` calls this, once it has read where every leaf has to
+        land, when the restored arrays would not fit on the device
+        beside the template they replace (a state above half of HBM).
+        Only references are dropped: an array that the caller still
+        holds elsewhere stays alive and valid. If the restore then
+        fails, the tree holds shapes, which a second ``restore`` takes
+        as its target all the same.
+        """
+        import jax
+
+        from ..io_preparer import abstract_of
+
+        self.tree = jax.tree.map(abstract_of, self.tree)
+
 
 class FnStateful:
     """Builds a Stateful from getter/setter callables — for state owned by
