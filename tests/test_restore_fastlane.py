@@ -98,7 +98,7 @@ def test_pool_disabled_by_env(monkeypatch):
 
 def test_pool_capacity_wait_notes_pool_wait_and_never_deadlocks():
     pool = staging_pool.StagingPool(capacity_bytes=4096, max_wait_s=0.2)
-    profile = _cprof.ConsumeProfile()
+    profile = _cprof.PhaseProfile()
     first = pool.acquire(4096)
     # Release from another thread while the second acquire waits. Pool
     # acquisitions happen inside consumer executor bodies, i.e. inside

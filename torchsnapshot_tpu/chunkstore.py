@@ -851,7 +851,7 @@ def decode_and_verify_chunk(
     equals the logical length falls back to identity (see
     ChunkStager's unsuitable-payload degrade) — the fingerprint check
     still gates the bytes. ``profile`` (a
-    ``telemetry.consume_profile.ConsumeProfile``, or None) splits the
+    ``telemetry.consume_profile.PhaseProfile``, or None) splits the
     chunk's decode vs verify cost for the restore micro-profiler.
 
     ``out`` (an exactly-``n``-byte writable memoryview, or None) is the

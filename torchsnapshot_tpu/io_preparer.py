@@ -36,6 +36,7 @@ import asyncio
 import logging
 import os
 import threading
+import time
 from concurrent.futures import Executor
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -314,6 +315,42 @@ def _on_h2d_engine_thread() -> bool:
     return threading.current_thread().name.startswith("tpusnapshot-h2d")
 
 
+async def _on_consume_executor(
+    executor: Optional[Executor],
+    body: Callable[[], Any],
+    profile: Optional[Any],
+    nbytes: int,
+) -> Any:
+    """Run a consume's blocking ``body`` on the scheduler's consume
+    executor (inline without one) and note, into the restore's profile,
+    the two hops that the consume wall holds besides the body:
+    ``executor_wait``, dispatched → a thread of the executor starts it,
+    and ``loop_wait``, body done → the event loop resumes this task."""
+    if executor is None:
+        return body()
+    dispatched = started = ended = time.monotonic()
+
+    def _timed() -> Any:
+        nonlocal started, ended
+        started = time.monotonic()
+        try:
+            return body()
+        finally:
+            ended = time.monotonic()
+
+    try:
+        return await asyncio.get_running_loop().run_in_executor(
+            executor, _timed
+        )
+    finally:
+        _cprof.note_interval(
+            profile, "executor_wait", dispatched, started, nbytes
+        )
+        _cprof.note_interval(
+            profile, "loop_wait", ended, time.monotonic(), nbytes
+        )
+
+
 class ArrayBufferStager(BufferStager):
     """Stages a device (or host) array into raw payload bytes.
 
@@ -340,6 +377,10 @@ class ArrayBufferStager(BufferStager):
         if nbytes is None:
             nbytes = int(np.dtype(data.dtype).itemsize * np.prod(data.shape))
         self._nbytes = nbytes
+        # The take's phase profile, captured where the stager is built
+        # (the taking thread): an async take's drain stages on threads
+        # of its own, after the call returned.
+        self._profile = _cprof.current("stage")
         if eager_host_copy:
             # Small arrays: start the whole-array async copy now so the
             # transfer overlaps with scheduling. Large arrays skip this —
@@ -379,22 +420,35 @@ class ArrayBufferStager(BufferStager):
         return await loop.run_in_executor(executor, self._stage_sync)
 
     def _stage_sync(self) -> BufferType:
+        # Every stretch below is a sub-step of the take's phase profile
+        # (telemetry/consume_profile.py): a note a leaf, and a
+        # ``stage.<name>`` span while tracing is enabled.
+        with _cprof.wall(self._profile):
+            return self._stage_phases()
+
+    def _stage_phases(self) -> BufferType:
+        profile = self._profile
         data = self._data
+        nbytes = self._nbytes
         if self._chunk_slices is not None:
-            data = data[self._chunk_slices]
+            with _cprof.substep(profile, "slice", nbytes):
+                data = data[self._chunk_slices]
         if _should_chunk_transfer(data):
-            host = _parallel_device_get(data)
+            host = _parallel_device_get(data, profile)
         else:
-            host = np.asarray(data)  # D2H for jax arrays; no-op for numpy
-        host = np.ascontiguousarray(host)
-        if (
-            isinstance(self._data, np.ndarray)
-            and not self._owns_data
-            and np.shares_memory(host, self._data)
-        ):
-            # User-owned mutable host memory: copy so the staged buffer is
-            # a consistent cut (jax.Arrays are immutable — no copy needed).
-            host = host.copy()
+            with _cprof.substep(profile, "d2h", nbytes):
+                host = np.asarray(data)  # D2H for jax arrays; no-op for numpy
+        with _cprof.substep(profile, "copy", nbytes):
+            host = np.ascontiguousarray(host)
+            if (
+                isinstance(self._data, np.ndarray)
+                and not self._owns_data
+                and np.shares_memory(host, self._data)
+            ):
+                # User-owned mutable host memory: copy so the staged buffer
+                # is a consistent cut (jax.Arrays are immutable — no copy
+                # needed).
+                host = host.copy()
         # Drop the source reference: once the payload is on host, the
         # device buffer (ours after a device-staged async take, or the
         # caller's) no longer needs to be pinned by this stager.
@@ -404,7 +458,8 @@ class ArrayBufferStager(BufferStager):
         # and it is zero-copy.
         payload = memoryview(host.reshape(-1).view(np.uint8))
         if self._compression is not None:
-            payload = compress_payload(payload, self._compression)
+            with _cprof.substep(profile, "compress", nbytes):
+                payload = compress_payload(payload, self._compression)
             if self._entry is not None:
                 self._entry.compression = self._compression
         if self._entry is not None:
@@ -415,7 +470,8 @@ class ArrayBufferStager(BufferStager):
             # only after execute_write_reqs finishes (snapshot.py _drain) —
             # staging may run entirely in that background drain under a
             # device-staged cut.
-            self._entry.checksum = compute_checksum(payload)
+            with _cprof.substep(profile, "checksum", len(payload)):
+                self._entry.checksum = compute_checksum(payload)
         return payload
 
     def get_staging_cost_bytes(self) -> int:
@@ -544,11 +600,9 @@ class ObjectBufferConsumer(BufferConsumer):
                 ):
                     return bytes_to_object(raw)
 
-        if executor is not None:
-            loop = asyncio.get_running_loop()
-            obj = await loop.run_in_executor(executor, _load)
-        else:
-            obj = _load()
+        obj = await _on_consume_executor(
+            executor, _load, self._profile, len(buf)
+        )
         self._callback(obj)
 
     def get_consuming_cost_bytes(self) -> int:
@@ -723,11 +777,9 @@ class _ChunkCopyConsumer(BufferConsumer):
                 if self._on_done is not None:
                     self._on_done()
 
-        if executor is not None:
-            loop = asyncio.get_running_loop()
-            await loop.run_in_executor(executor, _copy_and_signal)
-        else:
-            _copy_and_signal()
+        await _on_consume_executor(
+            executor, _copy_and_signal, self._profile, len(buf)
+        )
 
     def get_consuming_cost_bytes(self) -> int:
         return self._cost
@@ -868,11 +920,7 @@ class _SplitObjectReadState(_PooledAssemblyState):
                     # Disjoint ranges: concurrent executor threads never overlap.
                     memoryview(self._buf)[start:end] = buf
 
-        if executor is not None:
-            loop = asyncio.get_running_loop()
-            await loop.run_in_executor(executor, _copy)
-        else:
-            _copy()
+        await _on_consume_executor(executor, _copy, self._profile, len(buf))
         with self._lock:
             self._remaining -= 1
             last = self._remaining == 0
@@ -1076,35 +1124,43 @@ class _StreamingSplitState(_SplitObjectReadState):
         executor: Optional[Executor] = None,
     ) -> None:
         def _consume_part() -> None:
+            profile = self._profile
             with _cprof.consume_section():
-                if len(buf) != end - start:
-                    raise RuntimeError(
-                        f"Ranged sub-read returned {len(buf)} bytes for "
-                        f"[{start}, {end}) — object shorter than the manifest "
-                        f"implies (truncated or torn)."
-                    )
-                flat = np.frombuffer(buf, dtype=self._np_dtype)
-                with self._lock:
-                    self._part_refs[start] = (
-                        2 if self._crc is not None else 1
-                    )
+                with _cprof.substep(profile, "view", len(buf)):
+                    if len(buf) != end - start:
+                        raise RuntimeError(
+                            f"Ranged sub-read returned {len(buf)} bytes for "
+                            f"[{start}, {end}) — object shorter than the "
+                            f"manifest implies (truncated or torn)."
+                        )
+                    flat = np.frombuffer(buf, dtype=self._np_dtype)
+                    with self._lock:
+                        self._part_refs[start] = (
+                            2 if self._crc is not None else 1
+                        )
                 # Submit the H2D on the overlap engine FIRST: the
                 # transfer rides the link while the crc fold below runs
                 # on host and later sub-reads are still arriving.
-                fut = h2d_pipeline().submit(
-                    flat, self._device, profile=self._profile
-                )
-                if self._register_transfer is not None:
-                    self._register_transfer(fut)
-                fut.add_done_callback(
-                    lambda f, s=start, n=len(buf): self._transfer_done(
-                        s, n, f
+                with _cprof.substep(profile, "h2d_submit", len(buf)):
+                    fut = h2d_pipeline().submit(
+                        flat, self._device, profile=profile
                     )
-                )
+                    if self._register_transfer is not None:
+                        self._register_transfer(fut)
+                    fut.add_done_callback(
+                        lambda f, s=start, n=len(buf): self._transfer_done(
+                            s, n, f
+                        )
+                    )
                 if self._crc is not None:
-                    with _cprof.substep(self._profile, "verify", len(buf)):
-                        drained: List[Tuple[int, int]] = []
-                        with self._lock:
+                    drained: List[Tuple[int, int]] = []
+                    # The fold is in order and under the stream's lock:
+                    # the wait for the lock (other parts of this object
+                    # folding) is ``verify_wait``, the fold ``verify``.
+                    with _cprof.substep(profile, "verify_wait", len(buf)):
+                        self._lock.acquire()
+                    try:
+                        with _cprof.substep(profile, "verify", len(buf)):
                             self._stash[start] = buf
                             while self._next_off in self._stash:
                                 off = self._next_off
@@ -1113,31 +1169,35 @@ class _StreamingSplitState(_SplitObjectReadState):
                                 self._next_off += len(b)
                                 drained.append((off, len(b)))
                             stream_done = self._next_off >= self.nbytes
-                        # Re-credit drained parts outside the state lock
-                        # (the budget cell takes its own lock).
-                        for off, n in drained:
-                            self._part_release(off, n)
-                        if stream_done:
-                            actual = self._crc.tag()
-                            if actual != self._checksum:
-                                with self._lock:
-                                    self._failed = True
-                                raise RuntimeError(
-                                    f"Checksum mismatch: stored object is "
-                                    f"corrupt (expected {self._checksum}, "
-                                    f"got {actual})."
-                                )
+                    finally:
+                        self._lock.release()
+                    # Re-credit drained parts outside the state lock
+                    # (the budget cell takes its own lock).
+                    for off, n in drained:
+                        self._part_release(off, n)
+                    if stream_done:
+                        actual = self._crc.tag()
+                        if actual != self._checksum:
                             with self._lock:
-                                self._crc_ok = True
+                                self._failed = True
+                            raise RuntimeError(
+                                f"Checksum mismatch: stored object is "
+                                f"corrupt (expected {self._checksum}, "
+                                f"got {actual})."
+                            )
+                        with self._lock:
+                            self._crc_ok = True
 
-        if executor is not None:
-            loop = asyncio.get_running_loop()
-            await loop.run_in_executor(executor, _consume_part)
-        else:
-            _consume_part()
+        await _on_consume_executor(
+            executor, _consume_part, self._profile, len(buf)
+        )
         with self._lock:
             self._remaining -= 1
-        self._maybe_complete()
+        # Inside the scheduler's consume span, on the event loop: a
+        # finalize that this completion triggers (the transfers were all
+        # done) is the consume wall's own, not overlapped work.
+        with _cprof.consume_section():
+            self._maybe_complete()
 
 
 class _SubRangeConsumer(BufferConsumer):
@@ -1318,11 +1378,9 @@ class _ContentChunksReadState(_PooledAssemblyState):
                     ):
                         out[: len(logical)] = logical
 
-        if executor is not None:
-            loop = asyncio.get_running_loop()
-            await loop.run_in_executor(executor, _consume_part)
-        else:
-            _consume_part()
+        await _on_consume_executor(
+            executor, _consume_part, self._profile, len(buf)
+        )
         with self._lock:
             self._remaining -= 1
             last = self._remaining == 0
@@ -1879,7 +1937,11 @@ class ArrayRestorePlan:
 
     def _finalize_now(self) -> None:
         try:
-            self._await_pipeline()
+            # Blocks until the overlap engine has landed this leaf's
+            # transfers: inside a consume when the leaf's last consume
+            # triggered the finalize.
+            with _cprof.substep(self._profile, "h2d_wait"):
+                self._await_pipeline()
             with tracing.adopt_trace(self._trace_id), tracing.span(
                 "assemble"
             ):

@@ -30,6 +30,8 @@ import numpy as np
 
 import jax
 
+from ..telemetry import consume_profile as _cprof
+
 _DEFAULT_TRANSFER_CHUNK_BYTES = 8 * 1024 * 1024
 _DEFAULT_TRANSFER_CONCURRENCY = 32
 
@@ -84,31 +86,47 @@ def should_chunk_transfer(arr: Any) -> bool:
     return nbytes >= 2 * transfer_chunk_bytes()
 
 
-def parallel_device_get(arr: jax.Array) -> np.ndarray:
-    """Gather ``arr`` to host via parallel chunked transfers."""
+def parallel_device_get(
+    arr: jax.Array, profile: Optional[_cprof.PhaseProfile] = None
+) -> np.ndarray:
+    """Gather ``arr`` to host via parallel chunked transfers.
+
+    ``profile`` (the take's phase profile, telemetry/consume_profile.py)
+    gets one note a chunk for each of ``slice``, ``d2h`` and ``copy``,
+    one ``alloc`` and one ``fetch_wait`` a leaf, and the fetches' own
+    thread-seconds as wall."""
     shape = tuple(arr.shape)
     dtype = np.dtype(arr.dtype)
     nbytes = dtype.itemsize * math.prod(shape)
     axis = max(range(len(shape)), key=lambda d: shape[d])
     n_chunks = min(shape[axis], max(1, -(-nbytes // transfer_chunk_bytes())))
-    out = np.empty(shape, dtype=dtype)
+    with _cprof.substep(profile, "alloc", nbytes):
+        out = np.empty(shape, dtype=dtype)
     bounds = [round(i * shape[axis] / n_chunks) for i in range(n_chunks + 1)]
+    row_nbytes = nbytes // shape[axis]
 
     def _fetch(lo: int, hi: int) -> None:
-        piece = jax.lax.slice_in_dim(arr, lo, hi, axis=axis)
-        sel = tuple(
-            slice(lo, hi) if d == axis else slice(None)
-            for d in range(len(shape))
-        )
-        out[sel] = np.asarray(piece)
+        chunk_nbytes = (hi - lo) * row_nbytes
+        with _cprof.wall(profile):
+            with _cprof.substep(profile, "slice", chunk_nbytes):
+                piece = jax.lax.slice_in_dim(arr, lo, hi, axis=axis)
+            sel = tuple(
+                slice(lo, hi) if d == axis else slice(None)
+                for d in range(len(shape))
+            )
+            with _cprof.substep(profile, "d2h", chunk_nbytes):
+                landed = np.asarray(piece)
+            with _cprof.substep(profile, "copy", chunk_nbytes):
+                out[sel] = landed
 
     pool = _get_transfer_pool()
-    futures = [
-        pool.submit(_fetch, bounds[i], bounds[i + 1])
-        for i in range(n_chunks)
-        if bounds[i] < bounds[i + 1]
-    ]
-    errors = [f.exception() for f in futures]
+    with _cprof.substep(profile, "fetch_wait", nbytes):
+        futures = [
+            pool.submit(_fetch, bounds[i], bounds[i + 1])
+            for i in range(n_chunks)
+            if bounds[i] < bounds[i + 1]
+        ]
+        errors = [f.exception() for f in futures]
     for err in errors:
         if err is not None:
             raise err
@@ -263,8 +281,6 @@ class H2DPipeline:
         faultline's SimulatedCrash BaseException) resolve into the
         future — callers must surface them before publishing anything
         assembled from sibling transfers."""
-        from ..telemetry import consume_profile as _cprof
-
         nbytes = int(getattr(host, "nbytes", len(host)))
 
         def _transfer() -> Any:

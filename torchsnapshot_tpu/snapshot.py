@@ -50,7 +50,7 @@ from typing import Any, Callable, Dict, List, Optional, Set, Tuple
 
 from . import telemetry, tracing
 from .coord import Coordinator, barrier_compat, get_coordinator
-from .telemetry import consume_profile as _consume_profile
+from .telemetry import consume_profile as _phase_profile
 from .telemetry import export as telemetry_export
 from .telemetry import goodput as goodput_acct
 from .telemetry import ledger as runledger
@@ -199,7 +199,9 @@ class Snapshot:
             # bytes, however late — carries it.
             with goodput_acct.blocked("sync_take"), tracing.trace_scope(
                 "take"
-            ), tracing.span("Snapshot.take", path=path):
+            ), tracing.span("Snapshot.take", path=path), _phase_profile.scope(
+                "stage"
+            ):
                 merged = cls._take_impl(
                     path=path,
                     app_state=app_state,
@@ -280,7 +282,7 @@ class Snapshot:
             # async tier-down appears in this take's causal trace.
             with goodput_acct.blocked("async_stall"), tracing.trace_scope(
                 "async_take"
-            ):
+            ), _phase_profile.scope("stage"):
                 cls._take_impl(
                     path=path,
                     app_state=app_state,
@@ -363,6 +365,12 @@ class Snapshot:
         ).inc()
         watch.set_phase("capture")
         capture_t0 = time.monotonic()
+        # Take-side phase profile (telemetry/consume_profile.py): every
+        # array stager built below captures this scope and notes the
+        # sub-steps of its staging (alloc/slice/d2h/copy/checksum) into
+        # it, wherever the staging runs: inside this call on the
+        # host-staging route, in the background drain after clones.
+        stage_profile = _phase_profile.current("stage")
 
         manifest: Manifest = {}
         pending_write_reqs: List[WriteReq] = []
@@ -520,6 +528,7 @@ class Snapshot:
                         )
                     )
                 recorder.note_pipeline(write_stats)
+                _note_stage_phases(recorder, stage_profile)
                 if chunk_ctx is not None:
                     # Stored (post-codec) sizes exist only after the
                     # writes: fold the chunk pass's accounting into the
@@ -720,6 +729,7 @@ class Snapshot:
                         "write", time.monotonic() - drain_t0
                     )
                     recorder.note_pipeline(write_stats)
+                    _note_stage_phases(recorder, stage_profile)
                     if chunk_ctx is not None:
                         # Stored sizes exist only post-write; fold the
                         # chunk accounting in before the rank summary
@@ -807,23 +817,35 @@ class Snapshot:
         fingerprint (snapshots taken without ``fingerprint=True``) are
         skipped; a mismatch raises with the offending paths.
         """
+        entered = time.monotonic()
         coordinator = get_coordinator(coord if coord is not None else self._coord)
         rank = coordinator.get_rank()
         storage = self._open_storage()
         try:
+            # The consume micro-profiler's scope (telemetry/
+            # consume_profile.py): every buffer consumer built below
+            # captures it and notes its sub-steps (decode/verify/
+            # reassemble/device_put/…) into it — the WHERE inside
+            # consume. Always on (a monotonic pair per chunk sub-step).
             with goodput_acct.blocked("restore"), tracing.trace_scope(
                 "restore"
-            ), tracing.span("Snapshot.restore", path=self.path):
+            ), tracing.span(
+                "Snapshot.restore", path=self.path
+            ), _phase_profile.scope("consume") as consume_profile:
                 return self._restore_impl(
                     app_state, coordinator, rank, storage, paths,
                     verify_device=verify_device,
+                    consume_profile=consume_profile,
+                    entered=entered,
                 )
         finally:
             storage.close()
 
     def _restore_impl(
         self, app_state, coordinator, rank, storage, paths,
-        verify_device: bool = False,
+        verify_device: bool,
+        consume_profile: Any,
+        entered: float,
     ):
         # The restore() wrapper owns the storage plugin's lifetime.
         metadata = self._read_snapshot_metadata(storage)
@@ -861,13 +883,10 @@ class Snapshot:
         from .snapserve import client as _snapserve_client
 
         read_plane_token = _snapserve_client.restore_stats_begin()
-        # Consume micro-profiler (telemetry/consume_profile.py): every
-        # buffer consumer built below captures this scope and notes its
-        # sub-steps (decode/verify/reassemble/device_put/…) into it —
-        # the WHERE inside consume that the consume-dominated-restore
-        # doctor rule could not name before. Always on (the accounting
-        # is a monotonic pair per chunk sub-step).
-        consume_prof_token = _consume_profile.begin()
+        # What lies around the read pipeline, as the phases ``plan``
+        # and ``finalize`` of the report and as ``restore.plan`` /
+        # ``restore.finalize`` spans.
+        stretches = _RestoreStretches(recorder, entered)
 
         app_state = dict(app_state)
         rng_key, rng_stateful = _pop_rng_state(app_state)
@@ -892,6 +911,7 @@ class Snapshot:
                     verify_jobs_out=verify_jobs if verify_device else None,
                     stats=read_stats,
                     progress=watch,
+                    stretches=stretches,
                 )
             coordinator.barrier()
 
@@ -911,6 +931,7 @@ class Snapshot:
                 verify_jobs_out=verify_jobs if verify_device else None,
                 stats=read_stats,
                 progress=watch,
+                stretches=stretches,
             )
         watch.finish()
         tier_summary = _hottier.restore_stats_collect(tier_token)
@@ -921,14 +942,18 @@ class Snapshot:
         )
         if read_plane_summary is not None:
             recorder.note(read_plane=read_plane_summary)
+        stretches.end("finalize")
         self._finish_restore_report(
             recorder,
             read_stats,
             storage,
             rank,
             coordinator,
-            consume_prof_token=consume_prof_token,
+            consume_profile=consume_profile,
         )
+        # The report's own gather and write: in the span, not in the
+        # report it has just written.
+        stretches.end("finalize")
         if verify_device:
             verified, skipped = _verify_restored_fingerprints(verify_jobs)
             logger.info(
@@ -955,7 +980,7 @@ class Snapshot:
         storage: StoragePlugin,
         rank: int,
         coordinator: Coordinator,
-        consume_prof_token: Any = None,
+        consume_profile: Any,
     ) -> None:
         """Fold the read pipeline's stats into the flight recorder,
         gather every rank's summary over the coordinator (the restore
@@ -992,9 +1017,7 @@ class Snapshot:
         # consume GB/s as a fraction of the one-shot H2D probe — the
         # hardware bound ROADMAP item 1's rewrite is judged against.
         try:
-            profile_block = _consume_profile.collect(
-                consume_prof_token, consume_s=consume_s
-            )
+            profile_block = consume_profile.block(wall_s=consume_s)
             if profile_block is not None:
                 consumed_bytes = int(consume_agg.get("bytes", 0))
                 profile_block["bytes"] = consumed_bytes
@@ -2825,7 +2848,16 @@ def _prestage_write_reqs(
     globs and every other collective argument).
     """
     coordinator = get_coordinator(coordinator)
-    cloned = stage != "host" and device_clone_write_reqs(write_reqs)
+    cloned = False
+    if stage != "host":
+        # The attempt, kept or not: a capture that cannot clone pays for
+        # the clones that filled the device before it falls back.
+        clone_t0 = time.monotonic()
+        with tracing.span("capture.clone"):
+            cloned = device_clone_write_reqs(write_reqs)
+        profile = _phase_profile.current("stage")
+        if profile is not None:
+            profile.note("clone", time.monotonic() - clone_t0)
     all_cloned = all(coordinator.all_gather_object(cloned))
     if all_cloned and stage != "host":
         return None
@@ -2862,6 +2894,16 @@ def _prestage_write_reqs(
     return total
 
 
+def _note_stage_phases(recorder: Any, profile: Any) -> None:
+    """The take report's ``stage_phases`` block, of the restore's
+    ``consume_profile`` block's shape: sub-steps with ``other`` sum to
+    ``stage_s``, the thread-seconds inside ``_stage_sync``. Called once
+    the write pipeline has drained, so every stager has staged."""
+    block = profile.block() if profile is not None else None
+    if block is not None:
+        recorder.note(stage_phases=block)
+
+
 class _PreStagedStager:
     def __init__(self, buf: Any) -> None:
         self._buf = buf
@@ -2882,6 +2924,31 @@ class _PreStagedStager:
         return len(self._buf)
 
 
+class _RestoreStretches:
+    """The stretches of a restore outside its read pipeline, Stateful by
+    Stateful: ``plan`` from ``restore`` entered (or the last Stateful
+    loaded) until the first read is dispatched — metadata, manifest,
+    templates, the read plan — and ``finalize`` from the last consume
+    done until the Stateful is loaded (device concatenation,
+    ``load_state_dict``, template release) or ``restore`` returns. Each
+    is a phase of the report and, while tracing is enabled, a
+    ``restore.<stretch>`` span."""
+
+    def __init__(self, recorder: Any, began: float) -> None:
+        self._recorder = recorder
+        self._since = began
+
+    def end(self, stretch: str) -> None:
+        now = time.monotonic()
+        tracing.interval(f"restore.{stretch}", self._since, now)
+        self._recorder.add_phase(stretch, now - self._since)
+        self._since = now
+
+    def skip(self) -> None:
+        """What ran since the last mark has spans of its own."""
+        self._since = time.monotonic()
+
+
 def _load_stateful(
     key: str,
     stateful: Stateful,
@@ -2895,6 +2962,8 @@ def _load_stateful(
     verify_jobs_out: Optional[List[Tuple[str, Entry, Any]]] = None,
     stats: Optional[Dict[str, Any]] = None,
     progress: Optional[Any] = None,
+    *,
+    stretches: "_RestoreStretches",
 ) -> int:
     """Returns the number of leaves restored (callers detect no-op filters)."""
     # In-place restore strategy (reference snapshot.py:374-381): the
@@ -2968,6 +3037,7 @@ def _load_stateful(
                 stats.get("template_released_bytes", 0) + released
             )
 
+    stretches.end("plan")
     asyncio.run(
         execute_read_reqs(
             read_reqs,
@@ -2979,6 +3049,7 @@ def _load_stateful(
             progress=progress,
         )
     )
+    stretches.skip()
     assemble_t0 = time.monotonic()
     for finalize in finalizers:
         finalize()
@@ -3013,6 +3084,7 @@ def _load_stateful(
         inflate_manifest.update(snapshot_containers)
     new_state_dict = inflate(inflate_manifest, flattened, prefix=key)
     stateful.load_state_dict(new_state_dict)
+    stretches.end("finalize")
     return len(selected)
 
 

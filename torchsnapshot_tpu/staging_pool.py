@@ -16,7 +16,7 @@ exact-size buffers keyed by the restore plan's region/object sizes
 handful of shapes — so exact-size reuse hits). Concurrent restores
 share the one pool; attribution stays per-restore because the
 ``pool_wait`` sub-step is noted into the caller's captured
-:class:`~torchsnapshot_tpu.telemetry.consume_profile.ConsumeProfile`.
+:class:`~torchsnapshot_tpu.telemetry.consume_profile.PhaseProfile`.
 
 Budget contract (the fastlane accounting fix): a lease carries at most
 ONE scheduler budget re-credit, attached via
@@ -187,7 +187,7 @@ class StagingPool:
 
     # ------------------------------------------------------------ acquire
     def acquire(
-        self, nbytes: int, profile: Optional["_cprof.ConsumeProfile"] = None
+        self, nbytes: int, profile: Optional["_cprof.PhaseProfile"] = None
     ) -> StagingLease:
         """A buffer of exactly ``nbytes``, reused when the pool holds
         one. At capacity (outstanding + request past the cap while

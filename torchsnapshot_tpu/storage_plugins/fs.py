@@ -15,7 +15,7 @@ import threading
 import time
 from typing import Optional, Set, Tuple
 
-from .. import telemetry
+from .. import telemetry, tracing
 from ..io_types import IOReq, StoragePlugin, emit_storage_op
 
 
@@ -204,15 +204,23 @@ class FSStoragePlugin(StoragePlugin):
             with self._dirty_lock:
                 self._dirty_dirs.add(os.path.dirname(full))
 
-    def _read_sync(self, io_req: IOReq) -> None:
+    def _read_sync(
+        self, io_req: IOReq, trace_id: Optional[str] = None
+    ) -> None:
         full = os.path.join(self.root, io_req.path)
-        with open(full, "rb") as f:
-            if io_req.byte_range is not None:
-                start, end = io_req.byte_range
-                f.seek(start)
-                payload = f.read(end - start)
-            else:
-                payload = f.read()
+        # Inside the scheduler's ``read`` span, which also holds the
+        # wait for a thread of the loop's executor: the open and the
+        # read itself, apart, under the trace id of whoever asked.
+        with tracing.adopt_trace(trace_id):
+            with tracing.span("read.open", path=io_req.path):
+                f = open(full, "rb")
+            with f, tracing.span("read.io", path=io_req.path):
+                if io_req.byte_range is not None:
+                    start, end = io_req.byte_range
+                    f.seek(start)
+                    payload = f.read(end - start)
+                else:
+                    payload = f.read()
         # Return via `data`: zero-copy for consumers. Callers that want the
         # BytesIO interface read io_req.data themselves (wrapping here
         # would memcpy every payload).
@@ -230,7 +238,9 @@ class FSStoragePlugin(StoragePlugin):
     async def read(self, io_req: IOReq) -> None:
         loop = asyncio.get_running_loop()
         t0 = time.monotonic()
-        await loop.run_in_executor(None, self._read_sync, io_req)
+        await loop.run_in_executor(
+            None, self._read_sync, io_req, tracing.current_trace_id()
+        )
         telemetry.record_storage_op(
             "fs", "read", time.monotonic() - t0, _payload_nbytes(io_req)
         )

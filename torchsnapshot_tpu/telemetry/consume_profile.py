@@ -1,65 +1,110 @@
-"""Consume micro-profiler: sub-step attribution inside the restore path.
+"""Phase profile: sub-step attribution inside the restore's consume path
+and inside the take's staging path, by one accumulator.
 
-The flight recorder and the ``consume-dominated-restore`` doctor rule
-can say a restore spent 176s in ``consume`` against 0.76s of ``read``
-(BENCH_r05) — but not WHERE inside consume the time went, which is the
-number the streaming-restore rewrite (ROADMAP item 1) must be planned
-from and certified against. This module is that number: an always-on,
-contextvar-scoped accumulator the restore root opens and every buffer
-consumer notes into, at per-leaf/per-chunk granularity:
+The flight recorder can say a restore spent 176s in ``consume`` against
+0.76s of ``read`` (BENCH_r05), and the benchmark that ``async_save``
+blocked for 3.1 s (PERF.md section 5) — but not WHERE inside consume, or
+inside staging, the time went. This module is that number: an always-on,
+contextvar-scoped :class:`PhaseProfile` that the restore root (kind
+``"consume"``) or the take root (kind ``"stage"``) opens and that every
+buffer consumer, or every array stager, notes into at per-leaf/per-chunk
+granularity. While ``tracing`` is enabled each note is also a span,
+``consume.<sub-step>`` or ``stage.<sub-step>``.
 
-==================  ====================================================
-sub-step            what it times
-==================  ====================================================
-read_wait           a completed read's payload sitting in the scheduler
-                    queue before its consume dispatched (budget / device-
-                    budget / executor pressure — NOT part of consume wall)
-deserialize         pickled-object loads (``bytes_to_object``) and raw
-                    byte→array reinterpretation
-decode              codec work: ``decompress_payload`` and chunk-store
-                    codec decode (zlib/zstd/int8)
-verify              integrity: checksum verification, streaming crc
-                    folds, content-fingerprint checks
-reassemble          host memcpy: scattering chunk views into region
-                    buffers, splicing ranged sub-reads into assembly
-                    buffers
-device_put          H2D transfers issued from INSIDE consume executors
-                    (small-region batched puts at a consume-triggered
-                    finalize)
-staging_release     freeing assembly/staging buffers and re-crediting
-                    scheduler budget reservations
-pool_wait           waiting for a staging-pool buffer at pool capacity
-                    (staging_pool.py — budget pressure made visible)
-h2d_overlap         the overlap engine's H2D transfer wall
-                    (ops/transfer.py H2DPipeline) — UNION time across
-                    concurrent workers so bytes/seconds is delivered
-                    link GB/s; concurrent with reads/consumes, NOT
-                    part of consume wall
-overlap_other       in-consume-named work that ran outside any consume
-                    executor (engine-triggered finalize placement,
-                    donation waits) — beside the wall, kept separate
-                    so h2d_overlap's GB/s certificate stays pure
-other               consume wall the sub-steps above did not account
-                    for (event-loop/executor scheduling, GIL waits) —
-                    computed at collect time so the breakdown SUMS to
-                    the consume wall exactly
-==================  ====================================================
+======================  ================================================
+restore (``consume.``)  what it times
+======================  ================================================
+read_wait               a completed read's payload sitting in the
+                        scheduler queue before its consume dispatched
+                        (budget / device-budget / executor pressure —
+                        NOT part of consume wall)
+executor_wait           consume dispatched → one of the consume
+                        executor's threads starts it
+view                    the length check and the ``np.frombuffer`` view
+                        of a streamed part
+h2d_submit              ``h2d_pipeline().submit`` as the consume thread
+                        sees it, with the done-callback's registration
+deserialize             pickled-object loads (``bytes_to_object``) and
+                        raw byte→array reinterpretation
+decode                  codec work: ``decompress_payload`` and chunk-
+                        store codec decode (zlib/zstd/int8)
+verify_wait             waiting for a streamed object's lock before a
+                        crc fold (the fold is in order, so one object's
+                        parts queue behind each other here)
+verify                  integrity work alone: ``verify_checksum``, the
+                        crc32 fold of a streamed object (without the
+                        wait for its lock), content-fingerprint checks
+reassemble              host memcpy: scattering chunk views into region
+                        buffers, splicing ranged sub-reads into assembly
+                        buffers
+h2d_wait                a leaf's finalize waiting for the overlap engine
+                        to land the transfers it was handed (inside the
+                        consume that completed the leaf)
+device_put              H2D transfers issued from INSIDE consume
+                        executors (small-region batched puts at a
+                        consume-triggered finalize)
+staging_release         freeing assembly/staging buffers and re-crediting
+                        scheduler budget reservations
+pool_wait               waiting for a staging-pool buffer at pool
+                        capacity (staging_pool.py)
+loop_wait               the consume thread done → the event loop
+                        resumes the consume task (the loop is
+                        one thread; reads, consumes and progress ticks
+                        all complete on it)
+h2d_overlap             the overlap engine's H2D transfer wall
+                        (ops/transfer.py H2DPipeline) — UNION time across
+                        concurrent workers so bytes/seconds is delivered
+                        link GB/s; concurrent with reads/consumes, NOT
+                        part of consume wall
+overlap_other           in-consume-named work that ran outside any
+                        consume executor (engine-triggered finalize
+                        placement, donation waits) — beside the wall
+other                   consume wall the sub-steps above did not account
+                        for — computed at collect time so the breakdown
+                        SUMS to the consume wall exactly
+======================  ================================================
+
+======================  ================================================
+take (``stage.``)       what it times (``ArrayBufferStager._stage_sync``
+                        and ``ops/transfer.parallel_device_get._fetch``)
+======================  ================================================
+alloc                   the ``np.empty`` of a chunked leaf's assembly
+                        buffer
+slice                   dispatch of the device slice: one
+                        ``jax.lax.slice_in_dim`` a chunk, and
+                        ``data[chunk_slices]`` of a subdivided shard
+d2h                     slice dispatched → bytes on the host
+                        (``np.asarray``), a chunk or a whole small leaf
+copy                    host memcpy: ``out[sel] = piece``,
+                        ``np.ascontiguousarray``, the defensive copy of
+                        user numpy memory
+compress                ``compress_payload``
+checksum                ``compute_checksum`` of the payload
+fetch_wait              a staging thread waiting for its leaf's chunk
+                        fetches on the D2H pool (their own slice / d2h /
+                        copy are noted by the pool's threads)
+clone                   ``device_clone_write_reqs`` in the capture
+                        (span ``capture.clone``) — beside the wall
+other                   the rest of the thread-seconds inside
+                        ``_stage_sync`` and ``_fetch``: the block sums to
+                        them exactly
+======================  ================================================
 
 Scoping matches the snapserve read-plane attribution: the profile is a
-contextvar set in the restoring thread; consumers CAPTURE it (and the
-ambient trace id) at plan-build time — which happens in that thread —
-so notes from executor threads land in the right restore even with two
-restores in flight. Cost when nothing special is happening: one
-``time.monotonic()`` pair per noted sub-step per chunk, well under the
-<2% restore-wall budget bench's restore section enforces; sub-step
-tracing spans are emitted only while tracing is enabled.
+contextvar set in the restoring (or taking) thread; consumers and
+stagers CAPTURE it at plan-build time — which happens in that thread —
+so notes from executor threads, and from an async take's background
+drain, land in the right operation even with two in flight. Cost when
+nothing special is happening: one ``time.monotonic()`` pair and one lock
+per noted sub-step per chunk; spans are emitted only while tracing is
+enabled.
 """
 
 import contextvars
 import threading
 import time
 from contextlib import contextmanager
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Iterator, Optional
 
 from .. import tracing
 
@@ -72,13 +117,19 @@ from .. import tracing
 # fast path moved OFF the consume executors so it rides concurrently
 # with reads and decodes still in flight.
 IN_CONSUME_SUBSTEPS = (
+    "executor_wait",
+    "view",
+    "h2d_submit",
     "deserialize",
     "decode",
+    "verify_wait",
     "verify",
     "reassemble",
+    "h2d_wait",
     "device_put",
     "staging_release",
     "pool_wait",
+    "loop_wait",
 )
 # Beside-the-wall buckets: read_wait (scheduler queueing), h2d_overlap
 # (the overlap engine's transfers — union time, see overlap_span),
@@ -88,19 +139,48 @@ IN_CONSUME_SUBSTEPS = (
 # delivered-GB/s certificate is never polluted by finalize bytes).
 OVERLAP_SUBSTEPS = ("read_wait", "h2d_overlap", "overlap_other")
 SUBSTEPS = OVERLAP_SUBSTEPS + IN_CONSUME_SUBSTEPS
+# The take's side: thread-seconds inside ArrayBufferStager._stage_sync
+# and parallel_device_get's _fetch (their walls are the block's
+# ``stage_s``); ``clone`` is the capture's attempt at device clones,
+# beside that wall.
+STAGE_SUBSTEPS = (
+    "alloc",
+    "slice",
+    "d2h",
+    "copy",
+    "compress",
+    "checksum",
+    "fetch_wait",
+)
+# kind -> the sub-steps whose seconds, with ``other``, sum to the wall.
+_IN_WALL = {"consume": IN_CONSUME_SUBSTEPS, "stage": STAGE_SUBSTEPS}
 
 
-class ConsumeProfile:
-    """Thread-safe sub-step accumulator for ONE restore."""
+class PhaseProfile:
+    """Thread-safe sub-step accumulator for ONE restore (``kind``
+    "consume") or ONE take ("stage"); the kind is the prefix of the
+    spans its notes emit while tracing is enabled."""
 
-    __slots__ = ("_lock", "_agg", "trace_id", "_ov_active", "_ov_start")
+    __slots__ = (
+        "kind",
+        "_lock",
+        "_agg",
+        "_wall_s",
+        "trace_id",
+        "_ov_active",
+        "_ov_start",
+    )
 
-    def __init__(self) -> None:
+    def __init__(self, kind: str = "consume") -> None:
+        self.kind = kind
         self._lock = threading.Lock()
         # substep -> [count, seconds, bytes]
         self._agg: Dict[str, list] = {}
-        # Captured at begin() so executor-thread sub-step spans can
-        # stamp the restore's trace id without a contextvar handoff.
+        # Thread-seconds the noting code itself spent (``wall``): the
+        # take has no scheduler op that sums them, as the restore has.
+        self._wall_s = 0.0
+        # Captured here so executor-thread sub-step spans can stamp the
+        # operation's trace id without a contextvar handoff.
         self.trace_id = tracing.current_trace_id()
         # Union-time clock for the overlap engine: h2d_overlap seconds
         # count wall during which >= 1 transfer was in flight for THIS
@@ -118,6 +198,10 @@ class ConsumeProfile:
             entry[0] += 1
             entry[1] += seconds
             entry[2] += nbytes
+
+    def add_wall(self, seconds: float) -> None:
+        with self._lock:
+            self._wall_s += seconds
 
     def _overlap_enter(self) -> None:
         with self._lock:
@@ -147,9 +231,40 @@ class ConsumeProfile:
                 for substep, entry in sorted(self._agg.items())
             }
 
+    def block(self, wall_s: Optional[float] = None) -> Optional[Dict[str, Any]]:
+        """The flight-report block: ``substeps`` (count, thread-seconds,
+        bytes each), ``accounted_s`` and ``<kind>_s``, the wall they are
+        held against, with an ``other`` sub-step so that the in-wall
+        sub-steps plus ``other`` sum to the wall exactly. ``wall_s`` is
+        the scheduler's consume op seconds for a restore; a take's wall
+        is what :func:`wall` summed. None when nothing was noted (a
+        restore of primitives only)."""
+        substeps = self.summary()
+        if wall_s is None:
+            with self._lock:
+                wall_s = self._wall_s or None
+        if not substeps and not wall_s:
+            return None
+        accounted = sum(
+            substeps.get(s, {}).get("seconds", 0.0)
+            for s in _IN_WALL[self.kind]
+        )
+        block: Dict[str, Any] = {
+            "substeps": substeps,
+            "accounted_s": round(accounted, 6),
+        }
+        if wall_s is not None:
+            block[f"{self.kind}_s"] = round(wall_s, 6)
+            substeps["other"] = {
+                "count": 0,
+                "seconds": round(max(0.0, wall_s - accounted), 6),
+                "bytes": 0,
+            }
+        return block
 
-_SCOPE: "contextvars.ContextVar[Optional[ConsumeProfile]]" = (
-    contextvars.ContextVar("tpusnapshot_consume_profile", default=None)
+
+_SCOPE: "contextvars.ContextVar[Optional[PhaseProfile]]" = (
+    contextvars.ContextVar("tpusnapshot_phase_profile", default=None)
 )
 
 # Consume-section marker (thread-local): consumer executor bodies wrap
@@ -178,59 +293,49 @@ def in_consume_section() -> bool:
     return getattr(_SECTION, "active", False)
 
 
-def _route(name: str) -> str:
-    if name in IN_CONSUME_SUBSTEPS and not in_consume_section():
+def _route(profile: PhaseProfile, name: str) -> str:
+    if (
+        profile.kind == "consume"
+        and name in IN_CONSUME_SUBSTEPS
+        and not in_consume_section()
+    ):
         return "overlap_other"
     return name
 
 
-def begin() -> Tuple[ConsumeProfile, Any]:
-    """Open a per-restore profiling scope in the restoring thread."""
-    profile = ConsumeProfile()
-    return profile, _SCOPE.set(profile)
-
-
-def current() -> Optional[ConsumeProfile]:
-    """The active profile — captured by consumers at plan-build time."""
-    return _SCOPE.get()
-
-
-def collect(
-    token: Any, consume_s: Optional[float] = None
-) -> Optional[Dict[str, Any]]:
-    """Close the scope and build the flight-report block. ``consume_s``
-    (the scheduler's consume op seconds for this restore) yields the
-    ``other`` bucket, so the in-consume sub-steps plus ``other`` sum to
-    the consume wall exactly. None when nothing was noted (a restore of
-    primitives only)."""
-    if token is None:
-        return None
-    profile, var_token = token
+@contextmanager
+def scope(kind: str) -> Iterator[PhaseProfile]:
+    """Open one operation's profile in the restoring (taking) thread;
+    the scope closes with the block, the profile lives as long as
+    whoever captured it (an async take's drain notes into it after the
+    call returned)."""
+    profile = PhaseProfile(kind)
+    token = _SCOPE.set(profile)
     try:
-        _SCOPE.reset(var_token)
-    except ValueError:
-        pass  # reset from a different context: scope still collected
-    substeps = profile.summary()
-    if not substeps and not consume_s:
-        return None
-    block: Dict[str, Any] = {"substeps": substeps}
-    accounted = sum(
-        substeps.get(s, {}).get("seconds", 0.0) for s in IN_CONSUME_SUBSTEPS
-    )
-    block["accounted_s"] = round(accounted, 6)
-    if consume_s is not None:
-        block["consume_s"] = round(consume_s, 6)
-        other = max(0.0, consume_s - accounted)
-        block["substeps"]["other"] = {
-            "count": 0,
-            "seconds": round(other, 6),
-            "bytes": 0,
-        }
-    return block
+        yield profile
+    finally:
+        _SCOPE.reset(token)
+
+
+def current(kind: str = "consume") -> Optional[PhaseProfile]:
+    """The active profile of ``kind`` — captured by consumers and
+    stagers at plan-build time. None outside a scope, and for a stager
+    built inside a restore or a consumer built inside a take."""
+    profile = _SCOPE.get()
+    if profile is not None and profile.kind == kind:
+        return profile
+    return None
+
+
+def _span_args(profile: PhaseProfile, nbytes: int) -> Dict[str, Any]:
+    span_args: Dict[str, Any] = {"bytes": nbytes}
+    if profile.trace_id is not None:
+        span_args["trace"] = profile.trace_id
+    return span_args
 
 
 @contextmanager
-def overlap_span(profile: Optional[ConsumeProfile], nbytes: int = 0):
+def overlap_span(profile: Optional[PhaseProfile], nbytes: int = 0):
     """Time one overlap-engine transfer into ``h2d_overlap`` with
     UNION-time semantics: concurrent transfers for one restore advance
     the clock once, so bytes/seconds is the engine's delivered link
@@ -241,10 +346,9 @@ def overlap_span(profile: Optional[ConsumeProfile], nbytes: int = 0):
         yield
         return
     if tracing.enabled():
-        span_args: Dict[str, Any] = {"bytes": nbytes}
-        if profile.trace_id is not None:
-            span_args["trace"] = profile.trace_id
-        with tracing.span("consume.h2d_overlap", **span_args):
+        with tracing.span(
+            "consume.h2d_overlap", **_span_args(profile, nbytes)
+        ):
             profile._overlap_enter()
             try:
                 yield
@@ -260,24 +364,23 @@ def overlap_span(profile: Optional[ConsumeProfile], nbytes: int = 0):
 
 @contextmanager
 def substep(
-    profile: Optional[ConsumeProfile], name: str, nbytes: int = 0
+    profile: Optional[PhaseProfile], name: str, nbytes: int = 0
 ):
     """Time one sub-step into ``profile``. A plain passthrough when no
-    restore scope is active (``profile`` None) — verify()/read_object
-    paths reuse the instrumented consumers, and emitting
-    ``consume.<name>`` spans for them would hand summarize a bogus
-    consume-breakdown section for an operation that never restored.
-    While tracing is enabled, a span is emitted alongside the note,
-    stamped with the restore's trace id even from executor threads."""
+    scope is active (``profile`` None) — verify()/read_object paths
+    reuse the instrumented consumers, and emitting ``consume.<name>``
+    spans for them would hand summarize a bogus consume-breakdown
+    section for an operation that never restored. While tracing is
+    enabled, a ``<kind>.<name>`` span is emitted alongside the note,
+    stamped with the operation's trace id even from executor threads."""
     if profile is None:
         yield
         return
-    name = _route(name)
+    name = _route(profile, name)
     if tracing.enabled():
-        span_args: Dict[str, Any] = {"bytes": nbytes}
-        if profile.trace_id is not None:
-            span_args["trace"] = profile.trace_id
-        with tracing.span(f"consume.{name}", **span_args):
+        with tracing.span(
+            f"{profile.kind}.{name}", **_span_args(profile, nbytes)
+        ):
             t0 = time.monotonic()
             try:
                 yield
@@ -289,3 +392,41 @@ def substep(
         yield
     finally:
         profile.note(name, time.monotonic() - t0, nbytes)
+
+
+def note_interval(
+    profile: Optional[PhaseProfile],
+    name: str,
+    begin: float,
+    end: float,
+    nbytes: int = 0,
+) -> None:
+    """Note a sub-step whose two ends were read off ``time.monotonic()``
+    on different threads (a wait for an executor, for the event loop):
+    no ``with`` block can span it. Not routed: the caller knows on
+    which side of the consume wall the wait lies."""
+    if profile is None:
+        return
+    profile.note(name, end - begin, nbytes)
+    if tracing.enabled():
+        tracing.interval(
+            f"{profile.kind}.{name}",
+            begin,
+            end,
+            **_span_args(profile, nbytes),
+        )
+
+
+@contextmanager
+def wall(profile: Optional[PhaseProfile]):
+    """Count the block's thread-seconds into the profile's own wall
+    (the take's ``stage_s``). No span: the scheduler's ``stage`` span
+    already brackets it."""
+    if profile is None:
+        yield
+        return
+    t0 = time.monotonic()
+    try:
+        yield
+    finally:
+        profile.add_wall(time.monotonic() - t0)

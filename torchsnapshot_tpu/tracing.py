@@ -329,6 +329,32 @@ def span(name: str, **args: Any):
                 evs.append(end)
 
 
+def interval(name: str, begin: float, end: float, **args: Any) -> None:
+    """Record a span whose two ends the caller read off
+    ``time.monotonic()`` itself: a wait that begins on one thread and
+    ends on another, or a stretch of a function that no ``with`` block
+    brackets. The same async ``b``/``e`` pair as :func:`span`."""
+    evs = _events
+    if evs is None:
+        return
+    trace = _TRACE_CTX.get()
+    if trace is not None and "trace" not in args:
+        args = dict(args, trace=trace)
+    common = {
+        "name": name,
+        "cat": "snapshot",
+        "id": next(_span_ids),
+        "pid": os.getpid(),
+        "tid": threading.get_ident() & 0xFFFFFFFF,
+    }
+    first = dict(common, ph="b", ts=(begin - _t0) * 1e6)
+    if args:
+        first["args"] = args
+    with _lock:
+        evs.append(first)
+        evs.append(dict(common, ph="e", ts=(end - _t0) * 1e6))
+
+
 def instant(name: str, **args: Any) -> None:
     """Record a zero-duration marker (e.g. "manifest committed")."""
     if _events is None:
