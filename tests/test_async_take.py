@@ -477,3 +477,30 @@ def test_clone_oom_check_knob(tmp_path, monkeypatch):
     np.testing.assert_array_equal(
         np.asarray(target["m"].sd["w"]), np.arange(32.0)
     )
+
+
+def test_async_timeout_names_all_missing_ranks(tmp_path):
+    """_collect_completion_manifests' timeout error enumerates every
+    straggler rank, not just the first missing one."""
+    import asyncio
+
+    from torchsnapshot_tpu.manifest import SnapshotMetadata
+    from torchsnapshot_tpu.io_types import IOReq
+    from torchsnapshot_tpu.snapshot import _collect_completion_manifests
+    from torchsnapshot_tpu.storage_plugins.memory import MemoryStoragePlugin
+
+    storage = MemoryStoragePlugin()
+    nonce = "abc123"
+    # Ranks 0 and 2 completed; 1 and 3 never did.
+    for r in (0, 2):
+        doc = SnapshotMetadata(
+            version="v", world_size=4, manifest={}, take_id=nonce
+        ).to_yaml()
+        req = IOReq(path=f".completed/{nonce}/{r}")
+        req.buf.write(doc.encode())
+        asyncio.run(storage.write(req))
+
+    with pytest.raises(TimeoutError, match=r"rank\(s\) \[1, 3\]"):
+        asyncio.run(
+            _collect_completion_manifests(storage, 4, nonce, timeout_s=0.3)
+        )

@@ -555,33 +555,3 @@ def test_scheduler_budget_gauges_reset_after_pipeline(tmp_path):
         assert metrics[key] == 0.0  # reset on pipeline exit
         stalled = f'{_m.SCHED_BUDGET_STALLED}{{pipeline="{pipeline}"}}'
         assert metrics[stalled] == 0.0
-
-
-# ------------------------------------------------------- bench plumbing
-
-
-def test_bench_compare_gates_hot_tier_keys():
-    import importlib.util
-    import os as _os
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_compare",
-        _os.path.join(
-            _os.path.dirname(__file__), "..", "tools", "bench_compare.py"
-        ),
-    )
-    bc = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bc)
-    assert bc._self_test() == 0
-
-    base = {
-        "value": 1.0,
-        "hot_tier": {"hot_vs_durable": 7.5, "durability_lag_s": 0.8},
-        "every_step": {"hot": {"overhead_pct": 1.9}},
-    }
-    _, reg = bc.compare(
-        base,
-        dict(base, hot_tier={"hot_vs_durable": 7.5, "durability_lag_s": 2.0}),
-        0.2,
-    )
-    assert reg and "durability lag" in reg[0]

@@ -196,42 +196,6 @@ def test_timeline_skips_torn_lines(tmp_path, capsys):
     assert "torn/corrupt ledger line(s) skipped" in err
 
 
-# ------------------------------------------------------------ bench mode
-
-
-def _bench_doc(value, restore=2.0, gaps=None, wrapper=False):
-    doc = {
-        "metric": "snapshot_take_GBps",
-        "value": value,
-        "restore_GBps": restore,
-        "take_vs_ceiling": 0.9,
-        "restore_vs_ceiling": 0.8,
-        "gaps": gaps or [],
-    }
-    if wrapper:
-        return {"rc": 0, "tail": "noise\n" + json.dumps(doc) + "\n"}
-    return doc
-
-
-def test_timeline_bench_dir_mode(tmp_path, capsys):
-    for i, value in enumerate([1.0, 1.05, 0.95, 1.0]):
-        (tmp_path / f"BENCH_r{i:02d}.json").write_text(
-            json.dumps(_bench_doc(value, wrapper=(i == 1)))
-        )
-    assert timeline.main([str(tmp_path)]) == 0
-    capsys.readouterr()
-    # A collapsed final round trips the sentinel; its skipped section
-    # shows as a gap, not a zero.
-    (tmp_path / "BENCH_r04.json").write_text(
-        json.dumps(_bench_doc(0.2, restore=None, gaps=["step_stall"]))
-    )
-    assert timeline.main([str(tmp_path)]) == 1
-    out = capsys.readouterr().out
-    assert "REGRESSION take GB/s" in out
-    assert "BENCH_r04" in out
-    assert "step_stall" in out
-
-
 # --------------------------------------------------------------- goodput
 
 

@@ -2,9 +2,8 @@
 
 TPU-native analog of reference benchmarks/ddp/main.py:38-39: a model that
 is nothing but N large parameters (default 200 x ~100 MB = ~20 GB in the
-reference; sized down per-config here). Used by bench.py to measure raw
-snapshot throughput with replicated striping, exactly like the reference's
-published benchmark.
+reference; sized down per-config here): the state of the reference's
+published benchmark, replicated and striped across ranks.
 """
 
 from typing import Any, Dict, List

@@ -32,11 +32,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.attention import flash_attention, resolve_flash_block
 from ..parallel.mesh import shard_pytree
-from ..parallel.ring_attention import (
-    ring_attention,
-    ring_attention_zigzag,
-    zigzag_indices,
-)
 
 
 @dataclass(frozen=True)
@@ -45,10 +40,7 @@ class TransformerConfig:
     d_model: int = 128
     n_heads: int = 4
     # Key/value heads (grouped-query attention): n_heads % n_kv_heads
-    # == 0; q-head h attends kv-head h // group. None = n_heads (dense
-    # MHA). Under ring attention the K/V slices that rotate over ICI
-    # shrink by the group factor — GQA is a long-context communication
-    # optimization, not just a KV-cache one.
+    # == 0; q-head h attends kv-head h // group. None = n_heads (dense MHA).
     n_kv_heads: Any = None
     n_layers: int = 2
     d_ff: int = 512
@@ -59,36 +51,6 @@ class TransformerConfig:
     # is the numerical reference (the kernel's online softmax reassociates
     # reductions, so outputs match to float tolerance, not bitwise).
     flash_attention: bool = False
-    # Ring attention (parallel/ring_attention.py) over the mesh's "sp"
-    # axis: exact attention with K/V slices rotating over ICI, so no
-    # device gathers the full sequence — the long-context path. Requires
-    # a mesh with an "sp" axis; mutually exclusive with flash_attention.
-    #   False          — off (dense einsum attention)
-    #   True｜"contiguous" — contiguous layout: device j holds tokens
-    #                    [j·S/n, (j+1)·S/n); causal wall-clock tracks the
-    #                    busiest (last) device
-    #   "zigzag"       — balanced layout: device j holds sub-chunks j and
-    #                    2n−1−j, making causal work per device constant.
-    #                    The whole train step runs in zigzag token order
-    #                    (loss_fn permutes tokens/targets once at the
-    #                    input); forward() then expects tokens ALREADY in
-    #                    zigzag order and returns logits in that order.
-    #   "ulysses"      — all-to-all sequence parallelism: one all_to_all
-    #                    re-partitions [B,H,S/n,D] -> [B,H/n,S,D], each
-    #                    device runs FULL-sequence (flash) attention on
-    #                    its head subset, and a second all_to_all
-    #                    restores the layout. Needs per-device heads
-    #                    divisible by the sp axis; tokens stay in
-    #                    original order.
-    ring_attention: Any = False
-    # Local attention implementation for every sequence-parallel mode:
-    # "einsum" or "flash" (the fused Pallas kernel via its custom VJP —
-    # differentiable, O(rows·D) on-device memory). For ring modes this
-    # is the per-chunk attention and resolve_flash_block applies to the
-    # RING CHUNK length (S / sp, halved again under zigzag); for
-    # "ulysses" it is the full-sequence local attention and the
-    # constraint applies to the GLOBAL sequence length S.
-    ring_chunk_impl: str = "einsum"
 
 
 def _n_kv_heads(config: "TransformerConfig") -> int:
@@ -103,19 +65,6 @@ def _n_kv_heads(config: "TransformerConfig") -> int:
             f"n_kv_heads ({n_kv})"
         )
     return n_kv
-
-
-def _ring_mode(config: "TransformerConfig") -> Optional[str]:
-    """Normalize config.ring_attention to
-    None | "contiguous" | "zigzag" | "ulysses"."""
-    r = config.ring_attention
-    if r is False or r is None:
-        return None
-    if r is True or r == "contiguous":
-        return "contiguous"
-    if r in ("zigzag", "ulysses"):
-        return r
-    raise ValueError(f"unknown ring_attention mode: {r!r}")
 
 
 def init_params(config: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
@@ -207,31 +156,8 @@ def forward(
             )
         return x
 
-    def constrain4(x, spec):
-        return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
-
     _, seq_len = tokens.shape
-    ring_mode = _ring_mode(config)
-    if ring_mode is not None:
-        if config.flash_attention:
-            raise ValueError(
-                "flash_attention and ring_attention are mutually exclusive"
-            )
-        if mesh is None or "sp" not in mesh.axis_names:
-            raise ValueError(
-                'ring_attention requires a mesh with an "sp" axis'
-            )
-    if ring_mode == "zigzag":
-        # Tokens arrive in zigzag order; index the positional table by
-        # each slot's ORIGINAL position (a static permutation of rows of
-        # a replicated parameter — free under XLA). Everything else in
-        # the block stack is position-independent, and the zigzag ring
-        # enforces causality w.r.t. original order itself.
-        zz = zigzag_indices(seq_len, mesh.shape["sp"])
-        pos_rows = jnp.take(params["pos_embed"][:seq_len], zz, axis=0)
-    else:
-        pos_rows = params["pos_embed"][:seq_len]
-    h = params["embed"][tokens] + pos_rows
+    h = params["embed"][tokens] + params["pos_embed"][:seq_len]
     h = constrain(h.astype(config.dtype))
 
     if config.flash_attention and mesh is not None:
@@ -246,7 +172,7 @@ def forward(
         )
     mask = (
         None
-        if (config.flash_attention or ring_mode is not None)
+        if config.flash_attention
         else jnp.tril(jnp.ones((seq_len, seq_len), dtype=bool))
     )
     head_dim = config.d_model // config.n_heads
@@ -270,43 +196,6 @@ def forward(
                 block_q=block,
                 block_k=block,
             ).transpose(0, 2, 1, 3)
-        elif ring_mode is not None:
-            # [B, S, H, Dh] -> [B, H, S, Dh]: sequence rides "sp", batch
-            # rides "dp", and heads ride "tp" (q/k/v are tp-column-
-            # sharded already — replicating heads here would all-gather
-            # them and redo attention tp-fold); shard_map inside the jit
-            # trace needs the spec passed explicitly.
-            names = mesh.axis_names
-            head_axis = (
-                "tp"
-                if "tp" in names
-                and config.n_heads % mesh.shape["tp"] == 0
-                and n_kv_heads % mesh.shape["tp"] == 0
-                else None
-            )
-            ring_spec = P(
-                "dp" if "dp" in names else None, head_axis, "sp", None
-            )
-            qr = constrain4(q.transpose(0, 2, 1, 3), ring_spec)
-            kr = constrain4(k.transpose(0, 2, 1, 3), ring_spec)
-            vr = constrain4(v.transpose(0, 2, 1, 3), ring_spec)
-            if ring_mode == "zigzag":
-                attn = ring_attention_zigzag(
-                    qr, kr, vr, mesh, axis="sp", spec=ring_spec,
-                    chunk_impl=config.ring_chunk_impl,
-                ).transpose(0, 2, 1, 3)
-            elif ring_mode == "ulysses":
-                from ..parallel.ulysses import ulysses_attention
-
-                attn = ulysses_attention(
-                    qr, kr, vr, mesh, axis="sp", causal=True,
-                    spec=ring_spec, attn_impl=config.ring_chunk_impl,
-                ).transpose(0, 2, 1, 3)
-            else:
-                attn = ring_attention(
-                    qr, kr, vr, mesh, axis="sp", causal=True,
-                    spec=ring_spec, chunk_impl=config.ring_chunk_impl,
-                ).transpose(0, 2, 1, 3)
         else:
             if n_kv_heads != config.n_heads:
                 # Dense einsum is the numerical reference path; repeating
@@ -339,30 +228,7 @@ def loss_fn(
     config: TransformerConfig,
     mesh: Optional[Mesh] = None,
 ) -> jax.Array:
-    """Next-token cross entropy. ``tokens`` are in original order.
-
-    Under ``ring_attention="zigzag"`` the permutation to zigzag order
-    happens HERE, once per step, on int32 token ids (4 bytes/token over
-    the interconnect — the activations never leave zigzag order): tokens,
-    their next-token targets, and the validity mask are permuted
-    together, the forward runs entirely in zigzag order, and the loss —
-    a masked mean, permutation-invariant — matches the dense loss to
-    float tolerance.
-    """
-    if _ring_mode(config) == "zigzag":
-        s = tokens.shape[1]
-        idx = zigzag_indices(s, mesh.shape["sp"])
-        # Next-token targets in original order; the final position has
-        # no target (the rolled-in first token is masked out).
-        targets = jnp.roll(tokens, -1, axis=1)
-        valid = (jnp.arange(s) < s - 1).astype(jnp.float32)
-        ztok = jnp.take(tokens, idx, axis=1)
-        ztgt = jnp.take(targets, idx, axis=1)
-        zval = jnp.take(valid, idx)
-        logits = forward(params, ztok, config, mesh)  # zigzag order
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(logp, ztgt[..., None], axis=-1)[..., 0]
-        return jnp.sum(nll * zval[None]) / (tokens.shape[0] * (s - 1))
+    """Next-token cross entropy."""
     logits = forward(params, tokens, config, mesh)
     targets = tokens[:, 1:]
     logits = logits[:, :-1]

@@ -344,43 +344,6 @@ def _flash_bwd_rule(causal, block_q, block_k, interpret, residuals, g):
 _flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def flash_chunk_attention(q, k, v, causal, block_q, block_k, interpret):
-    """Flash attention returning BOTH (out, lse) — the chunk primitive for
-    ring attention (parallel/ring_attention.py), differentiable.
-
-    The ring's online-softmax merge consumes the chunk's normalized output
-    *and* its log-sum-exp, so cotangents arrive for both. The lse cotangent
-    folds into the existing tiled backward kernels without new code:
-    ds_ij = p_ij·(dout_i·v_j − delta_i) from the output plus
-    ds_ij += dlse_i·p_ij from the lse (∂lse_i/∂s_ij = p_ij), i.e. the
-    kernels run unchanged with delta' = delta − dlse. dv is lse-independent.
-    """
-    return _flash_forward(q, k, v, causal, block_q, block_k, interpret)
-
-
-def _flash_chunk_fwd(q, k, v, causal, block_q, block_k, interpret):
-    out, lse = _flash_forward(q, k, v, causal, block_q, block_k, interpret)
-    return (out, lse), (q, k, v, out, lse)
-
-
-def _flash_chunk_bwd(causal, block_q, block_k, interpret, residuals, g):
-    q, k, v, out, lse = residuals
-    dout, dlse = g
-    dout = dout.astype(jnp.float32)
-    delta = (
-        jnp.sum(dout * out.astype(jnp.float32), axis=-1, keepdims=True)
-        - dlse.astype(jnp.float32)
-    )
-    dq, dk, dv = _flash_backward(
-        q, k, v, dout, lse, delta, causal, block_q, block_k, interpret
-    )
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
-
-
-flash_chunk_attention.defvjp(_flash_chunk_fwd, _flash_chunk_bwd)
-
-
 def _resolve_blocks(s: int, block_q: int, block_k: int):
     block_q = min(block_q, s)
     block_k = min(block_k, s)

@@ -178,10 +178,8 @@ def chunked_device_put(arr: np.ndarray, device: Any) -> Any:
 #
 # One-shot hardware-bound measurement for the restore flight report
 # (snapxray): consume GB/s only means something as a FRACTION of what
-# the link could do, the same way bench pins take against the D2H
-# probe. Memoized per process — the report wants an order-of-magnitude
-# anchor, not a bracketing measurement (bench's restore section still
-# brackets with fresh probes).
+# the link could do. Memoized per process — the report wants an
+# order-of-magnitude anchor, not a bracketing measurement.
 
 _H2D_PROBE_BYTES_ENV_VAR = "TPUSNAPSHOT_H2D_PROBE_BYTES"
 _DEFAULT_H2D_PROBE_BYTES = 32 * 1024 * 1024

@@ -852,8 +852,8 @@ class Snapshot:
         available = self._available_entries(metadata, rank)
 
         # Rank-local flight record: the read/consume/assemble breakdown
-        # that names a consume-dominated restore (BENCH_r05) from a file
-        # instead of a trace viewer. Written best-effort at the end.
+        # that names a consume-dominated restore from a file instead of
+        # a trace viewer. Written best-effort at the end.
         recorder = flight.FlightRecorder(
             kind="restore", path=self.path, rank=rank
         )
@@ -1034,8 +1034,8 @@ class Snapshot:
                 # Streaming fast path: the overlap engine's delivered
                 # H2D throughput — transfers ran OFF the consume wall,
                 # so consume_gbps no longer bounds the restore; this
-                # number (vs the probe) is what certifies the pipeline
-                # kept the link busy (bench's restore_vs_h2d_ceiling).
+                # number (vs the probe) says whether the pipeline kept
+                # the link busy.
                 overlap = (profile_block.get("substeps") or {}).get(
                     "h2d_overlap"
                 )
@@ -2185,7 +2185,7 @@ _DEFAULT_H2D_PROBE_MIN_BYTES = 64 << 20
 def _probe_h2d_for_report(consumed_bytes: int) -> Optional[float]:
     """The flight report's H2D anchor (ops/transfer.py probe, memoized
     per process): consume GB/s is only meaningful as a fraction of what
-    the link measures — the way bench pins take against the D2H probe."""
+    the link measures."""
     floor = env_int(
         _H2D_PROBE_MIN_BYTES_ENV_VAR, _DEFAULT_H2D_PROBE_MIN_BYTES
     )

@@ -37,8 +37,8 @@ Three layers, smallest first:
   library reports its own blocking automatically.
 - **Timeline** (:mod:`.timeline`) —
   ``python -m torchsnapshot_tpu.telemetry.timeline <base>`` renders
-  per-step trends from the ledger (or a dir of BENCH_*.json) and runs
-  a median/MAD regression sentinel; exit 0/1/2 for CI.
+  per-step trends from the ledger and runs a median/MAD regression
+  sentinel; exit 0/1/2 for CI.
 - **Runtime sampler / snapscope** (:mod:`.sampler`) — a crash-isolated
   background thread snapshotting live runtime state (hot-tier drain
   queue/at-risk bytes/host occupancy, scheduler budget, goodput) into

@@ -1,10 +1,10 @@
 """Phase profile: sub-step attribution inside the restore's consume path
 and inside the take's staging path, by one accumulator.
 
-The flight recorder can say a restore spent 176s in ``consume`` against
-0.76s of ``read`` (BENCH_r05), and the benchmark that ``async_save``
-blocked for 3.1 s (PERF.md section 5) — but not WHERE inside consume, or
-inside staging, the time went. This module is that number: an always-on,
+The flight recorder can say that a restore spent its time in ``consume``
+and not in ``read``, and the benchmark that ``async_save`` blocked for
+3.1 s (PERF.md section 5) — but not WHERE inside consume, or inside
+staging, the time went. This module is that number: an always-on,
 contextvar-scoped :class:`PhaseProfile` that the restore root (kind
 ``"consume"``) or the take root (kind ``"stage"``) opens and that every
 buffer consumer, or every array stager, notes into at per-leaf/per-chunk

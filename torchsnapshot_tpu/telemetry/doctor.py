@@ -12,9 +12,8 @@ the manifest — or any JSON file of that schema) plus, optionally, a
 trace summary and a metric snapshot, and emits findings from the rule
 catalog below. Each finding names its rule id, the evidence that
 triggered it, and a remediation hint — the difference between "this
-restore was slow" and "this restore spent 176s deserializing against
-0.8s of reads; storage is innocent" (the BENCH_r05 pathology that
-motivated the whole telemetry subsystem).
+restore was slow" and "this restore spent its time deserializing, not
+reading; storage is innocent".
 
 Rule catalog (docs/OBSERVABILITY.md carries the narrative version):
 
@@ -125,7 +124,7 @@ _DEFAULT_WIRE_MARGIN_WARN = 0.70
 # Phases must clear this floor before a ratio means anything: a 0.05s
 # consume "dominating" a 0.006s read is scheduler jitter on a tiny
 # operation, not a pathology worth a remediation hint — the findings
-# this doctor exists for are seconds-to-minutes (BENCH_r05: 176s).
+# this doctor exists for are seconds-to-minutes.
 _MIN_PHASE_S = 1.0
 
 
@@ -203,11 +202,10 @@ _CONSUME_SUBSTEP_REMEDIATION = {
         "H2D transfers are running INSIDE consume executors instead of "
         "on the overlap engine — the streaming fast path is not "
         "engaging (regions too small, compressed payloads, or a "
-        "resharded template). Check restore_consume_vs_h2d in the "
-        "bench artifact / h2d_overlap_vs_probe in this report, raise "
-        "the H2D depth (TPUSNAPSHOT_H2D_DEPTH) and the device restore "
-        "budget (TPUSNAPSHOT_DEVICE_BUDGET_BYTES) so more regions "
-        "stream concurrently."
+        "resharded template). Check h2d_overlap_vs_probe in this "
+        "report, raise the H2D depth (TPUSNAPSHOT_H2D_DEPTH) and the "
+        "device restore budget (TPUSNAPSHOT_DEVICE_BUDGET_BYTES) so "
+        "more regions stream concurrently."
     ),
     "pool_wait": (
         "consumes are blocking on staging-pool capacity: concurrent "
@@ -307,11 +305,8 @@ def _rule_consume_dominated(report: Dict[str, Any]) -> Optional[Finding]:
                     min(fractions), 4
                 )
             # Streaming-pipeline evidence: how hard the overlap engine
-            # ran, and its delivered H2D vs the probe — named
-            # restore_vs_h2d_ceiling to MATCH the bench key gating the
-            # same quantity (consume_h2d_fraction above is the bench's
-            # restore_consume_vs_h2d analog). A firing rule WITH
-            # healthy overlap numbers points at host-side work
+            # ran, and its delivered H2D vs the probe. A firing rule
+            # WITH healthy overlap numbers points at host-side work
             # (decode/deserialize); without them the fast path never
             # engaged.
             if overlap_s:

@@ -20,7 +20,7 @@ This module is the registry those budgets reconcile through:
   bytes, and headroom against ``TPUSNAPSHOT_HOST_MEM_BUDGET`` (or the
   detected cgroup limit / host RAM) minus the process RSS;
 - :func:`window_begin`/:func:`window_collect` bracket one operation
-  (a take, a restore, a bench section) and return the phase-windowed
+  (a take, a restore) and return the phase-windowed
   memory block flight reports embed — per-domain high-waters inside
   the window, ending occupancy, counter deltas, and any pressure
   forecasts that fired;
@@ -652,7 +652,7 @@ def sample_block() -> Dict[str, Any]:
 
 
 def window_begin() -> int:
-    """Open a phase window (one per take/restore/bench section).
+    """Open a phase window (one per take/restore).
     Returns an opaque token for :func:`window_collect`. Windows are
     seeded with current occupancy so a domain that never moves inside
     the window still reports its standing bytes as the window

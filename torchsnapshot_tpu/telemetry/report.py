@@ -14,9 +14,9 @@ rank 0 writes the merged report beside the metadata document.
 ``restore`` gathers every rank's read/consume/assemble breakdown over
 the coordinator (the restore path is foreground-collective already) and
 rank 0 writes one merged ``.report.restore.json`` digest — the document
-that would have named BENCH_r05's 176s consume-dominated restore
-without a trace viewer. Pre-digest snapshots may instead hold legacy
-rank-local ``.report.restore.rank<N>.json`` files; readers accept both.
+that names a consume-dominated restore without a trace viewer.
+Pre-digest snapshots may instead hold legacy rank-local
+``.report.restore.rank<N>.json`` files; readers accept both.
 
 Reports are observability, not protocol: every write/read here is
 best-effort and may never fail the snapshot operation it describes.

@@ -10,10 +10,9 @@ and prints, per span name: count, total span-seconds, *busy* wall-clock
 overlap factor, and bytes/throughput where spans carry a ``bytes`` arg.
 
 It then names the **dominant phase** among the pipeline ops
-(stage/write on a take; read/consume on a restore), so the pathology
-that motivated this tool — BENCH_r05's restore spending 176.3s in
-``consume`` against 0.76s of ``read`` — is flagged automatically
-instead of requiring a human to eyeball Perfetto.
+(stage/write on a take; read/consume on a restore), so a restore that
+spends its time in ``consume`` and not in ``read`` is flagged
+automatically instead of requiring a human to eyeball Perfetto.
 
 ``consume.<substep>`` spans (the snapxray micro-profiler,
 ``telemetry/consume_profile.py``) additionally fold into a **consume
@@ -156,7 +155,7 @@ def summarize(spans: Dict[str, Dict[str, Any]]) -> Dict[str, Any]:
     # visibility but carry NO consume share and are never named
     # dominant — engine transfers on a wire-bound restore would
     # otherwise always "dominate" a wall they are not part of (the same
-    # exclusion doctor and bench_compare apply).
+    # exclusion doctor applies).
     _BESIDE_WALL = ("read_wait", "h2d_overlap", "overlap_other")
     consume_busy = (phases.get("consume") or {}).get("busy_s", 0.0)
     breakdown: Dict[str, Dict[str, Any]] = {}

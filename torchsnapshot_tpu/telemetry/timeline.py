@@ -4,7 +4,6 @@ Usage::
 
     python -m torchsnapshot_tpu.telemetry.timeline <ledger-root-url>
     python -m torchsnapshot_tpu.telemetry.timeline /path/ledger.jsonl
-    python -m torchsnapshot_tpu.telemetry.timeline <dir-of-BENCH_*.json>
     python -m torchsnapshot_tpu.inspect <base> --timeline
 
 Where ledger.py is the durable record, this is the reader that answers
@@ -23,18 +22,11 @@ The sentinel also folds the doctor-rule firing history recorded per
 take — "retry-storm fired at steps 40, 45, 50" is a trend even when no
 single metric trips.
 
-A directory of ``BENCH_*.json`` round artifacts is accepted in place of
-a ledger: the same sentinel runs over the cross-round headline series
-(take GB/s, restore GB/s, ceiling ratios). Sections a round skipped
-under its deadline (``gaps``, bench.py) are missing data, never zeros.
-
 Exit codes: 0 = healthy; 1 = regression flagged; 2 = usage / no data.
 """
 
 import argparse
-import glob as _glob
 import json
-import os
 import statistics
 import sys
 from typing import Any, Dict, List, Optional, Tuple
@@ -64,11 +56,10 @@ _TAKE_METRICS: List[_MetricDef] = [
 _RESTORE_METRICS: List[_MetricDef] = [
     ("wall_s", "restore seconds", "high", 0.05, None),
     ("gbps", "restore GB/s", "low", 0.0, None),
-    # snapxray consume profile: consume GB/s as a fraction of the H2D
-    # probe — the number ROADMAP item 1's streaming-restore rewrite is
-    # certified against. Dropping means consume is falling further
-    # behind the hardware bound. Null (no probe / pre-snapxray records)
-    # is missing data, never a regression.
+    # Consume profile: consume GB/s as a fraction of the H2D probe.
+    # Dropping means consume is falling further behind the hardware
+    # bound. Null (no probe, or a record that predates the profile) is
+    # missing data, never a regression.
     ("consume.h2d_fraction", "consume/H2D fraction", "low", 0.02, 0.3),
 ]
 # Drain event records (kind "tierdown", appended by the hot tier when a
@@ -77,85 +68,6 @@ _RESTORE_METRICS: List[_MetricDef] = [
 # this sentinel exists to name.
 _DRAIN_METRICS: List[_MetricDef] = [
     ("durability_lag_s", "durability lag s", "high", 0.05, None),
-]
-_BENCH_METRICS: List[_MetricDef] = [
-    ("value", "take GB/s", "low", 0.0, None),
-    ("restore_GBps", "restore GB/s", "low", 0.0, None),
-    ("take_vs_ceiling", "take/ceiling", "low", 0.05, 0.2),
-    ("restore_vs_ceiling", "restore/ceiling", "low", 0.05, 0.2),
-    # PR 6 hot-tier headline numbers, regression-gated like the rest:
-    # the hot-vs-durable restore ratio, the every-step hot-leg goodput
-    # overhead, and the bench take's measured durability lag.
-    ("hot_tier.hot_vs_durable", "hot/durable restore ratio", "low", 0.5, 0.3),
-    ("hot_tier.durability_lag_s", "bench durability lag s", "high", 0.5, None),
-    ("every_step.hot.overhead_pct", "every-step overhead %", "high", 0.5, 0.3),
-    # PR 9 snapserve read-fanout headline numbers: backend-read
-    # amplification at 32 concurrent clients (the service must hold it
-    # near 1x — creep back toward per-client backend reads is THE
-    # read-plane regression) and the aggregate served throughput.
-    (
-        "read_fanout.amplification_served",
-        "read-fanout amplification",
-        "high",
-        0.1,
-        0.15,
-    ),
-    ("read_fanout.served_gbps", "read-fanout GB/s", "low", 0.05, 0.3),
-    # Chunk-store dedup + codec headline numbers (bench dedup_codec
-    # section): the unchanged-retake physical fraction and the 10%-
-    # dirty-leaf physical fraction creeping UP mean dedup is saving
-    # fewer bytes; the effective (logical-bytes) throughput and codec
-    # ratio guard the "move fewer bytes" win itself.
-    (
-        "dedup_codec.second_take_physical_pct",
-        "2nd-take physical %",
-        "high",
-        0.5,
-        0.5,
-    ),
-    (
-        "dedup_codec.dirty10_physical_pct",
-        "10%-dirty physical %",
-        "high",
-        1.0,
-        0.5,
-    ),
-    ("dedup_codec.effective_gbps", "dedup effective GB/s", "low", 0.05, 0.3),
-    ("dedup_codec.codec_ratio", "bench codec ratio", "high", 0.02, 0.2),
-    # snapxray: bench's restore-section consume/H2D fraction — same
-    # sentinel rationale as the ledger-mode consume.h2d_fraction.
-    (
-        "restore_consume_vs_h2d",
-        "bench consume/H2D fraction",
-        "low",
-        0.02,
-        0.3,
-    ),
-    # fastlane: the streaming restore pipeline's overlap-engine H2D
-    # GB/s over the bracketed ceiling — ~1.0 means the restore is
-    # wire-bound; a drop is the pipeline sliding back toward a
-    # consume-serialized restore.
-    (
-        "restore_vs_h2d_ceiling",
-        "bench restore-H2D/ceiling",
-        "low",
-        0.05,
-        0.2,
-    ),
-    # snapfleet headline numbers (bench fleet section): aggregate
-    # backend amplification across the fleet (per-client pushdown must
-    # keep the SUM of fetched bytes near 1x the payload — creep means
-    # clients re-fetching whole objects), and the small tenant's p95
-    # grant-wait ratio vs the saturating tenant (fairness: the small
-    # tenant must not queue behind the big one's whole backlog).
-    ("fleet.amplification", "fleet backend amplification", "high", 0.1, 0.2),
-    (
-        "fleet.fairness_p95_ratio",
-        "fleet tenant-fairness p95 ratio",
-        "high",
-        0.1,
-        0.5,
-    ),
 ]
 
 
@@ -187,9 +99,9 @@ def detect_regressions(
 ) -> Optional[Dict[str, Any]]:
     """First regression in a ``(label, value)`` series, or None.
 
-    Missing values (``None`` — a skipped bench section, a record that
-    predates the metric) are excluded from baselines and never flagged:
-    missing data is not zero."""
+    Missing values (``None`` — a record that predates the metric) are
+    excluded from baselines and never flagged: missing data is not
+    zero."""
     present: List[Tuple[str, float]] = [
         (lab, v) for lab, v in points if v is not None
     ]
@@ -338,79 +250,6 @@ def analyze_ledger(
     }
 
 
-# ------------------------------------------------------------- bench mode
-
-
-def _load_bench_summary(path: str) -> Dict[str, Any]:
-    """A BENCH_*.json as its bench-summary dict: either the bare summary
-    bench.py prints or the driver wrapper whose ``tail`` embeds it."""
-    with open(path) as f:
-        doc = json.load(f)
-    if "metric" in doc:
-        return doc
-    tail = doc.get("tail")
-    if isinstance(tail, str):
-        idx = tail.rfind('{"metric"')
-        if idx >= 0:
-            try:
-                summary, _ = json.JSONDecoder().raw_decode(tail[idx:])
-                if isinstance(summary, dict):
-                    return summary
-            except json.JSONDecodeError:
-                pass
-    return {}
-
-
-def analyze_bench_dir(path: str, **knobs: Any) -> Dict[str, Any]:
-    files = sorted(_glob.glob(os.path.join(path, "BENCH_*.json")))
-    rows: List[Tuple[str, Dict[str, Any]]] = []
-    for f in files:
-        rows.append((os.path.splitext(os.path.basename(f))[0], _load_bench_summary(f)))
-    series: Dict[str, List[Tuple[str, Optional[float]]]] = {}
-    gaps: Dict[str, List[str]] = {}
-    for label, doc in rows:
-        for field, *_ in _BENCH_METRICS:
-            series.setdefault(field, []).append((label, _get(doc, field)))
-        for section in doc.get("gaps") or []:
-            gaps.setdefault(label, []).append(section)
-    return {
-        "n_records": len(rows),
-        "runs": [label for label, _ in rows],
-        "gaps": gaps,
-        "regressions": run_sentinel(series, _BENCH_METRICS, **knobs),
-        "series": {
-            field: [[lab, v] for lab, v in pts]
-            for field, pts in series.items()
-        },
-    }
-
-
-def render_bench(result: Dict[str, Any]) -> List[str]:
-    lines = []
-    by_run: Dict[str, Dict[str, Optional[float]]] = {}
-    for field, pts in (result.get("series") or {}).items():
-        for lab, v in pts:
-            by_run.setdefault(lab, {})[field] = v
-    lines.append(
-        f"{'run':>12s} {'take GB/s':>10s} {'restore':>8s} "
-        f"{'take/ceil':>9s} {'rest/ceil':>9s} {'hot/dur':>8s} "
-        f"{'es-ovh%':>8s}  gaps"
-    )
-    for lab in result.get("runs") or []:
-        vals = by_run.get(lab, {})
-        gap = ",".join((result.get("gaps") or {}).get(lab, [])) or "-"
-        lines.append(
-            f"{lab:>12s} {_fmt(vals.get('value'), '10.4f')} "
-            f"{_fmt(vals.get('restore_GBps'), '8.4f')} "
-            f"{_fmt(vals.get('take_vs_ceiling'), '9.3f')} "
-            f"{_fmt(vals.get('restore_vs_ceiling'), '9.3f')} "
-            f"{_fmt(vals.get('hot_tier.hot_vs_durable'), '8.2f')} "
-            f"{_fmt(vals.get('every_step.hot.overhead_pct'), '8.2f')}  "
-            f"{gap}"
-        )
-    return lines
-
-
 # -------------------------------------------------------------------- CLI
 
 
@@ -443,13 +282,12 @@ def main(argv: Optional[List[str]] = None) -> int:
     parser = argparse.ArgumentParser(
         prog="python -m torchsnapshot_tpu.telemetry.timeline",
         description="Render per-step checkpoint telemetry trends from a "
-        "ledger (or a directory of BENCH_*.json) and run the "
-        "rolling-baseline regression sentinel.",
+        "ledger and run the rolling-baseline regression sentinel.",
     )
     parser.add_argument(
         "path",
-        help="ledger root URL (reads <path>/.telemetry/ledger.jsonl), a "
-        "ledger .jsonl file, or a directory of BENCH_*.json artifacts",
+        help="ledger root URL (reads <path>/.telemetry/ledger.jsonl) or "
+        "a ledger .jsonl file",
     )
     parser.add_argument("--json", action="store_true", help="emit JSON")
     parser.add_argument(
@@ -480,23 +318,6 @@ def main(argv: Optional[List[str]] = None) -> int:
         "mad_k": args.mad_k,
         "rel_floor": args.rel_floor,
     }
-
-    bench_mode = (
-        "://" not in args.path
-        and os.path.isdir(args.path)
-        and bool(_glob.glob(os.path.join(args.path, "BENCH_*.json")))
-    )
-    if bench_mode:
-        result = analyze_bench_dir(args.path, **knobs)
-        if result["n_records"] == 0:
-            print(f"no BENCH_*.json under {args.path}", file=sys.stderr)
-            return 2
-        if args.json:
-            print(json.dumps(result, indent=2, sort_keys=True))
-        else:
-            for line in render_bench(result) + _render_findings(result):
-                print(line)
-        return 1 if result["regressions"] else 0
 
     from . import ledger as _ledger
 

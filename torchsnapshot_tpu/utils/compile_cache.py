@@ -1,7 +1,7 @@
 """One placement rule for JAX's persistent compilation cache.
 
-Entry-point scripts (``chip_smoke.py``, ``bench.py``, ``benchmarks/*.py``)
-call :func:`configure_compile_cache` before their first use of JAX. The
+Entry-point scripts (``chip_smoke.py``, ``perfbench/run.py``) call
+:func:`configure_compile_cache` before their first use of JAX. The
 cache directory is part of the cache key, so it must not move between
 runs: it is ``JAX_COMPILATION_CACHE_DIR`` when the environment sets one
 (JAX reads that variable itself; nothing is set in code), and otherwise

@@ -20,7 +20,7 @@ key* — the layer records:
 Everything mirrors into the process metrics registry (the
 ``tpusnapshot_wire_*`` catalog entries) AND into module-local
 aggregates that support cheap windowed deltas (``window_begin`` /
-``window_collect``) for flight reports and bench blocks, mirroring the
+``window_collect``) for flight reports, mirroring the
 hot tier's ``replication_stats_begin`` pattern.
 
 **Flight recorder.** Always on: a bounded ring of the last N RPC
@@ -353,7 +353,7 @@ def _copy_agg() -> Dict[Tuple[str, str], Dict[str, Any]]:
 
 def window_begin() -> Dict[Tuple[str, str], Dict[str, Any]]:
     """Opaque token for :func:`window_collect` — flight reports open
-    one per take/restore, bench blocks one per block."""
+    one per take/restore."""
     return _copy_agg()
 
 
