@@ -360,6 +360,12 @@ class ArrayBufferStager(BufferStager):
     so only chunk-sized host memory is allocated.
     """
 
+    # The pooled assembly buffer a chunked leaf's payload lives in, from
+    # staging until the write pipeline lets go of the payload
+    # (``release_staged``). A class default: ``chunkstore.ChunkStager``
+    # has an ``__init__`` of its own and never leases.
+    _lease: Optional[staging_pool.StagingLease] = None
+
     def __init__(
         self,
         data: Any,
@@ -434,7 +440,9 @@ class ArrayBufferStager(BufferStager):
             with _cprof.substep(profile, "slice", nbytes):
                 data = data[self._chunk_slices]
         if _should_chunk_transfer(data):
-            host = _parallel_device_get(data, profile)
+            host = _parallel_device_get(
+                data, profile, out=self._lease_assembly_buffer(data)
+            )
         else:
             with _cprof.substep(profile, "d2h", nbytes):
                 host = np.asarray(data)  # D2H for jax arrays; no-op for numpy
@@ -462,6 +470,8 @@ class ArrayBufferStager(BufferStager):
                 payload = compress_payload(payload, self._compression)
             if self._entry is not None:
                 self._entry.compression = self._compression
+            # The compressed payload is bytes of its own.
+            self.release_staged()
         if self._entry is not None:
             # The checksum reaches the persisted metadata because staging
             # always precedes the manifest consolidation: sync takes write
@@ -473,6 +483,42 @@ class ArrayBufferStager(BufferStager):
             with _cprof.substep(profile, "checksum", len(payload)):
                 self._entry.checksum = compute_checksum(payload)
         return payload
+
+    def _lease_assembly_buffer(self, data: Any) -> Optional[np.ndarray]:
+        """The host array a chunked leaf is assembled in, leased from
+        the takes' pool (``staging_pool.py``): after a process's first
+        save its pages are touched already. None outside a take, where
+        nothing would say how much one take has leased: the gather
+        allocates, as it always did."""
+        profile = self._profile
+        if profile is None:
+            return None
+        nbytes = self._nbytes
+        pool = staging_pool.get_take_staging_pool()
+        began = time.monotonic()
+        # Held by the stager from here on: ``release_staged``.
+        self._lease = pool.acquire(nbytes)
+        lease = self._lease
+        # What stays between saves is at most what one take held
+        # leased at once: the whole capture where it is staged inside
+        # the call, a budget's worth where a sync take is held to one.
+        pool.retain_up_to(profile.note_pool_lease(lease.reused, nbytes))
+        out = lease.as_array(np.dtype(data.dtype), list(data.shape))
+        _cprof.note_interval(
+            profile,
+            "alloc",
+            began,
+            time.monotonic(),
+            nbytes,
+            pool="hit" if lease.reused else "miss",
+        )
+        return out
+
+    def release_staged(self) -> None:
+        lease, self._lease = self._lease, None
+        if lease is not None:
+            lease.release()
+            self._profile.note_pool_release(lease.nbytes)
 
     def get_staging_cost_bytes(self) -> int:
         return self._nbytes

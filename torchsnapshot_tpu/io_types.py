@@ -282,6 +282,15 @@ class BufferStager(abc.ABC):
     def get_staging_cost_bytes(self) -> int:
         """Peak host memory charged against the budget while staging."""
 
+    def release_staged(self) -> None:
+        """The write pipeline is done with the staged payload:
+        ``storage.write`` of it has returned or raised, or the pipeline
+        unwinds before it got that far. A stager whose payload lives in
+        a pooled buffer gives the lease back here (``staging_pool.py``).
+        May be called again (the pipeline sweeps its requests on the way
+        out): only the first call gives anything back. Nothing to give
+        back by default."""
+
 
 class BufferConsumer(abc.ABC):
     @abc.abstractmethod

@@ -87,21 +87,31 @@ def should_chunk_transfer(arr: Any) -> bool:
 
 
 def parallel_device_get(
-    arr: jax.Array, profile: Optional[_cprof.PhaseProfile] = None
+    arr: jax.Array,
+    profile: Optional[_cprof.PhaseProfile] = None,
+    out: Optional[np.ndarray] = None,
 ) -> np.ndarray:
     """Gather ``arr`` to host via parallel chunked transfers.
 
+    ``out``, where given, is the C-contiguous host array of ``arr``'s
+    shape and dtype that the chunks are assembled in and that is
+    returned: a take hands in a buffer of its pool
+    (``io_preparer.ArrayBufferStager``), whose pages an earlier save has
+    touched. Without it the array is allocated here.
+
     ``profile`` (the take's phase profile, telemetry/consume_profile.py)
     gets one note a chunk for each of ``slice``, ``d2h`` and ``copy``,
-    one ``alloc`` and one ``fetch_wait`` a leaf, and the fetches' own
-    thread-seconds as wall."""
+    one ``alloc`` (here, or where ``out`` was leased) and one
+    ``fetch_wait`` a leaf, and the fetches' own thread-seconds as
+    wall."""
     shape = tuple(arr.shape)
     dtype = np.dtype(arr.dtype)
     nbytes = dtype.itemsize * math.prod(shape)
     axis = max(range(len(shape)), key=lambda d: shape[d])
     n_chunks = min(shape[axis], max(1, -(-nbytes // transfer_chunk_bytes())))
-    with _cprof.substep(profile, "alloc", nbytes):
-        out = np.empty(shape, dtype=dtype)
+    if out is None:
+        with _cprof.substep(profile, "alloc", nbytes):
+            out = np.empty(shape, dtype=dtype)
     bounds = [round(i * shape[axis] / n_chunks) for i in range(n_chunks + 1)]
     row_nbytes = nbytes // shape[axis]
 

@@ -46,7 +46,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import psutil
 
-from . import telemetry, tracing
+from . import staging_pool, telemetry, tracing
 from .io_types import IOReq, ReadReq, StoragePlugin, WriteReq, io_payload
 from .telemetry import consume_profile as _cprof
 from .telemetry import memwatch
@@ -236,7 +236,7 @@ async def execute_write_reqs(
         transient=True,
         watch_residual="used",
     )
-    memwatch.forecast(
+    if memwatch.forecast(
         min(
             sum(
                 wr.buffer_stager.get_staging_cost_bytes()
@@ -245,7 +245,11 @@ async def execute_write_reqs(
             memory_budget_bytes,
         ),
         kind="take",
-    )
+    ):
+        # The burst is predicted not to fit: the assembly buffers that
+        # earlier takes left in their pool are the one thing this
+        # pipeline can give the host back first.
+        staging_pool.trim_take_staging_pool()
     try:
         while pending or staged or staging or io_tasks:
             # Dispatch staging while the budget allows; always keep at
@@ -314,11 +318,23 @@ async def execute_write_reqs(
                 )
 
                 async def _write(
-                    io_req=io_req, path=wr.path, n=len(buf), share=share
+                    io_req=io_req,
+                    path=wr.path,
+                    n=len(buf),
+                    share=share,
+                    stager=wr.buffer_stager,
                 ):
                     t0 = time.monotonic()
-                    with tracing.span("write", path=path, bytes=n):
-                        await storage.write(io_req)
+                    try:
+                        with tracing.span("write", path=path, bytes=n):
+                            await storage.write(io_req)
+                    finally:
+                        # ``write`` has returned, raised or been
+                        # cancelled: a pooled buffer under the payload
+                        # goes back (its pool hands it out again only
+                        # once nothing views it, staging_pool.py).
+                        io_req.data = None
+                        stager.release_staged()
                     _observe_op(
                         ops,
                         "write",
@@ -363,6 +379,11 @@ async def execute_write_reqs(
                 await progress.async_tick()
     finally:
         executor.shutdown(wait=False)
+        # On the way out of a failed or cancelled run: what was staged
+        # and never written gives its pooled buffer back too. After a
+        # sound run every stager has done so already.
+        for wr in write_reqs:
+            wr.buffer_stager.release_staged()
         in_use_gauge.set(0)
         stalled_gauge.set(0)
         mem_domain.set_used(max(0, memory_budget_bytes - budget))

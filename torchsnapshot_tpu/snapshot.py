@@ -2882,12 +2882,29 @@ def _prestage_write_reqs(
 
         from .scheduler import _MAX_STAGING_THREADS
 
-        with ThreadPoolExecutor(max_workers=_MAX_STAGING_THREADS) as executor:
-            bufs = await asyncio.gather(
-                *(wr.buffer_stager.stage_buffer(executor) for wr in write_reqs)
-            )
+        try:
+            with ThreadPoolExecutor(
+                max_workers=_MAX_STAGING_THREADS
+            ) as executor:
+                bufs = await asyncio.gather(
+                    *(
+                        wr.buffer_stager.stage_buffer(executor)
+                        for wr in write_reqs
+                    )
+                )
+        except BaseException:
+            # No drain will write what was staged (the executor's exit
+            # has waited for the stagers still running): the pooled
+            # buffers go back now.
+            for wr in write_reqs:
+                wr.buffer_stager.release_staged()
+            raise
         for wr, buf in zip(write_reqs, bufs):
-            wr.buffer_stager = _PreStagedStager(buf)
+            # The pooled buffer under ``buf`` stays leased until the
+            # drain has written it: the stager's release travels along.
+            wr.buffer_stager = _PreStagedStager(
+                buf, wr.buffer_stager.release_staged
+            )
 
     with tracing.span("capture_host_stage", bytes=total):
         asyncio.run(_stage_all())
@@ -2905,11 +2922,19 @@ def _note_stage_phases(recorder: Any, profile: Any) -> None:
 
 
 class _PreStagedStager:
-    def __init__(self, buf: Any) -> None:
+    def __init__(self, buf: Any, release: Callable[[], None]) -> None:
         self._buf = buf
+        self._nbytes = len(buf)
+        self._release = release
 
     async def stage_buffer(self, executor: Any = None) -> Any:
         return self._buf
+
+    def release_staged(self) -> None:
+        """Lets go of the payload and gives its pooled backing back
+        (``BufferStager.release_staged`` of the stager that staged it)."""
+        self._buf = None
+        self._release()
 
     def get_staging_cost_bytes(self) -> int:
         # The buffer is already retained in host memory; dispatching its
@@ -2921,7 +2946,7 @@ class _PreStagedStager:
     def payload_nbytes(self) -> int:
         # The budget cost above is deliberately 0; progress totals still
         # want the real payload size (scheduler's bytes_total sum).
-        return len(self._buf)
+        return self._nbytes
 
 
 class _RestoreStretches:

@@ -1,4 +1,5 @@
-"""Pooled host staging buffers for the streaming restore pipeline.
+"""Pooled host staging buffers: the streaming restore pipeline's, and
+(a second pool of the same class, below) a take's assembly buffers.
 
 The pre-fastlane restore allocated a fresh host buffer for every
 assembly unit — one ``bytearray(nbytes)`` per split whole-object read
@@ -25,7 +26,30 @@ buffer actually returns to the pool — never per sub-read, never twice,
 whatever mix of executor threads, H2D-engine callbacks, and error paths
 races to release it.
 
-Env knobs:
+The take side (:func:`get_take_staging_pool`): the host buffers a take
+assembles its chunked leaves in (``ArrayBufferStager._stage_phases``
+hands them to ``ops/transfer.parallel_device_get`` as ``out``) come from
+a second process-wide pool of this class and go back to it when the
+write pipeline has seen ``storage.write`` of the object return, so that
+every save of a process after its first copies into pages the host has
+already faulted in (PERF.md section 5: the first touch of fresh pages
+was 62 % of a host-staged capture's thread-seconds). It never waits and
+has no knob: its cap is the most bytes a single take of this process
+has held leased at once (:meth:`StagingPool.retain_up_to`), so what
+stays resident between saves is at most one capture's bytes, which every
+such save holds on the host anyway (and a budget's worth where a sync
+take is held to a budget). A take that runs while another still drains
+gets fresh memory for what is still leased. The free buffers are let go
+by :func:`trim_take_staging_pool` (also called when memwatch's forecast
+before a write pipeline predicts an overcommit), and with the process.
+
+The takes' pool hands a free buffer out again only while nothing outside
+the pool refers to it (:func:`_viewed_elsewhere`): a storage plug-in
+that kept the payload past ``write()``, or a writer thread that a
+cancelled pipeline left behind, keeps the buffer and the pool forgets
+it.
+
+Env knobs (the restore side's; the take side has none):
 
 - ``TPUSNAPSHOT_RESTORE_STAGING_POOL_BYTES`` — pool capacity (default
   1 GiB). Bounds both the retained free set and the point past which
@@ -39,9 +63,10 @@ Env knobs:
 """
 
 import collections
+import sys
 import threading
 import time
-from typing import Callable, Deque, Dict, List, Optional
+from typing import Any, Callable, Deque, Dict, List, Optional
 
 import numpy as np
 
@@ -55,6 +80,9 @@ _POOL_BYTES_ENV_VAR = "TPUSNAPSHOT_RESTORE_STAGING_POOL_BYTES"
 _DEFAULT_POOL_BYTES = 1 << 30
 _POOL_WAIT_ENV_VAR = "TPUSNAPSHOT_RESTORE_POOL_WAIT_S"
 _DEFAULT_POOL_WAIT_S = 5.0
+# snapmem domain names of the two pools.
+_RESTORE_DOMAIN = "staging_pool"
+_TAKE_DOMAIN = "take_staging_pool"
 
 
 def pool_capacity_bytes() -> int:
@@ -67,6 +95,19 @@ def pool_capacity_bytes() -> int:
 # lock: it parks the lease here (a deque append is atomic under the
 # GIL) and the next ordinary entry into a pool releases it.
 _dropped: Deque["StagingLease"] = collections.deque()
+
+
+# What ``sys.getrefcount`` reads for a buffer that only a free list
+# holds, calibrated with the expression ``_viewed_elsewhere`` uses. A
+# memoryview, and every array made by ``np.frombuffer`` or sliced from
+# one, holds a reference to the buffer object under it for as long as
+# it lives, so a higher count means somebody can still read the bytes.
+_calibration_bucket = [bytearray(1)]
+_FREE_LIST_REFS = sys.getrefcount(_calibration_bucket[0])
+
+
+def _viewed_elsewhere(bucket: List[Any], i: int) -> bool:
+    return sys.getrefcount(bucket[i]) > _FREE_LIST_REFS
 
 
 def _release_dropped() -> None:
@@ -94,12 +135,21 @@ class StagingLease:
     release defensively without double-crediting the budget.
     """
 
-    __slots__ = ("buffer", "nbytes", "_pool", "_released", "_budget_cb",
-                 "_budget_nbytes", "_lock")
+    __slots__ = ("buffer", "nbytes", "reused", "_pool", "_released",
+                 "_budget_cb", "_budget_nbytes", "_lock")
 
-    def __init__(self, pool: "StagingPool", buffer: bytearray, nbytes: int):
+    def __init__(
+        self,
+        pool: "StagingPool",
+        buffer: Any,  # a bytearray; of the takes' pool a uint8 array
+        nbytes: int,
+        reused: bool = False,
+    ):
         self.buffer = buffer
         self.nbytes = nbytes
+        # Whether the pool had the buffer (its pages touched by an
+        # earlier user) or allocated it for this lease.
+        self.reused = reused
         self._pool = pool
         self._released = False
         self._budget_cb: Optional[Callable[[int], None]] = None
@@ -138,9 +188,14 @@ class StagingLease:
             self._released = True
             cb, self._budget_cb = self._budget_cb, None
             nbytes = self._budget_nbytes
+            buffer = self.buffer
+            if self._pool.take_side:
+                # A reference from a released lease would read as a
+                # view of the buffer.
+                self.buffer = None
         if cb is not None:
             cb(nbytes)
-        self._pool._give_back(self.buffer, self.nbytes)
+        self._pool._give_back(buffer, self.nbytes)
 
     def __del__(self) -> None:
         # Safety net for error paths (a failed restore dropping its
@@ -150,7 +205,8 @@ class StagingLease:
         # failure injections (faultline crash matrices). Nobody else can
         # see an unreachable lease, so the flag is read without the
         # lock; the release itself happens in _release_dropped().
-        if not self._released:
+        # (At interpreter exit the module's names may be gone already.)
+        if not self._released and _dropped is not None:
             _dropped.append(self)
 
 
@@ -161,8 +217,22 @@ class StagingPool:
         self,
         capacity_bytes: int,
         max_wait_s: Optional[float] = None,
+        take_side: bool = False,
     ) -> None:
         self.capacity_bytes = capacity_bytes
+        # The takes' pool differs in what its users differ in. A take's
+        # payload goes to a storage plug-in, somebody else's code, where
+        # the restore's consumers own every view of their buffers and
+        # drop them before the release: so a free buffer that something
+        # outside this pool still refers to leaves it instead of being
+        # handed out. And sixteen threads copy into a take's buffer
+        # where one fills the restore's: so a miss allocates untouched
+        # pages (``np.empty``), which the copies fault in side by side,
+        # not a ``bytearray`` that one thread zeroes under the lock. The
+        # ``tpusnapshot_restore_staging_pool_*`` metrics are the restore
+        # pool's alone; the takes' pool shows in its snapmem domain and
+        # in each take's report (``stage_phases``).
+        self.take_side = take_side
         self.max_wait_s = (
             max_wait_s
             if max_wait_s is not None
@@ -179,7 +249,7 @@ class StagingPool:
         # the pinned side — free buffers are retention, leaked LEASES
         # are the drift the sentinel must name.
         self._mem_domain = memwatch.register(
-            "staging_pool",
+            _TAKE_DOMAIN if take_side else _RESTORE_DOMAIN,
             cap_bytes=capacity_bytes,
             watch_residual="pinned",
             owner=self,
@@ -198,14 +268,20 @@ class StagingPool:
         _release_dropped()
         with self._cond:
             buf = self._take_free_locked(nbytes)
-            if buf is None:
+            if buf is None and not self.take_side:
                 # No exact-size hit: retained free buffers of OTHER
                 # sizes are just idle bytearrays — evict them to make
                 # capacity room rather than stalling behind them (a
                 # cap full of model A's region sizes must not make
                 # model B's restore wait out max_wait_s per buffer).
+                # (The takes' cap bounds what is retained, not a take
+                # in flight: it makes room when a buffer comes back.)
                 self._evict_free_locked(nbytes)
-            if buf is None and self._must_wait_locked(nbytes):
+            if (
+                buf is None
+                and self.max_wait_s > 0
+                and self._must_wait_locked(nbytes)
+            ):
                 with _cprof.substep(profile, "pool_wait", nbytes):
                     deadline = time.monotonic() + self.max_wait_s
                     while buf is None and self._must_wait_locked(nbytes):
@@ -214,29 +290,45 @@ class StagingPool:
                             break
                         self._cond.wait(remaining)
                         buf = self._take_free_locked(nbytes)
-                telemetry.counter(_metric_names.RESTORE_POOL_WAITS).inc(1)
-                self._mem_domain.counter("waits")
+                self._count("waits", _metric_names.RESTORE_POOL_WAITS)
                 if buf is None:
                     buf = self._take_free_locked(nbytes)
-            if buf is None:
-                buf = bytearray(nbytes)
-                telemetry.counter(_metric_names.RESTORE_POOL_MISSES).inc(1)
-                self._mem_domain.counter("misses")
+            reused = buf is not None
+            if reused:
+                self._count("hits", _metric_names.RESTORE_POOL_HITS)
             else:
-                telemetry.counter(_metric_names.RESTORE_POOL_HITS).inc(1)
-                self._mem_domain.counter("hits")
+                buf = (
+                    np.empty(nbytes, np.uint8)
+                    if self.take_side
+                    else bytearray(nbytes)
+                )
+                self._count("misses", _metric_names.RESTORE_POOL_MISSES)
             self._in_use_bytes += nbytes
             self._publish_locked()
-        return StagingLease(self, buf, nbytes)
+        return StagingLease(self, buf, nbytes, reused)
 
-    def _take_free_locked(self, nbytes: int) -> Optional[bytearray]:
+    def _count(self, event: str, restore_metric: str) -> None:
+        self._mem_domain.counter(event)
+        if not self.take_side:
+            telemetry.counter(restore_metric).inc(1)
+
+    def _take_free_locked(self, nbytes: int) -> Optional[Any]:
+        """A free buffer of ``nbytes``. One of the takes' pool that
+        something outside the pool refers to (a plug-in kept the
+        payload, a cancelled pipeline's writer thread still reads it)
+        leaves the pool here instead: whoever views it owns it."""
         bucket = self._free.get(nbytes)
-        if not bucket:
-            return None
-        buf = bucket.pop()
-        if not bucket:
+        buf = None
+        while bucket and buf is None:
+            viewed = self.take_side and _viewed_elsewhere(
+                bucket, len(bucket) - 1
+            )
+            candidate = bucket.pop()
+            self._free_bytes -= nbytes
+            if not viewed:
+                buf = candidate
+        if bucket is not None and not bucket:
             del self._free[nbytes]
-        self._free_bytes -= nbytes
         return buf
 
     def _evict_free_locked(self, need_bytes: int) -> None:
@@ -254,12 +346,15 @@ class StagingPool:
             and self._in_use_bytes + self._free_bytes + need_bytes
             > self.capacity_bytes
         ):
-            size = next(iter(self._free))
-            bucket = self._free[size]
-            bucket.pop()
-            if not bucket:
-                del self._free[size]
-            self._free_bytes -= size
+            self._drop_oldest_free_locked()
+
+    def _drop_oldest_free_locked(self) -> None:
+        size = next(iter(self._free))
+        bucket = self._free[size]
+        bucket.pop()
+        if not bucket:
+            del self._free[size]
+        self._free_bytes -= size
 
     def _must_wait_locked(self, nbytes: int) -> bool:
         # Free bytes are evictable (see acquire) — only bytes held by
@@ -274,11 +369,38 @@ class StagingPool:
         _release_dropped()
         with self._cond:
             self._in_use_bytes -= nbytes
+            if self.take_side:
+                # The save that just wrote this buffer is what the next
+                # one will look like: the sizes unused for longest (the
+                # front of the dict) make room for it.
+                while (
+                    self._free
+                    and self._free_bytes + nbytes > self.capacity_bytes
+                ):
+                    self._drop_oldest_free_locked()
             if self._free_bytes + nbytes <= self.capacity_bytes:
                 self._free.setdefault(nbytes, []).append(buffer)
                 self._free_bytes += nbytes
             self._publish_locked()
             self._cond.notify_all()
+
+    def retain_up_to(self, nbytes: int) -> None:
+        """Raise the cap to ``nbytes`` if it is lower (the take side:
+        the bytes one take holds leased at this moment)."""
+        with self._cond:
+            if nbytes > self.capacity_bytes:
+                self.capacity_bytes = nbytes
+                self._mem_domain.set_cap(nbytes)
+
+    def trim(self) -> int:
+        """Let every free buffer go; returns the bytes let go. Leased
+        buffers are their holders'."""
+        _release_dropped()
+        with self._cond:
+            freed, self._free_bytes = self._free_bytes, 0
+            self._free.clear()
+            self._publish_locked()
+        return freed
 
     def _publish_locked(self) -> None:
         """Mirror occupancy into the gauges and the snapmem domain
@@ -286,15 +408,16 @@ class StagingPool:
         condition held after every byte-moving transition."""
         total = self._free_bytes + self._in_use_bytes
         self._high_water_bytes = max(self._high_water_bytes, total)
-        telemetry.gauge(_metric_names.RESTORE_POOL_RETAINED).set(
-            float(self._free_bytes)
-        )
-        telemetry.gauge(_metric_names.RESTORE_POOL_LEASED).set(
-            float(self._in_use_bytes)
-        )
-        telemetry.gauge(_metric_names.RESTORE_POOL_HWM).set(
-            float(self._high_water_bytes)
-        )
+        if not self.take_side:
+            telemetry.gauge(_metric_names.RESTORE_POOL_RETAINED).set(
+                float(self._free_bytes)
+            )
+            telemetry.gauge(_metric_names.RESTORE_POOL_LEASED).set(
+                float(self._in_use_bytes)
+            )
+            telemetry.gauge(_metric_names.RESTORE_POOL_HWM).set(
+                float(self._high_water_bytes)
+            )
         self._mem_domain.set_used(total, pinned_bytes=self._in_use_bytes)
 
     # ------------------------------------------------------------- stats
@@ -332,3 +455,34 @@ def reset_staging_pool() -> None:
             if pool is not None:
                 pool._mem_domain.close()
         _pool.clear()
+
+
+_take_pool: List[StagingPool] = []
+
+
+def get_take_staging_pool() -> StagingPool:
+    """The process-wide pool of the takes' assembly buffers (module
+    docstring, "The take side"). Starts with a cap of 0: it retains
+    nothing until a take has leased something."""
+    with _pool_lock:
+        if not _take_pool:
+            _take_pool.append(
+                StagingPool(0, max_wait_s=0.0, take_side=True)
+            )
+        return _take_pool[0]
+
+
+def trim_take_staging_pool() -> int:
+    """Give back the host memory the take side retains between saves
+    (at most one capture's bytes); the next host-staged save pays the
+    first touch of fresh pages again. Returns the bytes let go."""
+    return get_take_staging_pool().trim()
+
+
+def reset_take_staging_pool() -> None:
+    """Drop the takes' pool with its cap (tests)."""
+    _release_dropped()
+    with _pool_lock:
+        for pool in _take_pool:
+            pool._mem_domain.close()
+        _take_pool.clear()

@@ -68,8 +68,10 @@ other                   consume wall the sub-steps above did not account
 take (``stage.``)       what it times (``ArrayBufferStager._stage_sync``
                         and ``ops/transfer.parallel_device_get._fetch``)
 ======================  ================================================
-alloc                   the ``np.empty`` of a chunked leaf's assembly
-                        buffer
+alloc                   a chunked leaf's assembly buffer: a lease of the
+                        takes' pool (``staging_pool.py``; its span says
+                        whether the pool had the buffer, ``pool`` "hit"
+                        or "miss"), or an ``np.empty`` outside a take
 slice                   dispatch of the device slice: one
                         ``jax.lax.slice_in_dim`` a chunk, and
                         ``data[chunk_slices]`` of a subdivided shard
@@ -89,6 +91,10 @@ other                   the rest of the thread-seconds inside
                         ``_stage_sync`` and ``_fetch``: the block sums to
                         them exactly
 ======================  ================================================
+
+A take's block also carries ``pool_hit_bytes`` and ``pool_miss_bytes``:
+the bytes of assembly buffers the takes' pool had, their pages touched
+by an earlier save of the process, and the bytes it allocated afresh.
 
 Scoping matches the snapserve read-plane attribution: the profile is a
 contextvar set in the restoring (or taking) thread; consumers and
@@ -166,6 +172,8 @@ class PhaseProfile:
         "_lock",
         "_agg",
         "_wall_s",
+        "_pool_bytes",
+        "_pool_leased_now",
         "trace_id",
         "_ov_active",
         "_ov_start",
@@ -179,6 +187,9 @@ class PhaseProfile:
         # Thread-seconds the noting code itself spent (``wall``): the
         # take has no scheduler op that sums them, as the restore has.
         self._wall_s = 0.0
+        # A take's assembly-buffer bytes the pool had / allocated.
+        self._pool_bytes = {"hit": 0, "miss": 0}
+        self._pool_leased_now = 0
         # Captured here so executor-thread sub-step spans can stamp the
         # operation's trace id without a contextvar handoff.
         self.trace_id = tracing.current_trace_id()
@@ -202,6 +213,18 @@ class PhaseProfile:
     def add_wall(self, seconds: float) -> None:
         with self._lock:
             self._wall_s += seconds
+
+    def note_pool_lease(self, reused: bool, nbytes: int) -> int:
+        """Count one assembly buffer leased from the takes' pool;
+        returns the bytes this take holds leased now."""
+        with self._lock:
+            self._pool_bytes["hit" if reused else "miss"] += nbytes
+            self._pool_leased_now += nbytes
+            return self._pool_leased_now
+
+    def note_pool_release(self, nbytes: int) -> None:
+        with self._lock:
+            self._pool_leased_now -= nbytes
 
     def _overlap_enter(self) -> None:
         with self._lock:
@@ -253,6 +276,10 @@ class PhaseProfile:
             "substeps": substeps,
             "accounted_s": round(accounted, 6),
         }
+        if self.kind == "stage":
+            with self._lock:
+                block["pool_hit_bytes"] = self._pool_bytes["hit"]
+                block["pool_miss_bytes"] = self._pool_bytes["miss"]
         if wall_s is not None:
             block[f"{self.kind}_s"] = round(wall_s, 6)
             substeps["other"] = {
@@ -400,11 +427,14 @@ def note_interval(
     begin: float,
     end: float,
     nbytes: int = 0,
+    **span_args: Any,
 ) -> None:
-    """Note a sub-step whose two ends were read off ``time.monotonic()``
-    on different threads (a wait for an executor, for the event loop):
-    no ``with`` block can span it. Not routed: the caller knows on
-    which side of the consume wall the wait lies."""
+    """Note a sub-step whose two ends the caller read off
+    ``time.monotonic()`` itself: on different threads (a wait for an
+    executor, for the event loop), where no ``with`` block can span it,
+    or around a stretch whose outcome its span is to carry
+    (``span_args``). Not routed: the caller knows on which side of the
+    consume wall the stretch lies."""
     if profile is None:
         return
     profile.note(name, end - begin, nbytes)
@@ -414,6 +444,7 @@ def note_interval(
             begin,
             end,
             **_span_args(profile, nbytes),
+            **span_args,
         )
 
 
