@@ -2009,9 +2009,17 @@ class ArrayRestorePlan:
         template untouched, never with a torn leaf."""
         with self._lock:
             transfers = list(self._transfers)
-        for fut in transfers:
-            fut.result()  # re-raises transfer errors
+        waited = len(transfers)
+        while transfers:
+            transfers.pop().result()  # re-raises transfer errors
+        # A resolved future holds what it returned: a streamed part's
+        # device chunk. Let go of those waited for, or every chunk
+        # outlives its region's assembly for as long as this plan does
+        # (it sits in reference cycles, so until a cyclic collection),
+        # and a state above half of HBM is on the device nearly twice
+        # while the device budget counts the chunks as freed.
         with self._lock:
+            del self._transfers[:waited]
             outstanding = self._outstanding
         if outstanding == 0:
             return

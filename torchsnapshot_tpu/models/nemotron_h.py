@@ -42,13 +42,15 @@ cast to float32 where they are used.
 
 import dataclasses
 import math
-from typing import Any, Dict, NamedTuple, Tuple
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..ops.attention import flash_attention, resolve_flash_block
+from . import experts
+from .mixed_adamw import AdamW, Moments, adamw_update, state_of_master  # noqa: F401
 
 _F32 = jnp.float32
 
@@ -104,19 +106,15 @@ class NemotronHConfig:
     def conv_dim(self) -> int:
         return self.mamba_inner + 2 * self.n_groups * self.ssm_state_size
 
-
-class Moments(NamedTuple):
-    mu: Any
-    nu: Any
-
-
-@dataclasses.dataclass(frozen=True)
-class AdamW:
-    lr: float = 1e-4
-    b1: float = 0.9
-    b2: float = 0.95
-    eps: float = 1e-8
-    weight_decay: float = 0.1  # decoupled, on leaves of two or more axes
+    @property
+    def routing(self) -> experts.Routing:
+        return experts.Routing(
+            expert_ids=self.expert_ids,
+            top_k=self.num_experts_per_tok,
+            normalise=self.norm_topk_prob,
+            scaling_factor=self.routed_scaling_factor,
+            capacity=self.expert_capacity,
+        )
 
 
 # ------------------------------------------------------------------ init
@@ -190,13 +188,7 @@ def init_master(config: NemotronHConfig, key: jax.Array) -> Dict[str, Any]:
 def init_state(config: NemotronHConfig, key: jax.Array) -> Dict[str, Any]:
     """The whole training state, jit-able: master weights, their compute
     copies, zeroed moments, count 0."""
-    master = init_master(config, key)
-    zeros = jax.tree.map(jnp.zeros_like, master)
-    return {
-        "params": jax.tree.map(lambda m: m.astype(config.dtype), master),
-        "master": master,
-        "opt": (Moments(zeros, zeros), jnp.zeros((), jnp.int32)),
-    }
+    return state_of_master(init_master(config, key), config.dtype)
 
 
 # ---------------------------------------------------------------- blocks
@@ -313,69 +305,35 @@ def mamba_mixer(x, blk, config: NemotronHConfig):
     return jnp.einsum("bte,ed->btd", y, blk["out_proj"])
 
 
-def held_gates(x, blk, config: NemotronHConfig):
-    """``[tokens, held experts]`` float32: the weight with which each
-    held expert's result enters each token, 0 where the token's top k
-    (over all ``n_routed_experts``) does not name it."""
-    scores = jax.nn.sigmoid(
-        jnp.einsum("td,de->te", x.astype(_F32), blk["router"].astype(_F32))
-    )
-    _, chosen = jax.lax.top_k(
-        scores + blk["router_bias"].astype(_F32), config.num_experts_per_tok
-    )
-    weights = jnp.take_along_axis(scores, chosen, axis=-1)
-    if config.norm_topk_prob:
-        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
-    weights = weights * config.routed_scaling_factor
-    held = jnp.asarray(config.expert_ids, chosen.dtype)
-    hit = chosen[:, :, None] == held[None, None, :]  # [T, K, held]
-    return jnp.sum(jnp.where(hit, weights[:, :, None], 0.0), axis=1), jnp.any(hit, axis=1)
-
-
 def _relu2(h):
     return jnp.square(jax.nn.relu(h))
 
 
+def _relu2_of(up):
+    """The body of this model's experts: ``relu(x W_up)^2``, no gate."""
+    return lambda project: _relu2(project(up))
+
+
+def held_gates(x, blk, config: NemotronHConfig):
+    """``[tokens, held experts]`` float32 gates and which tokens go to
+    which held expert (:func:`experts.held_gates`; the correction bias
+    enters the choice alone)."""
+    return experts.held_gates(x, blk["router"], blk["router_bias"], config.routing)
+
+
 def _experts_dense(x, gates, up, down):
-    """Every held expert on every token, weighted by its gate."""
-    h = _relu2(jnp.einsum("td,edf->etf", x, up))
-    h = h * gates.T[:, :, None].astype(h.dtype)
-    return jnp.einsum("etf,efd->td", h, down)
+    return experts.experts_dense(x, gates, _relu2_of(up), down)
 
 
 def _experts_gathered(x, gates, routed, up, down, capacity):
-    """Each held expert on the tokens routed to it, gathered into
-    ``capacity`` slots; exact when no expert is sent more than that."""
-    t = x.shape[0]
-    held = gates.shape[1]
-    slot = jnp.where(routed, jnp.cumsum(routed, axis=0) - 1, capacity)
-    expert = jnp.broadcast_to(jnp.arange(held), (t, held))
-    token = jnp.broadcast_to(jnp.arange(t)[:, None], (t, held))
-    # token_of[e, s]: the s-th token routed to e; t marks an empty slot.
-    token_of = jnp.full((held, capacity), t, jnp.int32).at[expert, slot].set(
-        token, mode="drop"
-    )
-    xg = jnp.take(x, token_of, axis=0, mode="fill", fill_value=0)
-    gg = jnp.take_along_axis(
-        jnp.pad(gates, [(0, 1), (0, 0)]).T, token_of, axis=1
-    )
-    h = _relu2(jnp.einsum("ecd,edf->ecf", xg, up)) * gg[:, :, None].astype(x.dtype)
-    y = jnp.einsum("ecf,efd->ecd", h, down)
-    return jnp.zeros_like(x).at[token_of.reshape(-1)].add(
-        y.reshape(-1, x.shape[1]), mode="drop"
-    )
+    return experts.experts_gathered(x, gates, routed, _relu2_of(up), down, capacity)
 
 
 def routed_experts(x, blk, config: NemotronHConfig):
     """The held experts' part of the layer's result, ``x`` [tokens, d]."""
-    gates, routed = held_gates(x, blk, config)
-    capacity = config.expert_capacity
-    if not capacity or capacity >= x.shape[0]:
-        return _experts_dense(x, gates, blk["up"], blk["down"])
-    return jax.lax.cond(
-        jnp.max(jnp.sum(routed, axis=0)) <= capacity,
-        lambda: _experts_gathered(x, gates, routed, blk["up"], blk["down"], capacity),
-        lambda: _experts_dense(x, gates, blk["up"], blk["down"]),
+    return experts.routed_experts(
+        x, blk["router"], blk["router_bias"], _relu2_of(blk["up"]), blk["down"],
+        config.routing,
     )
 
 
@@ -441,32 +399,6 @@ def loss_fn(params, tokens, config: NemotronHConfig):
     """Next-token cross entropy over the held rows of the vocabulary."""
     logp = jax.nn.log_softmax(forward(params, tokens, config)[:, :-1], axis=-1)
     return -jnp.mean(jnp.take_along_axis(logp, tokens[:, 1:, None], axis=-1))
-
-
-def adamw_update(state, grads, hp: AdamW):
-    """Mixed-precision AdamW: moments and master in float32, the compute
-    copies recast from the master; decoupled weight decay on leaves of
-    two or more axes."""
-    (moments, count), master = state["opt"], state["master"]
-    count = count + 1
-    t = count.astype(_F32)
-    grads = jax.tree.map(lambda g: g.astype(_F32), grads)
-    mu = jax.tree.map(lambda m, g: hp.b1 * m + (1 - hp.b1) * g, moments.mu, grads)
-    nu = jax.tree.map(lambda n, g: hp.b2 * n + (1 - hp.b2) * g * g, moments.nu, grads)
-
-    def step(w, m, n):
-        update = (m / (1 - hp.b1**t)) / (jnp.sqrt(n / (1 - hp.b2**t)) + hp.eps)
-        if w.ndim >= 2:
-            update = update + hp.weight_decay * w
-        return w - hp.lr * update
-
-    master = jax.tree.map(step, master, mu, nu)
-    dtype_of = jax.tree.map(lambda p: p.dtype, state["params"])
-    return {
-        "params": jax.tree.map(lambda w, d: w.astype(d), master, dtype_of),
-        "master": master,
-        "opt": (Moments(mu, nu), count),
-    }
 
 
 def adamw_train_step(state, tokens, config: NemotronHConfig, hp: AdamW = AdamW()):

@@ -54,9 +54,22 @@ def _causal_positions(qi, kj, block_q: int, block_k: int):
     return q_pos, k_pos
 
 
-def _block_visible(qi, kj, block_q: int, block_k: int):
-    """Whether any key of block kj is visible (causally) to block qi."""
-    return kj * block_k <= qi * block_q + (block_q - 1)
+def _block_visible(qi, kj, block_q: int, block_k: int, window=None):
+    """Whether any key of block kj is visible (causally, and under a
+    ``window``: within it) to some query of block qi."""
+    visible = kj * block_k <= qi * block_q + (block_q - 1)
+    if window is None:
+        return visible
+    # The block's last key lies within the window of its first query.
+    return visible & (qi * block_q - (kj * block_k + block_k - 1) < window)
+
+
+def _visible(q_pos, k_pos, window=None):
+    """The causal mask of one tile; under a ``window`` query i sees the
+    keys j with ``0 <= i - j < window``."""
+    if window is None:
+        return k_pos <= q_pos
+    return (k_pos <= q_pos) & (q_pos - k_pos < window)
 
 
 def resolve_flash_block(seq_len: int) -> int:
@@ -146,6 +159,7 @@ def _flash_kernel(
     causal: bool,
     block_q: int,
     block_k: int,
+    window: Optional[int] = None,
 ):
     qi = pl.program_id(1)
     kj = pl.program_id(2)
@@ -158,8 +172,9 @@ def _flash_kernel(
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     # Causal: key block kj is entirely in the future of query block qi
-    # iff its first key index exceeds the last query index.
-    run = _block_visible(qi, kj, block_q, block_k) if causal else True
+    # iff its first key index exceeds the last query index; under a
+    # window it may also lie entirely behind every query's window.
+    run = _block_visible(qi, kj, block_q, block_k, window) if causal else True
 
     @pl.when(run)
     def _step():
@@ -174,10 +189,16 @@ def _flash_kernel(
         ) * scale  # [block_q, block_k]
         if causal:
             q_pos, k_pos = _causal_positions(qi, kj, block_q, block_k)
-            s = jnp.where(k_pos <= q_pos, s, _NEG_INF)
+            s = jnp.where(_visible(q_pos, k_pos, window), s, _NEG_INF)
         m_prev = m_ref[:]  # [block_q, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)  # [block_q, block_k]
+        if causal and window is not None:
+            # The first block a query block visits need not hold a key
+            # that each of its rows sees (causal alone: key 0 always
+            # is). Such a row has m_new = -1e30 and exp(s - m_new) = 1
+            # on its masked keys, which must not enter l or acc.
+            p = jnp.where(_visible(q_pos, k_pos, window), p, 0.0)
         alpha = jnp.exp(m_prev - m_new)  # [block_q, 1]
         l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
         acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
@@ -200,7 +221,7 @@ def _flash_kernel(
 
 
 def _bwd_pieces(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *, scale,
-                causal, qi, kj, block_q, block_k):
+                causal, qi, kj, block_q, block_k, window=None):
     """Recompute p and ds for one (q-block, k-block) pair — the shared
     core of both backward kernels. Returns (p, ds), both [block_q,
     block_k] float32."""
@@ -219,7 +240,7 @@ def _bwd_pieces(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *, scale,
     p = jnp.where(lse_raw > _NEG_INF / 2, jnp.exp(s - lse_safe), 0.0)
     if causal:
         q_pos, k_pos = _causal_positions(qi, kj, block_q, block_k)
-        p = jnp.where(k_pos <= q_pos, p, 0.0)
+        p = jnp.where(_visible(q_pos, k_pos, window), p, 0.0)
     dp = jax.lax.dot_general(
         do, v, dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -230,7 +251,7 @@ def _bwd_pieces(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *, scale,
 
 def _flash_bwd_dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc,
-    *, scale, causal, block_q, block_k,
+    *, scale, causal, block_q, block_k, window=None,
 ):
     qi = pl.program_id(1)
     kj = pl.program_id(2)
@@ -239,14 +260,14 @@ def _flash_bwd_dq_kernel(
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    run = _block_visible(qi, kj, block_q, block_k) if causal else True
+    run = _block_visible(qi, kj, block_q, block_k, window) if causal else True
 
     @pl.when(run)
     def _step():
         _, ds = _bwd_pieces(
             q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             scale=scale, causal=causal, qi=qi, kj=kj,
-            block_q=block_q, block_k=block_k,
+            block_q=block_q, block_k=block_k, window=window,
         )
         dq_acc[:] += scale * jax.lax.dot_general(
             ds, k_ref[0].astype(jnp.float32),
@@ -261,7 +282,7 @@ def _flash_bwd_dq_kernel(
 
 def _flash_bwd_dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-    dk_acc, dv_acc, *, scale, causal, block_q, block_k,
+    dk_acc, dv_acc, *, scale, causal, block_q, block_k, window=None,
 ):
     # Grid: (bh, n_k, n_q) — the q-block axis iterates sequentially so
     # the dk/dv accumulators persist across it.
@@ -273,15 +294,16 @@ def _flash_bwd_dkv_kernel(
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    # Causal: q block strictly before the k block contributes nothing.
-    run = _block_visible(qi, kj, block_q, block_k) if causal else True
+    # Causal: q block strictly before the k block contributes nothing,
+    # nor does one whose every query has left the block behind its window.
+    run = _block_visible(qi, kj, block_q, block_k, window) if causal else True
 
     @pl.when(run)
     def _step():
         p, ds = _bwd_pieces(
             q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             scale=scale, causal=causal, qi=qi, kj=kj,
-            block_q=block_q, block_k=block_k,
+            block_q=block_q, block_k=block_k, window=window,
         )
         dv_acc[:] += jax.lax.dot_general(
             p, do_ref[0].astype(jnp.float32),
@@ -300,31 +322,36 @@ def _flash_bwd_dkv_kernel(
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _reference_attention(q, k, v, causal):
+def _reference_attention(q, k, v, causal, window=None):
     """Differentiable einsum attention — the kernels' numerical spec
     (forward and backward match it to float tolerance, not bitwise: the
-    tiled kernels reassociate the softmax reductions)."""
+    tiled kernels reassociate the softmax reductions). ``window``:
+    query i sees the keys j with ``0 <= i - j < window``."""
     d = q.shape[-1]
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) / (d**0.5)
     if causal:
         length = q.shape[2]
         mask = jnp.tril(jnp.ones((length, length), bool))
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones((length, length), bool), -window)
         s = jnp.where(mask[None, None], s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bhkd->bhqd", p, v)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_attention(q, k, v, causal, block_q, block_k, interpret):
-    return _flash_forward(q, k, v, causal, block_q, block_k, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_attention(q, k, v, causal, block_q, block_k, interpret, window):
+    return _flash_forward(q, k, v, causal, block_q, block_k, interpret, window)[0]
 
 
-def _flash_fwd_rule(q, k, v, causal, block_q, block_k, interpret):
-    out, lse = _flash_forward(q, k, v, causal, block_q, block_k, interpret)
+def _flash_fwd_rule(q, k, v, causal, block_q, block_k, interpret, window):
+    out, lse = _flash_forward(
+        q, k, v, causal, block_q, block_k, interpret, window
+    )
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd_rule(causal, block_q, block_k, interpret, residuals, g):
+def _flash_bwd_rule(causal, block_q, block_k, interpret, window, residuals, g):
     # Tiled Pallas backward: p is reconstructed per tile from the saved
     # log-sum-exp, so the backward, like the forward, never materializes
     # the S×S score matrix (O(S·D) memory end to end). Two kernels: dq
@@ -336,7 +363,7 @@ def _flash_bwd_rule(causal, block_q, block_k, interpret, residuals, g):
         keepdims=True,
     )
     dq, dk, dv = _flash_backward(
-        q, k, v, g, lse, delta, causal, block_q, block_k, interpret
+        q, k, v, g, lse, delta, causal, block_q, block_k, interpret, window
     )
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
@@ -356,9 +383,12 @@ def _resolve_blocks(s: int, block_q: int, block_k: int):
 
 
 @functools.partial(
-    jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret")
+    jax.jit,
+    static_argnames=("causal", "block_q", "block_k", "interpret", "window"),
 )
-def _flash_backward(q, k, v, g, lse, delta, causal, block_q, block_k, interpret):
+def _flash_backward(
+    q, k, v, g, lse, delta, causal, block_q, block_k, interpret, window=None
+):
     b, h, s, d = q.shape
     group = _gqa_group(q, k)
     hkv = h // group
@@ -384,7 +414,7 @@ def _flash_backward(q, k, v, g, lse, delta, causal, block_q, block_k, interpret)
     dq = pl.pallas_call(
         functools.partial(
             _flash_bwd_dq_kernel, scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k,
+            block_q=block_q, block_k=block_k, window=window,
         ),
         out_shape=jax.ShapeDtypeStruct((bh, s, d), jnp.float32),
         grid=(bh, s // block_q, s // block_k),
@@ -407,7 +437,7 @@ def _flash_backward(q, k, v, g, lse, delta, causal, block_q, block_k, interpret)
     dk, dv = pl.pallas_call(
         functools.partial(
             _flash_bwd_dkv_kernel, scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k,
+            block_q=block_q, block_k=block_k, window=window,
         ),
         out_shape=(
             jax.ShapeDtypeStruct((bh, s, d), jnp.float32),
@@ -440,11 +470,26 @@ def flash_attention(
     block_q: int = 128,
     block_k: int = 128,
     interpret: Optional[bool] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
-    """softmax(QKᵀ/√D)·V without materializing the S×S score matrix."""
+    """softmax(QKᵀ/√D)·V without materializing the S×S score matrix.
+
+    ``window`` (causal only): query i sees the keys j with ``0 <= i - j
+    < window``; key blocks that lie wholly behind a query block's
+    windows are skipped as those above the diagonal are. It need not be
+    a multiple of the blocks. ``None`` is plain causal attention, and
+    compiles to the program it compiled to before windows existed."""
+    if window is not None:
+        if not causal:
+            raise ValueError("a window is a causal window: causal must be True")
+        if window < 1:
+            raise ValueError(f"window must be at least 1, got {window}")
+        if window >= q.shape[2]:
+            window = None  # every key at or before a query is within it
     if interpret is None:
         interpret = resolve_interpret()
-    return _flash_attention(q, k, v, causal, block_q, block_k, interpret)
+    return _flash_attention(q, k, v, causal, block_q, block_k, interpret, window)
+
 
 
 def _gqa_group(q: jax.Array, k: jax.Array) -> int:
@@ -478,7 +523,8 @@ def _kv_index_map(h: int, group: int, block_axis: int = 2):
 
 
 @functools.partial(
-    jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret")
+    jax.jit,
+    static_argnames=("causal", "block_q", "block_k", "interpret", "window"),
 )
 def _flash_forward(
     q: jax.Array,  # [B, Hq, S, D]
@@ -488,6 +534,7 @@ def _flash_forward(
     block_q: int,
     block_k: int,
     interpret: bool,  # resolved by flash_attention(); never None here
+    window: Optional[int] = None,
 ):
     """Returns (out [B,Hq,S,D], lse [B,Hq,S,1] float32)."""
     b, h, s, d = q.shape
@@ -506,6 +553,7 @@ def _flash_forward(
         causal=causal,
         block_q=block_q,
         block_k=block_k,
+        window=window,
     )
     kv_map = _kv_index_map(h, group)
     out, lse = pl.pallas_call(

@@ -532,6 +532,15 @@ async def execute_read_reqs(
     device_budget = _BudgetCell(
         device_budget_bytes if device_budget_bytes is not None else (1 << 62)
     )
+    # Admissions held back on the device budget: when a scan first found
+    # a consumable payload that HBM had no room for (by ``id`` of its
+    # request; with the device bytes it asked for then: a region's later
+    # sub-reads cost nothing once its first was admitted), until its
+    # consume is dispatched. Each is a ``restore.device_budget_wait``
+    # span and one of the report's ``device_budget_waits``.
+    held_back_since: Dict[int, Tuple[float, int]] = {}
+    device_waits = 0
+    device_wait_s = 0.0
     bytes_read = 0
     max_io = storage.max_read_concurrency
     executor = ThreadPoolExecutor(max_workers=_MAX_STAGING_THREADS)
@@ -647,6 +656,9 @@ async def execute_read_reqs(
                     if not dcost or device_budget.value >= dcost:
                         pick = i
                         break
+                    held_back_since.setdefault(
+                        id(rr), (time.monotonic(), dcost)
+                    )
                 if pick is None:
                     if reading or consuming:
                         # Device-budget wait is stall too: consumable
@@ -669,6 +681,19 @@ async def execute_read_reqs(
                     )
                 consumer = rr.buffer_consumer
                 dcost = consumer.get_device_cost_bytes()
+                held_back = held_back_since.pop(id(rr), None)
+                if held_back is not None:
+                    since, asked = held_back
+                    admitted = time.monotonic()
+                    tracing.interval(
+                        "restore.device_budget_wait",
+                        since,
+                        admitted,
+                        path=rr.path,
+                        bytes=asked,
+                    )
+                    device_waits += 1
+                    device_wait_s += admitted - since
                 if dcost:
                     device_budget.charge(dcost)
                     consumer.set_device_cost_releaser(device_budget.release)
@@ -738,6 +763,13 @@ async def execute_read_reqs(
         memory_budget_bytes - min_budget,
         ops,
     )
+    if stats is not None:
+        stats["device_budget_waits"] = (
+            stats.get("device_budget_waits", 0) + device_waits
+        )
+        stats["device_budget_wait_s"] = (
+            stats.get("device_budget_wait_s", 0.0) + device_wait_s
+        )
     mbps = bytes_read / 1024 / 1024 / elapsed if elapsed > 0 else 0.0
     logger.info(
         "Rank %d finished loading (%d bytes). Throughput: %.2f MB/s",

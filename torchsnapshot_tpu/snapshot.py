@@ -994,12 +994,20 @@ class Snapshot:
         assemble_s = read_stats.pop("assemble_s", 0.0)
         recorder.note_pipeline(read_stats)
         # Whether a template had to make room (0: it fitted beside the
-        # landed arrays), and the fullest device's peak as the runtime
-        # reports it (None on a backend that reports none; a peak since
-        # the process began, so an upper bound on this restore's own).
+        # landed arrays), how many consumes the read pipeline held back
+        # because HBM had no room for their chunks yet and for how long
+        # in all (admissions wait side by side, so the seconds are
+        # thread-seconds, not wall), and the fullest device's peak as the
+        # runtime reports it (None on a backend that reports none; a peak
+        # since the process began, so an upper bound on this restore's
+        # own).
         recorder.note(
             template_released_bytes=read_stats.pop(
                 "template_released_bytes", 0
+            ),
+            device_budget_waits=read_stats.pop("device_budget_waits", 0),
+            device_budget_wait_s=round(
+                read_stats.pop("device_budget_wait_s", 0.0), 6
             ),
             device_peak_bytes=device_peak_bytes(),
         )
@@ -3047,8 +3055,16 @@ def _load_stateful(
         and path_globs is None
         and template_crowds_device(flattened.values())
     ):
+        release_t0 = time.monotonic()
         released = forget_device_templates(flattened)
         release()
+        tracing.interval(
+            "restore.release_template",
+            release_t0,
+            time.monotonic(),
+            key=key,
+            bytes=released,
+        )
         logger.info(
             "restore of %r: the arrays to land do not fit beside the "
             "template; released %d bytes of template before reading "
