@@ -20,6 +20,7 @@ import torchsnapshot_tpu.io_preparer as iop
 from torchsnapshot_tpu import PytreeStateful, Snapshot, tracing
 from torchsnapshot_tpu.io_types import BufferConsumer, IOReq, ReadReq
 from torchsnapshot_tpu.scheduler import execute_read_reqs
+from torchsnapshot_tpu.storage_plugins.fs import FSStoragePlugin
 from torchsnapshot_tpu.storage_plugins.memory import MemoryStoragePlugin
 
 
@@ -158,6 +159,10 @@ def test_a_restore_under_a_faked_budget_reports_its_release_and_its_waits(
     path = str(tmp_path / "snap")
     Snapshot.take(path, {"m": PytreeStateful({"a": a, "b": b})})
     monkeypatch.setenv("TPUSNAPSHOT_DEVICE_BUDGET_BYTES", str(9 << 20))
+    # Parts of both regions in flight together, so that b's first payload
+    # arrives while a is still in assembly (through the fs plug-in's two
+    # streams b's may arrive after a has been assembled: no wait at all).
+    monkeypatch.setattr(FSStoragePlugin, "max_read_concurrency", 16)
     target = {"m": PytreeStateful({"a": jnp.zeros_like(a), "b": jnp.zeros_like(b)})}
     Snapshot(path).restore(target)
     tracing.flush()

@@ -1043,6 +1043,12 @@ class _StreamingSplitState(_SplitObjectReadState):
         )
         self._next_off = 0
         self._stash: Dict[int, BufferType] = {}
+        # Held across a part's crc32 fold (45 ms a 64 MiB part), and by
+        # nothing else: the stash, the offset and the crc are the
+        # fold's own. ``self._lock`` guards the small state that the
+        # event loop's thread and the overlap engine's callbacks touch,
+        # and is never held across a fold, so neither waits for one.
+        self._fold_lock = threading.Lock()
         self._released = 0  # deferred bytes already re-credited
         self._device_release: Optional[Callable[[int], None]] = None
         self._deposited = 0  # device bytes charged by the scheduler
@@ -1200,11 +1206,12 @@ class _StreamingSplitState(_SplitObjectReadState):
                     )
                 if self._crc is not None:
                     drained: List[Tuple[int, int]] = []
-                    # The fold is in order and under the stream's lock:
-                    # the wait for the lock (other parts of this object
-                    # folding) is ``verify_wait``, the fold ``verify``.
+                    # The fold is in order and under the stream's fold
+                    # lock: the wait for the lock (other parts of this
+                    # object folding) is ``verify_wait``, the fold
+                    # ``verify``.
                     with _cprof.substep(profile, "verify_wait", len(buf)):
-                        self._lock.acquire()
+                        self._fold_lock.acquire()
                     try:
                         with _cprof.substep(profile, "verify", len(buf)):
                             self._stash[start] = buf
@@ -1216,7 +1223,7 @@ class _StreamingSplitState(_SplitObjectReadState):
                                 drained.append((off, len(b)))
                             stream_done = self._next_off >= self.nbytes
                     finally:
-                        self._lock.release()
+                        self._fold_lock.release()
                     # Re-credit drained parts outside the state lock
                     # (the budget cell takes its own lock).
                     for off, n in drained:

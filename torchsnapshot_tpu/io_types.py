@@ -373,8 +373,8 @@ class StoragePlugin(abc.ABC):
     # How many concurrent IO ops this backend profits from, read by the
     # scheduler as its per-pipeline concurrency caps. Object stores
     # (GCS/S3) want many parallel streams both ways; the fs plugin writes
-    # one object at a time (measured, see its comment) and reads with the
-    # default fan-out.
+    # one object at a time and reads through two streams (both measured,
+    # see its comments).
     max_write_concurrency: int = 16
     max_read_concurrency: int = 16
 

@@ -7,6 +7,9 @@ with storage IO under a per-process host-memory budget:
 - write: ``stage_buffer`` (HBM→RAM copy + serialize, thread executor)
   → ``storage.write``;
 - read: ``storage.read`` → ``consume_buffer`` (deserialize + RAM→HBM).
+  The reads are issued by a stage with a thread and a loop of its own
+  (``_ReadStage``): the next read starts when a read returns, whatever
+  the loop that admits the consumes is busy with.
 
 Budget accounting is symmetric and conservative (the reference *adds*
 instead of subtracting the read budget at dispatch, scheduler.py:209,
@@ -34,6 +37,7 @@ scheduler.py:104-117).
 """
 
 import asyncio
+import contextvars
 import io
 import logging
 import os
@@ -42,7 +46,7 @@ import threading
 import time
 from collections import deque
 from concurrent.futures import ThreadPoolExecutor
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Set, Tuple
 
 import psutil
 
@@ -412,16 +416,23 @@ class _BudgetCell:
     (split-read assembly buffers, streaming-split crc stashes): ``release``
     re-credits when the backing allocation is actually freed, not when a
     consume task completes. Locked: streaming splits release from executor
-    threads as their in-order prefix drains, racing the event loop's
-    charge/refund."""
+    threads as their in-order prefix drains, racing the charges of the
+    read stage's thread and the event loop's refunds. The callback of
+    ``call_on_release`` runs after every release, outside the lock: the
+    read stage's wake-up when the head of its queue waits for room."""
 
-    __slots__ = ("value", "_lock", "_charges", "_releases")
+    __slots__ = ("value", "_lock", "_charges", "_releases", "_on_release")
 
     def __init__(self, value: int) -> None:
         self.value = value
         self._lock = threading.Lock()
         self._charges = 0
         self._releases = 0
+        self._on_release: Optional[Callable[[], None]] = None
+
+    def call_on_release(self, callback: Callable[[], None]) -> None:
+        with self._lock:
+            self._on_release = callback
 
     def charge(self, nbytes: int) -> None:
         with self._lock:
@@ -432,6 +443,9 @@ class _BudgetCell:
         with self._lock:
             self.value += nbytes
             self._releases += 1
+            on_release = self._on_release
+        if on_release is not None:
+            on_release()
 
     def charge_count(self) -> int:
         with self._lock:
@@ -470,6 +484,275 @@ async def _straggler_release_landed(cell: _BudgetCell) -> bool:
     return cell.release_count() != baseline
 
 
+def _start_threads(executor: ThreadPoolExecutor, count: int) -> None:
+    """Have ``executor`` start ``count`` of its threads now.
+
+    A ``ThreadPoolExecutor`` starts a thread inside ``submit`` when none
+    is idle, under ``concurrent.futures``' process-wide lock. A restore
+    that left that to its first sixteen consumes paid for it while reads
+    were in flight: consume threads queued on that lock behind the
+    event loop's thread, which was starting threads (PERF.md section 5,
+    PR 30). Every submit here finds the earlier tasks still held at the
+    gate, so each starts a thread; afterwards all of them are idle."""
+    gate = threading.Event()
+    held = [executor.submit(gate.wait) for _ in range(count)]
+    gate.set()
+    for task in held:
+        task.result()
+
+
+# How long a failed or cancelled run waits for the read stage's thread
+# to leave its loop. Its reads are cancelled first, so this is reached
+# only by a plug-in whose ``read`` does not yield to cancellation.
+_READ_STAGE_JOIN_S = 5.0
+
+
+class _ReadStage:
+    """The read half of :func:`execute_read_reqs`, on a thread and an
+    event loop of its own.
+
+    It keeps up to ``storage.max_read_concurrency`` calls of
+    ``storage.read`` in flight, in the order of ``pending`` and under
+    the host budget, and starts the next one when one returns: not when
+    the loop that admits the consumes next comes round, which answers an
+    event 0.16-0.21 s late while sixteen consumes fold and submit
+    (PERF.md section 5, PR 30). A returned payload is posted to that
+    loop (``deliver``); so is the first failure (``fail``).
+
+    ``storage.read`` is the only call into the plug-in. Its threads
+    (the default executor of the stage's loop, which
+    ``loop.run_in_executor(None, ...)`` in a plug-in lands on) exist
+    before the first read is issued.
+
+    What the two threads share: the budget cell (locked); ``issued``
+    and ``consumed``, each written by one thread alone (the stage
+    counts the reads it has charged, the consumes' loop the requests
+    whose consume has ended: equal means nothing is in flight anywhere,
+    which is when a head above the budget is admitted all the same);
+    ``reads_in_flight``, written here and read there.
+    """
+
+    def __init__(
+        self,
+        pending: "deque[ReadReq]",
+        storage: StoragePlugin,
+        memory_budget_bytes: int,
+        deliver: Callable[[ReadReq, Any, int, float], None],
+        fail: Callable[[BaseException], None],
+    ) -> None:
+        self._pending = pending
+        self._storage = storage
+        # The host budget: charged here as a read is issued, given back
+        # by the consumes' loop and by the consumers' deferred
+        # releasers, each of which wakes a head that waits for room.
+        self.budget = _BudgetCell(memory_budget_bytes)
+        self.budget.call_on_release(self.wake)
+        self._deliver = deliver
+        self._fail = fail
+        self.streams = max(1, storage.max_read_concurrency)
+        self._consumes_loop = asyncio.get_running_loop()
+        self._loop = asyncio.new_event_loop()
+        self._executor = ThreadPoolExecutor(
+            max_workers=self.streams,
+            thread_name_prefix="tpusnapshot-read",
+        )
+        self._loop.set_default_executor(self._executor)
+        self._released = asyncio.Event()
+        self._run_task: Optional[asyncio.Task] = None
+        # The restore's trace id and phase profile are context
+        # variables: the stage's tasks run under a copy of the caller's.
+        self._thread = threading.Thread(
+            target=contextvars.copy_context().run,
+            args=(self._thread_main,),
+            name="tpusnapshot-read-stage",
+            daemon=True,
+        )
+        self.issued = 0
+        self.consumed = 0
+        self.reads_in_flight = 0
+        self.budget_blocked = False
+        self.stall_s = 0.0
+        self.min_budget = memory_budget_bytes
+        # Seconds between the first read issued and the last returned
+        # with no ``storage.read`` in flight: the report's
+        # ``read_idle_s``.
+        self.read_idle_s = 0.0
+        self._idle_since: Optional[float] = None
+
+    def start(self) -> None:
+        _start_threads(
+            self._executor, min(self.streams, len(self._pending))
+        )
+        self._thread.start()
+
+    def consume_ended(self, refund: int) -> None:
+        """A request's consume has ended (the consumes' loop): counted
+        before the refund wakes the stage, which with nothing left in
+        flight admits a head of any size."""
+        self.consumed += 1
+        self.budget.release(refund)
+
+    def wake(self) -> None:
+        """Budget came back (any thread)."""
+        try:
+            self._loop.call_soon_threadsafe(self._released.set)
+        except RuntimeError:
+            # The stage's loop has closed; an engine thread's release
+            # after the run has nobody left to wake.
+            pass
+
+    def close(self) -> None:
+        """Stop the stage (idempotent; after a sound run it has ended
+        by itself): reads still in flight are cancelled, none is
+        issued."""
+
+        def _cancel() -> None:
+            if self._run_task is not None:
+                self._run_task.cancel()
+
+        if self._thread.ident is None:
+            # Never started: what __init__ opened is closed here.
+            self._executor.shutdown(wait=False)
+            self._loop.close()
+            return
+        try:
+            self._loop.call_soon_threadsafe(_cancel)
+        except RuntimeError:
+            pass  # closed already: the stage ran to its end
+        if self._thread.is_alive():
+            self._thread.join(_READ_STAGE_JOIN_S)
+            if self._thread.is_alive():
+                logger.warning(
+                    "the read stage's thread is still in a plug-in's "
+                    "read %.0f s after it was cancelled",
+                    _READ_STAGE_JOIN_S,
+                )
+
+    def _thread_main(self) -> None:
+        try:
+            self._run_task = self._loop.create_task(self._run())
+            self._loop.run_until_complete(self._run_task)
+        except asyncio.CancelledError:
+            pass  # close() on a failed or cancelled run
+        except BaseException as e:
+            self._post(self._fail, e)
+        finally:
+            self._executor.shutdown(wait=False, cancel_futures=True)
+            self._loop.close()
+
+    def _post(self, callback: Callable[..., None], *args: Any) -> None:
+        try:
+            self._consumes_loop.call_soon_threadsafe(callback, *args)
+        except RuntimeError:
+            # The consumes' loop has gone (its run failed or was
+            # cancelled while this read was in flight).
+            logger.debug("read stage: nobody left to post to")
+
+    async def _run(self) -> None:
+        budget = self.budget
+        reading: Set[asyncio.Future] = set()
+        released: Optional[asyncio.Future] = None
+        try:
+            while self._pending or reading:
+                self.budget_blocked = False
+                while self._pending and len(reading) < self.streams:
+                    consumer = self._pending[0].buffer_consumer
+                    cost = consumer.get_consuming_cost_bytes()
+                    # Cleared before the budget is read: a release that
+                    # lands after the test below is then still seen.
+                    self._released.clear()
+                    nothing_in_flight = self.issued == self.consumed
+                    if budget.value < cost and nothing_in_flight:
+                        # Same straggler grace as the device scan of the
+                        # consumes' loop: split-assembly buffers release
+                        # host budget from executor threads after their
+                        # consume task resolves.
+                        while (
+                            budget.value < cost
+                            and await _straggler_release_landed(budget)
+                        ):
+                            pass
+                    if budget.value < cost and not nothing_in_flight:
+                        self.budget_blocked = True
+                        break
+                    rr = self._pending.popleft()
+                    # Invariant the flow analysis cannot see: every
+                    # charge is re-credited when the request's consume
+                    # completes (execute_read_reqs) or by the consumer's
+                    # deferred releaser, and the cell is per-pipeline-
+                    # run: a failed run cancels its tasks and drops the
+                    # cell, so no charge outlives the budget it was
+                    # charged against.
+                    # snapcheck: disable=resource-lifecycle -- cross-thread discharge: released at consume completion (execute_read_reqs) or via the consumer's deferred releaser; cell dies with the run
+                    budget.charge(cost)
+                    self.issued += 1
+                    self.min_budget = min(self.min_budget, budget.value)
+                    deferred = consumer.get_deferred_cost_bytes()
+                    if deferred:
+                        consumer.set_cost_releaser(budget.release)
+                    # The consume-completion refund excludes the deferred
+                    # portion, which the consumer releases itself.
+                    reading.add(
+                        asyncio.ensure_future(
+                            self._read(rr, cost - deferred)
+                        )
+                    )
+                if self.budget_blocked:
+                    released = asyncio.ensure_future(self._released.wait())
+                    reading.add(released)
+                wait_t0 = time.monotonic()
+                done, _ = await asyncio.wait(
+                    reading, return_when=asyncio.FIRST_COMPLETED
+                )
+                if released is not None:
+                    # A read was ready to be issued and the budget said
+                    # no: the time until something gave way is stall.
+                    self.stall_s += time.monotonic() - wait_t0
+                    released.cancel()
+                    reading.discard(released)
+                    done.discard(released)
+                    released = None
+                reading -= done
+                for task in done:
+                    task.result()  # _read posts its failures; never raises
+        finally:
+            # close() on a failed or cancelled run: the reads in flight
+            # are cancelled and seen out, so none is left pending when
+            # the loop closes.
+            for task in reading:
+                task.cancel()
+            if reading:
+                await asyncio.wait(reading)
+
+    async def _read(self, rr: ReadReq, refund: int) -> None:
+        io_req = IOReq(path=rr.path, byte_range=rr.byte_range)
+        t0 = time.monotonic()
+        if self.reads_in_flight == 0 and self._idle_since is not None:
+            self.read_idle_s += t0 - self._idle_since
+        self.reads_in_flight += 1
+        failure: Optional[BaseException] = None
+        try:
+            with tracing.span("read", path=rr.path):
+                await self._storage.read(io_req)
+        except asyncio.CancelledError:
+            raise
+        except BaseException as e:  # snapcheck: disable=swallowed-exception -- raised by execute_read_reqs on the consumes' loop (faultline's SimulatedCrash is a BaseException)
+            failure = e
+        finally:
+            ended = time.monotonic()
+            self.reads_in_flight -= 1
+            if self.reads_in_flight == 0:
+                self._idle_since = ended
+        if failure is not None:
+            # Nothing more is issued; what is in flight runs out.
+            self._pending.clear()
+            self._post(self._fail, failure)
+        else:
+            self._post(
+                self._deliver, rr, io_payload(io_req), refund, ended - t0
+            )
+
+
 async def execute_read_reqs(
     read_reqs: List[ReadReq],
     storage: StoragePlugin,
@@ -492,7 +775,6 @@ async def execute_read_reqs(
     flight recorder, as in :func:`execute_write_reqs`.
     """
     begin_ts = time.monotonic()
-    min_budget = memory_budget_bytes
     stall_s = 0.0
     ops: Dict[str, Dict[str, Any]] = {}
     if progress is not None:
@@ -520,7 +802,7 @@ async def execute_read_reqs(
         return key if key is not None else r.buffer_consumer.get_consuming_cost_bytes()
 
     pending = deque(sorted(read_reqs, key=lambda r: -_sort_bytes(r)))
-    reading: Dict[asyncio.Task, Tuple[ReadReq, int]] = {}
+    total = len(pending)
     consumable: deque = deque()  # (ReadReq, buf, host_refund, ready_t)
     # Consume micro-profile (snapxray): read_wait — a completed read's
     # payload queued behind budget/executor pressure before its consume
@@ -528,7 +810,6 @@ async def execute_read_reqs(
     # stages. The scope was opened by the restore root in this thread.
     profile = _cprof.current()
     consuming: Dict[asyncio.Task, int] = {}
-    budget = _BudgetCell(memory_budget_bytes)
     device_budget = _BudgetCell(
         device_budget_bytes if device_budget_bytes is not None else (1 << 62)
     )
@@ -542,8 +823,37 @@ async def execute_read_reqs(
     device_waits = 0
     device_wait_s = 0.0
     bytes_read = 0
-    max_io = storage.max_read_concurrency
+    delivered = 0
+    read_failure: Optional[BaseException] = None
+    # Set by the read stage's posts (a payload, a failure): what this
+    # loop waits for besides its consumes.
+    arrived = asyncio.Event()
+
+    def _deliver(rr: ReadReq, buf: Any, refund: int, seconds: float) -> None:
+        nonlocal bytes_read, delivered
+        delivered += 1
+        bytes_read += len(buf)
+        _observe_op(
+            ops,
+            "read",
+            seconds,
+            len(buf),
+            progress,
+            # Credit the same cost units bytes_total summed (consuming
+            # cost minus deferred).
+            progress_bytes=refund,
+        )
+        consumable.append((rr, buf, refund, time.monotonic()))
+        arrived.set()
+
+    def _fail(error: BaseException) -> None:
+        nonlocal read_failure
+        if read_failure is None:
+            read_failure = error
+        arrived.set()
+
     executor = ThreadPoolExecutor(max_workers=_MAX_STAGING_THREADS)
+    arrival: Optional[asyncio.Future] = None
     in_use_gauge = telemetry.gauge(
         _metric_names.SCHED_BUDGET_IN_USE, pipeline="read"
     )
@@ -577,67 +887,18 @@ async def execute_read_reqs(
         ),
         kind="restore",
     )
+    stage = _ReadStage(pending, storage, memory_budget_bytes, _deliver, _fail)
+    budget = stage.budget
     try:
-        while pending or reading or consumable or consuming:
+        # Every thread this run needs exists before its first read is
+        # issued: the consumes' (at most one a request), the stage's own
+        # and those its plug-in reads run on (_ReadStage.start).
+        _start_threads(executor, min(_MAX_STAGING_THREADS, total))
+        stage.start()
+        while delivered < total or consumable or consuming:
+            if read_failure is not None:
+                raise read_failure
             budget_blocked = False
-            while pending and len(reading) < max_io:
-                consumer = pending[0].buffer_consumer
-                cost = consumer.get_consuming_cost_bytes()
-                nothing_in_flight = not (reading or consumable or consuming)
-                if budget.value < cost and nothing_in_flight:
-                    # Same straggler grace as the device scan below:
-                    # split-assembly buffers release host budget from
-                    # executor threads after their consume task resolves.
-                    while (
-                        budget.value < cost
-                        and await _straggler_release_landed(budget)
-                    ):
-                        pass
-                if budget.value >= cost or nothing_in_flight:
-                    rr = pending.popleft()
-                    # Invariant the flow analysis cannot see: every
-                    # charge is re-credited when its read/consume task
-                    # completes in a LATER loop iteration (the
-                    # budget.release below / the consumer's deferred
-                    # releaser), and the cell is per-pipeline-run — a
-                    # failed run gang-cancels its tasks and drops the
-                    # cell with the stack frame, so no charge outlives
-                    # the budget it was charged against.
-                    # snapcheck: disable=resource-lifecycle -- cross-iteration discharge: released at task completion (below) or via the consumer's deferred releaser; cell dies with the run
-                    budget.charge(cost)
-                    min_budget = min(min_budget, budget.value)
-                    deferred = consumer.get_deferred_cost_bytes()
-                    if deferred:
-                        consumer.set_cost_releaser(budget.release)
-                    io_req = IOReq(path=rr.path, byte_range=rr.byte_range)
-
-                    async def _read(
-                        io_req=io_req,
-                        path=rr.path,
-                        share=cost - deferred,
-                    ) -> IOReq:
-                        t0 = time.monotonic()
-                        with tracing.span("read", path=path):
-                            await storage.read(io_req)
-                        _observe_op(
-                            ops,
-                            "read",
-                            time.monotonic() - t0,
-                            len(io_payload(io_req)),
-                            progress,
-                            # Credit the same cost units bytes_total
-                            # summed (consuming cost minus deferred).
-                            progress_bytes=share,
-                        )
-                        return io_req
-
-                    task = asyncio.ensure_future(_read())
-                    # The consume-completion refund excludes the deferred
-                    # portion, which the consumer releases itself.
-                    reading[task] = (rr, cost - deferred)
-                else:
-                    budget_blocked = True
-                    break
 
             # Dispatch consumes under the device budget. The scan skips
             # past blocked entries (a region waiting for budget must not
@@ -660,15 +921,20 @@ async def execute_read_reqs(
                         id(rr), (time.monotonic(), dcost)
                     )
                 if pick is None:
-                    if reading or consuming:
+                    if stage.reads_in_flight or consuming:
                         # Device-budget wait is stall too: consumable
                         # work exists but cannot dispatch until budget
                         # frees.
                         budget_blocked = True
                         break
-                    if await _straggler_release_landed(device_budget):
+                    if (
+                        await _straggler_release_landed(device_budget)
+                        or stage.reads_in_flight
+                    ):
                         # A deferred release from an engine thread beat
-                        # the grace window — rescan before overrunning.
+                        # the grace window (or the stage, found between
+                        # two reads, has issued its next) — rescan
+                        # before overrunning.
                         continue
                     pick = 0
                 rr, buf, host_refund, ready_t = consumable[pick]
@@ -725,42 +991,47 @@ async def execute_read_reqs(
                         0, device_budget_bytes - device_budget.value
                     ),
                 )
-            stalled_gauge.set(1.0 if budget_blocked else 0.0)
-            in_flight = set(reading) | set(consuming)
-            if not in_flight:
-                continue
+            stalled_gauge.set(
+                1.0 if budget_blocked or stage.budget_blocked else 0.0
+            )
+            if arrival is None:
+                arrival = asyncio.ensure_future(arrived.wait())
             wait_t0 = time.monotonic()
             done, _ = await asyncio.wait(
-                in_flight, return_when=asyncio.FIRST_COMPLETED
+                set(consuming) | {arrival},
+                return_when=asyncio.FIRST_COMPLETED,
             )
             if budget_blocked:
                 stall_s += time.monotonic() - wait_t0
+            if arrival in done:
+                # The payloads are in ``consumable`` already (_deliver).
+                done.discard(arrival)
+                arrival = None
+                arrived.clear()
             for task in done:
-                if task in reading:
-                    rr, cost = reading.pop(task)
-                    buf = io_payload(task.result())
-                    bytes_read += len(buf)
-                    consumable.append((rr, buf, cost, time.monotonic()))
-                else:
-                    cost = consuming.pop(task)
-                    task.result()  # propagate consume errors
-                    budget.release(cost)
+                cost = consuming.pop(task)
+                task.result()  # propagate consume errors
+                stage.consume_ended(cost)
             if progress is not None:
                 await progress.async_tick()
     finally:
+        if arrival is not None:
+            arrival.cancel()
+        stage.close()
         executor.shutdown(wait=False)
         in_use_gauge.set(0)
         stalled_gauge.set(0)
         mem_domain.set_used(max(0, memory_budget_bytes - budget.value))
         mem_domain.close()
         mem_device_domain.close()
+    stall_s += stage.stall_s
     elapsed = time.monotonic() - begin_ts
     _merge_stats(
         stats,
         "read",
         bytes_read,
         stall_s,
-        memory_budget_bytes - min_budget,
+        memory_budget_bytes - stage.min_budget,
         ops,
     )
     if stats is not None:
@@ -770,6 +1041,8 @@ async def execute_read_reqs(
         stats["device_budget_wait_s"] = (
             stats.get("device_budget_wait_s", 0.0) + device_wait_s
         )
+        stats["read_idle_s"] = stats.get("read_idle_s", 0.0) + stage.read_idle_s
+        stats["read_streams"] = stage.streams
     mbps = bytes_read / 1024 / 1024 / elapsed if elapsed > 0 else 0.0
     logger.info(
         "Rank %d finished loading (%d bytes). Throughput: %.2f MB/s",

@@ -997,10 +997,12 @@ class Snapshot:
         # landed arrays), how many consumes the read pipeline held back
         # because HBM had no room for their chunks yet and for how long
         # in all (admissions wait side by side, so the seconds are
-        # thread-seconds, not wall), and the fullest device's peak as the
-        # runtime reports it (None on a backend that reports none; a peak
-        # since the process began, so an upper bound on this restore's
-        # own).
+        # thread-seconds, not wall), the seconds between the first read
+        # issued and the last returned with no plug-in read in flight
+        # and the fan-out the reads went through, and the fullest
+        # device's peak as the runtime reports it (None on a backend
+        # that reports none; a peak since the process began, so an upper
+        # bound on this restore's own).
         recorder.note(
             template_released_bytes=read_stats.pop(
                 "template_released_bytes", 0
@@ -1009,6 +1011,8 @@ class Snapshot:
             device_budget_wait_s=round(
                 read_stats.pop("device_budget_wait_s", 0.0), 6
             ),
+            read_idle_s=round(read_stats.pop("read_idle_s", 0.0), 6),
+            read_streams=read_stats.pop("read_streams", 0),
             device_peak_bytes=device_peak_bytes(),
         )
         ops = read_stats.get("ops") or {}

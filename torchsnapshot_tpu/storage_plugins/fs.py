@@ -48,8 +48,27 @@ class FSStoragePlugin(StoragePlugin):
     # which a training loop on the same host pays in slowed steps (a
     # step under the drain: 124-135 ms at 1, 189-286 ms at 2, 500-520 ms
     # at 8, against 110 ms free) for a drain that ends about a second
-    # sooner. Reads keep the default fan-out.
+    # sooner.
     max_write_concurrency = 1
+    # Two read streams. Measured on the same host (PERF.md section 5,
+    # PR 35, the sweep of this cap at 1/2/4/8/16 under a restore of
+    # 4.08 GB in 72 parts of 64 MiB, three to seven runs a value): the
+    # directory is one serial pipe (1.07-1.20 GB/s at any count, PR 30),
+    # an `open` completes only once the other streams' data has passed,
+    # and the restore takes 3.89-4.08 s at 1, 3.90-3.93 s at 2 (4.09
+    # and 4.21 in two runs of seven), 3.93-4.09 s at 4, 3.83-4.11 s at 8
+    # and 3.84-3.99 s at 16 (4.05-4.21 s before the read stage fed
+    # itself): flat, as plain reads are. What the count decides is where
+    # the loss sits. One stream leaves the pipe empty between two reads
+    # (60 gaps of 4.7 ms); a second keeps a request waiting behind the
+    # one in flight; every further one only lands more parts at one
+    # instant, so the streams fall into step (16 gaps of 15 ms at 4) and
+    # the burst that the last reads leave takes longer to verify and to
+    # reach the device after the pipe has gone quiet (0.03 s at 1 and 2,
+    # 0.13-0.20 s at 4, 0.37-0.45 s at 16), while each stream holds
+    # 64 MiB more of host memory (high water 0.4 / 0.8 / 2.2 / 3.1 /
+    # 3.9 GB). Storage whose streams add up wants more: measure there.
+    max_read_concurrency = 2
 
     def __init__(self, root: str) -> None:
         self.root = root
