@@ -3,7 +3,9 @@ compiled for a described (not attached) TPU v5e: what the Pallas
 interpreter cannot show (a slice off the tiling, more VMEM than a kernel
 may ask for). Forward and both backward kernels, 8192 positions in
 1024-row tiles, head 128, grouped-query: the sliding layers' 64 heads
-over 8 under a window of 512, the full layers' 48 over 8 plain causal.
+over 8 under a window of 512, the full layers' 48 over 8 plain causal;
+and as the SDAR cell runs them: 32 heads over 4 under the block-diffusion
+mask of two halves of 4096 in blocks of 4.
 
 Nothing runs: a compile that passes is no chip run. Skipped where no
 such topology can be described. The topology is described inside a
@@ -36,22 +38,32 @@ def one_chip():
 
 
 @pytest.mark.parametrize(
-    "heads,window", [(64, 512), (48, None)], ids=["sliding_64_heads", "full_48_heads"]
+    "heads,kv_heads,mask",
+    [
+        (64, 8, {"window": 512}),
+        (48, 8, {}),
+        (32, 4, {"diffusion": (SEQ // 2, 4)}),
+        (32, 4, {"diffusion": (SEQ // 2, 3)}),  # a block that is no shift
+    ],
+    ids=["sliding_64_heads", "full_48_heads", "block_diffusion_32_heads",
+         "block_diffusion_block_of_3"],
 )
-def test_forward_and_backward_kernels_compile_at_the_cell_s_widths(one_chip, heads, window):
+def test_forward_and_backward_kernels_compile_at_the_cell_s_widths(
+    one_chip, heads, kv_heads, mask
+):
     block = resolve_flash_block(SEQ)
     assert block == 1024
     spec = lambda h, d=HEAD, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
         (1, h, SEQ, d), dtype, sharding=one_chip
     )
-    q, kv = spec(heads), spec(8)
+    q, kv = spec(heads), spec(kv_heads)
     forward = _flash_forward.lower(
         q, kv, kv, causal=True, block_q=block, block_k=block, interpret=False,
-        window=window,
+        **mask,
     ).compile()
     assert "tpu_custom_call" in forward.as_text()
     backward = _flash_backward.lower(
         q, kv, kv, q, spec(heads, 1, jnp.float32), spec(heads, 1, jnp.float32),
-        causal=True, block_q=block, block_k=block, interpret=False, window=window,
+        causal=True, block_q=block, block_k=block, interpret=False, **mask,
     ).compile()
     assert backward.as_text().count("tpu_custom_call") >= 2

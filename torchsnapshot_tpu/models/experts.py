@@ -1,9 +1,10 @@
 """One chip's share of a mixture-of-experts layer: the router over all
 of the layer's experts and the part of the result that the experts held
-here give. What ``nemotron_h.py`` and ``laguna.py`` both run.
+here give. What ``nemotron_h.py``, ``laguna.py`` and ``sdar.py`` run.
 
 The layer is told which experts it holds (:class:`Routing`), scores
-every token over all of them at the router's published width, takes
+every token over all of them at the router's published width (by
+sigmoid, or by softmax: ``sdar.py``), takes
 each token's top k, and computes its own experts' part: what the absent
 experts would have added is left out, as expert parallelism leaves it
 to the other chips. No token is dropped.
@@ -47,6 +48,13 @@ class Routing:
     # tensor, several of them in the backward pass). Must divide the
     # number of experts held.
     dense_group: int = 0
+    # How the router's logits become scores: "sigmoid" (each expert's
+    # own), or "softmax" over all of the router's experts, in float32.
+    scoring: str = "sigmoid"
+
+    def __post_init__(self):
+        if self.scoring not in ("sigmoid", "softmax"):
+            raise ValueError(f"scoring is sigmoid or softmax, not {self.scoring!r}")
 
 
 def held_gates(x, router, bias, routing: Routing):
@@ -54,8 +62,9 @@ def held_gates(x, router, bias, routing: Routing):
     held expert's result enters each token, 0 where the token's top k
     (over all the router's experts) does not name it; and, as booleans,
     which tokens are routed to which held expert. ``bias`` (or None) is
-    added to the sigmoid scores for the choice alone."""
-    scores = jax.nn.sigmoid(
+    added to the scores for the choice alone."""
+    score = jax.nn.softmax if routing.scoring == "softmax" else jax.nn.sigmoid
+    scores = score(
         jnp.einsum("td,de->te", x.astype(_F32), router.astype(_F32))
     )
     choice = scores if bias is None else scores + bias.astype(_F32)

@@ -31,10 +31,11 @@ tolerance, not bitwise).
 """
 
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -54,9 +55,12 @@ def _causal_positions(qi, kj, block_q: int, block_k: int):
     return q_pos, k_pos
 
 
-def _block_visible(qi, kj, block_q: int, block_k: int, window=None):
+def _block_visible(qi, kj, block_q: int, block_k: int, window=None, diffusion=None):
     """Whether any key of block kj is visible (causally, and under a
-    ``window``: within it) to some query of block qi."""
+    ``window``: within it; under ``diffusion``: by the block-diffusion
+    mask) to some query of block qi."""
+    if diffusion is not None:
+        return _diffusion_block_visible(qi, kj, block_q, block_k, *diffusion)
     visible = kj * block_k <= qi * block_q + (block_q - 1)
     if window is None:
         return visible
@@ -64,12 +68,72 @@ def _block_visible(qi, kj, block_q: int, block_k: int, window=None):
     return visible & (qi * block_q - (kj * block_k + block_k - 1) < window)
 
 
-def _visible(q_pos, k_pos, window=None):
+def _visible(q_pos, k_pos, window=None, diffusion=None):
     """The causal mask of one tile; under a ``window`` query i sees the
-    keys j with ``0 <= i - j < window``."""
+    keys j with ``0 <= i - j < window``; under ``diffusion`` the mask is
+    :func:`_diffusion_visible`'s and not causal by token."""
+    if diffusion is not None:
+        return _diffusion_visible(q_pos, k_pos, *diffusion)
     if window is None:
         return k_pos <= q_pos
     return (k_pos <= q_pos) & (q_pos - k_pos < window)
+
+
+def _diffusion_visible(q_pos, k_pos, half: int, block: int):
+    """The mask of block-diffusion training over a sequence of two
+    halves of ``half`` tokens each, the clean tokens first and their
+    noised copies after them, both in blocks of ``block``: a clean query
+    sees the clean keys of its own block and of those before it; a
+    noised query sees the clean keys of the blocks strictly before its
+    own and the noised keys of its own block; no clean query sees a
+    noised key. Positions are integer arrays (or numpy's) that
+    broadcast against each other."""
+    q_noised, k_noised = q_pos >= half, k_pos >= half
+    q_blk = _block_of(q_pos - q_noised * half, block)
+    k_blk = _block_of(k_pos - k_noised * half, block)
+    # a noised query's own block is hidden among the clean keys
+    from_clean = ~k_noised & (k_blk <= q_blk - q_noised)
+    return from_clean | (q_noised & k_noised & (k_blk == q_blk))
+
+
+def block_diffusion_mask(half: int, block: int):
+    """:func:`_diffusion_visible` written out for the whole sequence:
+    bool [2 half, 2 half] (numpy), (query, key) -> seen. For an einsum
+    path and for tests; the kernels never build it."""
+    at = np.arange(2 * half)
+    return _diffusion_visible(at[:, None], at[None, :], half, block)
+
+
+def _diffusion_block_visible(qi, kj, block_q: int, block_k: int, half: int, block: int):
+    """Whether :func:`_diffusion_visible` is true anywhere in the tile
+    (qi, kj), from the tile's corners alone. A tile may straddle the
+    two halves."""
+    q0, q1 = qi * block_q, qi * block_q + (block_q - 1)
+    k0, k1 = kj * block_k, kj * block_k + (block_k - 1)
+    last = half - 1
+    has_clean_k = k0 < half
+    first_clean_blk = _block_of(k0, block)
+    # clean queries (q0 .. min(q1, half - 1)) on clean keys
+    clean = (
+        (q0 < half) & has_clean_k
+        & (first_clean_blk <= _block_of(jnp.minimum(q1, last), block))
+    )
+    # noised queries: the blocks a .. c of their positions within the
+    # noised half, and ka .. kc those of the tile's noised keys (a tile
+    # that holds none is ruled out by the comparisons with ``half``)
+    in_half = lambda pos: _block_of(jnp.maximum(pos, half) - half, block)
+    a, c, ka, kc = in_half(q0), in_half(q1), in_half(k0), in_half(k1)
+    on_clean = has_clean_k & (first_clean_blk < c)
+    on_noised = (k1 >= half) & (ka <= c) & (kc >= a)
+    return clean | ((q1 >= half) & (on_clean | on_noised))
+
+
+def _block_of(pos, block: int):
+    """``pos // block`` of positions that are never negative; a shift
+    where the block is a power of two (no vector division on the chip)."""
+    if block & (block - 1) == 0:
+        return pos >> (block.bit_length() - 1)
+    return pos // block
 
 
 def resolve_flash_block(seq_len: int) -> int:
@@ -160,6 +224,7 @@ def _flash_kernel(
     block_q: int,
     block_k: int,
     window: Optional[int] = None,
+    diffusion=None,
 ):
     qi = pl.program_id(1)
     kj = pl.program_id(2)
@@ -174,7 +239,10 @@ def _flash_kernel(
     # Causal: key block kj is entirely in the future of query block qi
     # iff its first key index exceeds the last query index; under a
     # window it may also lie entirely behind every query's window.
-    run = _block_visible(qi, kj, block_q, block_k, window) if causal else True
+    run = (
+        _block_visible(qi, kj, block_q, block_k, window, diffusion)
+        if causal else True
+    )
 
     @pl.when(run)
     def _step():
@@ -189,16 +257,18 @@ def _flash_kernel(
         ) * scale  # [block_q, block_k]
         if causal:
             q_pos, k_pos = _causal_positions(qi, kj, block_q, block_k)
-            s = jnp.where(_visible(q_pos, k_pos, window), s, _NEG_INF)
+            s = jnp.where(_visible(q_pos, k_pos, window, diffusion), s, _NEG_INF)
         m_prev = m_ref[:]  # [block_q, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)  # [block_q, block_k]
-        if causal and window is not None:
+        if causal and (window is not None or diffusion is not None):
             # The first block a query block visits need not hold a key
             # that each of its rows sees (causal alone: key 0 always
-            # is). Such a row has m_new = -1e30 and exp(s - m_new) = 1
-            # on its masked keys, which must not enter l or acc.
-            p = jnp.where(_visible(q_pos, k_pos, window), p, 0.0)
+            # is; block diffusion: a noised query of the first block
+            # sees no clean key). Such a row has m_new = -1e30 and
+            # exp(s - m_new) = 1 on its masked keys, which must not
+            # enter l or acc.
+            p = jnp.where(_visible(q_pos, k_pos, window, diffusion), p, 0.0)
         alpha = jnp.exp(m_prev - m_new)  # [block_q, 1]
         l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
         acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
@@ -221,7 +291,7 @@ def _flash_kernel(
 
 
 def _bwd_pieces(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *, scale,
-                causal, qi, kj, block_q, block_k, window=None):
+                causal, qi, kj, block_q, block_k, window=None, diffusion=None):
     """Recompute p and ds for one (q-block, k-block) pair — the shared
     core of both backward kernels. Returns (p, ds), both [block_q,
     block_k] float32."""
@@ -240,7 +310,7 @@ def _bwd_pieces(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *, scale,
     p = jnp.where(lse_raw > _NEG_INF / 2, jnp.exp(s - lse_safe), 0.0)
     if causal:
         q_pos, k_pos = _causal_positions(qi, kj, block_q, block_k)
-        p = jnp.where(_visible(q_pos, k_pos, window), p, 0.0)
+        p = jnp.where(_visible(q_pos, k_pos, window, diffusion), p, 0.0)
     dp = jax.lax.dot_general(
         do, v, dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -251,7 +321,7 @@ def _bwd_pieces(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *, scale,
 
 def _flash_bwd_dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc,
-    *, scale, causal, block_q, block_k, window=None,
+    *, scale, causal, block_q, block_k, window=None, diffusion=None,
 ):
     qi = pl.program_id(1)
     kj = pl.program_id(2)
@@ -260,7 +330,10 @@ def _flash_bwd_dq_kernel(
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    run = _block_visible(qi, kj, block_q, block_k, window) if causal else True
+    run = (
+        _block_visible(qi, kj, block_q, block_k, window, diffusion)
+        if causal else True
+    )
 
     @pl.when(run)
     def _step():
@@ -268,6 +341,7 @@ def _flash_bwd_dq_kernel(
             q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             scale=scale, causal=causal, qi=qi, kj=kj,
             block_q=block_q, block_k=block_k, window=window,
+            diffusion=diffusion,
         )
         dq_acc[:] += scale * jax.lax.dot_general(
             ds, k_ref[0].astype(jnp.float32),
@@ -283,6 +357,7 @@ def _flash_bwd_dq_kernel(
 def _flash_bwd_dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
     dk_acc, dv_acc, *, scale, causal, block_q, block_k, window=None,
+    diffusion=None,
 ):
     # Grid: (bh, n_k, n_q) — the q-block axis iterates sequentially so
     # the dk/dv accumulators persist across it.
@@ -296,7 +371,10 @@ def _flash_bwd_dkv_kernel(
 
     # Causal: q block strictly before the k block contributes nothing,
     # nor does one whose every query has left the block behind its window.
-    run = _block_visible(qi, kj, block_q, block_k, window) if causal else True
+    run = (
+        _block_visible(qi, kj, block_q, block_k, window, diffusion)
+        if causal else True
+    )
 
     @pl.when(run)
     def _step():
@@ -304,6 +382,7 @@ def _flash_bwd_dkv_kernel(
             q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             scale=scale, causal=causal, qi=qi, kj=kj,
             block_q=block_q, block_k=block_k, window=window,
+            diffusion=diffusion,
         )
         dv_acc[:] += jax.lax.dot_general(
             p, do_ref[0].astype(jnp.float32),
@@ -322,11 +401,12 @@ def _flash_bwd_dkv_kernel(
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _reference_attention(q, k, v, causal, window=None):
+def _reference_attention(q, k, v, causal, window=None, block_diffusion=None):
     """Differentiable einsum attention — the kernels' numerical spec
     (forward and backward match it to float tolerance, not bitwise: the
     tiled kernels reassociate the softmax reductions). ``window``:
-    query i sees the keys j with ``0 <= i - j < window``."""
+    query i sees the keys j with ``0 <= i - j < window``;
+    ``block_diffusion``: :func:`flash_attention`'s."""
     d = q.shape[-1]
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) / (d**0.5)
     if causal:
@@ -334,24 +414,34 @@ def _reference_attention(q, k, v, causal, window=None):
         mask = jnp.tril(jnp.ones((length, length), bool))
         if window is not None:
             mask &= ~jnp.tril(jnp.ones((length, length), bool), -window)
+        if block_diffusion is not None:
+            mask = jnp.asarray(block_diffusion_mask(*block_diffusion))
         s = jnp.where(mask[None, None], s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bhkd->bhqd", p, v)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_attention(q, k, v, causal, block_q, block_k, interpret, window):
-    return _flash_forward(q, k, v, causal, block_q, block_k, interpret, window)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_attention(
+    q, k, v, causal, block_q, block_k, interpret, window, diffusion
+):
+    return _flash_forward(
+        q, k, v, causal, block_q, block_k, interpret, window, diffusion
+    )[0]
 
 
-def _flash_fwd_rule(q, k, v, causal, block_q, block_k, interpret, window):
+def _flash_fwd_rule(
+    q, k, v, causal, block_q, block_k, interpret, window, diffusion
+):
     out, lse = _flash_forward(
-        q, k, v, causal, block_q, block_k, interpret, window
+        q, k, v, causal, block_q, block_k, interpret, window, diffusion
     )
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd_rule(causal, block_q, block_k, interpret, window, residuals, g):
+def _flash_bwd_rule(
+    causal, block_q, block_k, interpret, window, diffusion, residuals, g
+):
     # Tiled Pallas backward: p is reconstructed per tile from the saved
     # log-sum-exp, so the backward, like the forward, never materializes
     # the S×S score matrix (O(S·D) memory end to end). Two kernels: dq
@@ -363,7 +453,8 @@ def _flash_bwd_rule(causal, block_q, block_k, interpret, window, residuals, g):
         keepdims=True,
     )
     dq, dk, dv = _flash_backward(
-        q, k, v, g, lse, delta, causal, block_q, block_k, interpret, window
+        q, k, v, g, lse, delta, causal, block_q, block_k, interpret, window,
+        diffusion,
     )
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
@@ -384,10 +475,13 @@ def _resolve_blocks(s: int, block_q: int, block_k: int):
 
 @functools.partial(
     jax.jit,
-    static_argnames=("causal", "block_q", "block_k", "interpret", "window"),
+    static_argnames=(
+        "causal", "block_q", "block_k", "interpret", "window", "diffusion"
+    ),
 )
 def _flash_backward(
-    q, k, v, g, lse, delta, causal, block_q, block_k, interpret, window=None
+    q, k, v, g, lse, delta, causal, block_q, block_k, interpret, window=None,
+    diffusion=None,
 ):
     b, h, s, d = q.shape
     group = _gqa_group(q, k)
@@ -415,6 +509,7 @@ def _flash_backward(
         functools.partial(
             _flash_bwd_dq_kernel, scale=scale, causal=causal,
             block_q=block_q, block_k=block_k, window=window,
+            diffusion=diffusion,
         ),
         out_shape=jax.ShapeDtypeStruct((bh, s, d), jnp.float32),
         grid=(bh, s // block_q, s // block_k),
@@ -438,6 +533,7 @@ def _flash_backward(
         functools.partial(
             _flash_bwd_dkv_kernel, scale=scale, causal=causal,
             block_q=block_q, block_k=block_k, window=window,
+            diffusion=diffusion,
         ),
         out_shape=(
             jax.ShapeDtypeStruct((bh, s, d), jnp.float32),
@@ -471,6 +567,7 @@ def flash_attention(
     block_k: int = 128,
     interpret: Optional[bool] = None,
     window: Optional[int] = None,
+    block_diffusion: Optional[Tuple[int, int]] = None,
 ) -> jax.Array:
     """softmax(QKᵀ/√D)·V without materializing the S×S score matrix.
 
@@ -478,7 +575,31 @@ def flash_attention(
     < window``; key blocks that lie wholly behind a query block's
     windows are skipped as those above the diagonal are. It need not be
     a multiple of the blocks. ``None`` is plain causal attention, and
-    compiles to the program it compiled to before windows existed."""
+    compiles to the program it compiled to before windows existed.
+
+    ``block_diffusion = (half, block)``: the mask of block-diffusion
+    training in place of the causal one. The sequence is ``half`` clean
+    tokens and then their ``half`` noised copies, both in blocks of
+    ``block`` tokens; what sees what is :func:`_diffusion_visible`'s
+    (block-causal among the clean, block-diagonal among the noised,
+    strictly block-causal from noised to clean). Tiles in which nothing
+    is seen are skipped: about five eighths of them at four tiles a
+    half, three quarters as the tiles get small. Neither ``half`` nor
+    ``block`` need be a multiple of the tiles."""
+    if block_diffusion is not None:
+        half, block = (int(n) for n in block_diffusion)
+        if not causal or window is not None:
+            raise ValueError(
+                "block_diffusion replaces the causal mask: causal stays "
+                "True and there is no window"
+            )
+        if block < 1 or 2 * half != q.shape[2]:
+            raise ValueError(
+                f"block_diffusion=(half, block) needs a sequence of 2 * "
+                f"half tokens and a block of at least 1; got "
+                f"{(half, block)} for {q.shape[2]} tokens"
+            )
+        block_diffusion = (half, block)
     if window is not None:
         if not causal:
             raise ValueError("a window is a causal window: causal must be True")
@@ -488,7 +609,9 @@ def flash_attention(
             window = None  # every key at or before a query is within it
     if interpret is None:
         interpret = resolve_interpret()
-    return _flash_attention(q, k, v, causal, block_q, block_k, interpret, window)
+    return _flash_attention(
+        q, k, v, causal, block_q, block_k, interpret, window, block_diffusion
+    )
 
 
 
@@ -524,7 +647,9 @@ def _kv_index_map(h: int, group: int, block_axis: int = 2):
 
 @functools.partial(
     jax.jit,
-    static_argnames=("causal", "block_q", "block_k", "interpret", "window"),
+    static_argnames=(
+        "causal", "block_q", "block_k", "interpret", "window", "diffusion"
+    ),
 )
 def _flash_forward(
     q: jax.Array,  # [B, Hq, S, D]
@@ -535,6 +660,7 @@ def _flash_forward(
     block_k: int,
     interpret: bool,  # resolved by flash_attention(); never None here
     window: Optional[int] = None,
+    diffusion: Optional[Tuple[int, int]] = None,
 ):
     """Returns (out [B,Hq,S,D], lse [B,Hq,S,1] float32)."""
     b, h, s, d = q.shape
@@ -554,6 +680,7 @@ def _flash_forward(
         block_q=block_q,
         block_k=block_k,
         window=window,
+        diffusion=diffusion,
     )
     kv_map = _kv_index_map(h, group)
     out, lse = pl.pallas_call(

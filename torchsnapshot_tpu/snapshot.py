@@ -91,7 +91,7 @@ from .manifest import (
     is_replicated,
 )
 from .rng_state import RNGState
-from .serialization import check_compression
+from .serialization import array_nbytes, check_compression
 from .scheduler import (
     execute_read_reqs,
     execute_write_reqs,
@@ -857,6 +857,16 @@ class Snapshot:
         recorder = flight.FlightRecorder(
             kind="restore", path=self.path, rank=rank
         )
+        # What a restore of everything would select from (containers are
+        # structure, not leaves), beside what this one selects
+        # (``_load_stateful``: ``leaves_selected``, ``bytes_selected``).
+        recorder.note(
+            leaves_in_snapshot=sum(
+                1
+                for entry in available.values()
+                if not isinstance(entry, (ListEntry, DictEntry))
+            )
+        )
         tracing.set_identity(rank=rank)
         watch = liveprog.ProgressPublisher(
             kind="restore",
@@ -1002,8 +1012,12 @@ class Snapshot:
         # and the fan-out the reads went through, and the fullest
         # device's peak as the runtime reports it (None on a backend
         # that reports none; a peak since the process began, so an upper
-        # bound on this restore's own).
+        # bound on this restore's own). First, what the restore chose:
+        # the leaves it selected (by app-state key or ``paths=``) and
+        # their logical bytes.
         recorder.note(
+            leaves_selected=read_stats.pop("leaves_selected", 0),
+            bytes_selected=read_stats.pop("bytes_selected", 0),
             template_released_bytes=read_stats.pop(
                 "template_released_bytes", 0
             ),
@@ -2986,6 +3000,14 @@ class _RestoreStretches:
         self._since = time.monotonic()
 
 
+def _entry_logical_nbytes(entry: Optional[Entry]) -> int:
+    """An array entry's bytes as the restored array holds them (shape x
+    dtype, whatever codec or chunking stored them); 0 for anything else."""
+    if isinstance(entry, (ArrayEntry, ShardedArrayEntry)):
+        return array_nbytes(entry.dtype, entry.shape)
+    return 0
+
+
 def _load_stateful(
     key: str,
     stateful: Stateful,
@@ -3022,6 +3044,15 @@ def _load_stateful(
             # Nothing of this stateful matches the filter: leave it
             # untouched (no load_state_dict call, no side effects).
             return 0
+    if stats is not None:
+        # What this restore chose out of the manifest, by app-state key
+        # or by ``paths=``: the report's ``leaves_selected`` and
+        # ``bytes_selected`` (arrays' logical bytes; objects and
+        # primitives count as leaves of 0 bytes).
+        stats["leaves_selected"] = stats.get("leaves_selected", 0) + len(selected)
+        stats["bytes_selected"] = stats.get("bytes_selected", 0) + sum(
+            _entry_logical_nbytes(available.get(p)) for p in selected
+        )
     for logical_path, template in flattened.items():
         if logical_path not in selected:
             continue  # partial restore: keep the template's value
