@@ -13,6 +13,7 @@ import asyncio
 import json
 import os
 import threading
+import time
 
 import jax
 import jax.numpy as jnp
@@ -21,7 +22,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 import torchsnapshot_tpu.scheduler as sched
-from torchsnapshot_tpu import Snapshot, StateDict
+from torchsnapshot_tpu import Snapshot, StateDict, staging_pool
 from torchsnapshot_tpu import faultline as fl
 from torchsnapshot_tpu import snapshot as snapshot_mod
 from torchsnapshot_tpu.io_types import BufferConsumer, IOReq, ReadReq
@@ -368,14 +369,22 @@ def test_a_head_above_the_budget_waits_for_the_consumes_then_goes_alone():
     assert stats["budget_high_water_bytes"] == 500
 
 
-@pytest.mark.parametrize("route", ["streamed", "host_assembled"])
+@pytest.mark.parametrize(
+    "route", ["streamed", "streamed_into_pool", "host_assembled"]
+)
 def test_a_corrupted_part_raises_before_any_array_is_exposed(
     tmp_path, monkeypatch, route
 ):
     monkeypatch.setenv("TPUSNAPSHOT_PARALLEL_READ_THRESHOLD", str(_PART))
     monkeypatch.setenv("TPUSNAPSHOT_STRICT_INTEGRITY", "1")
+    if route == "streamed_into_pool":
+        # Puts that copy (the chunked path's concatenate): the parts are
+        # read into pooled buffers, which all go back after the failure.
+        monkeypatch.setenv("TPUSNAPSHOT_FORCE_CHUNKED_TRANSFER", "1")
+        monkeypatch.setenv("TPUSNAPSHOT_H2D_CHUNK_BYTES", str(_PART // 4))
+        staging_pool.reset_staging_pool()
     values = np.arange(8 * _PART // 4, dtype=np.float32)
-    saved = jnp.asarray(values) if route == "streamed" else values
+    saved = values if route == "host_assembled" else jnp.asarray(values)
     path = str(tmp_path / "snap")
     Snapshot.take(path, {"m": StateDict(w=saved, other=jnp.ones((8,)))})
     obj = tmp_path / "snap" / "0" / "m" / "w"
@@ -390,12 +399,23 @@ def test_a_corrupted_part_raises_before_any_array_is_exposed(
             exposed.append(sd)
             super().load_state_dict(sd)
 
-    template = jnp.zeros_like(saved) if route == "streamed" else np.zeros_like(saved)
+    template = (
+        np.zeros_like(saved) if route == "host_assembled" else jnp.zeros_like(saved)
+    )
     target = _Target(w=template, other=jnp.zeros((8,)))
     with pytest.raises(RuntimeError, match="[Cc]hecksum"):
         Snapshot(path).restore({"m": target})
     assert exposed == []
     assert not np.asarray(target["w"]).any() and not np.asarray(target["other"]).any()
+    if route == "streamed_into_pool":
+        # The last transfers land after the restore has raised.
+        pool = staging_pool.get_staging_pool()
+        deadline = time.monotonic() + _LIMIT_S
+        while pool.stats()["in_use_bytes"] and time.monotonic() < deadline:
+            time.sleep(0.01)
+        assert pool.stats()["in_use_bytes"] == 0
+        assert pool.stats()["free_bytes"] > 0
+        staging_pool.reset_staging_pool()
 
 
 @pytest.mark.faultline

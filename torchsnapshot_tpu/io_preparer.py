@@ -55,6 +55,7 @@ from .ops.transfer import (
     device_clone,
     h2d_chunk_bytes,
     h2d_pipeline,
+    h2d_put_copies,
     parallel_device_get,
     should_chunk_h2d,
     should_chunk_transfer,
@@ -1059,6 +1060,9 @@ class _StreamingSplitState(_SplitObjectReadState):
         # only after BOTH holds drop — the crc prefix drain (the
         # out-of-order stash) and the overlap engine's transfer.
         self._part_refs: Dict[int, int] = {}
+        # The pooled buffers the parts were read into (``IOReq.into``),
+        # by offset: back to the pool when the part's holds drop.
+        self._part_leases: Dict[int, staging_pool.StagingLease] = {}
         self._transfers_remaining = 0
         self._crc_ok = self._crc is None
         self._completed = False
@@ -1102,6 +1106,23 @@ class _StreamingSplitState(_SplitObjectReadState):
             if remaining > 0:
                 release(remaining)
 
+    def reads_into_pool(self, nbytes: int) -> bool:
+        # A part's put must not alias its buffer, which is refilled
+        # once the part is released.
+        return h2d_put_copies(nbytes, self._device)
+
+    def hold_part_lease(
+        self, start: int, lease: staging_pool.StagingLease
+    ) -> None:
+        with self._lock:
+            self._part_leases[start] = lease
+
+    def _give_back_part(self, start: int) -> None:
+        with self._lock:
+            lease = self._part_leases.pop(start, None)
+        if lease is not None:
+            lease.release()
+
     def _part_release(self, start: int, nbytes: int) -> None:
         release = None
         with self._lock:
@@ -1116,6 +1137,9 @@ class _StreamingSplitState(_SplitObjectReadState):
             release = self._cost_release
             if release is not None:
                 self._released += nbytes
+        # The buffer first: a read that the budget's credit admits finds
+        # it free.
+        self._give_back_part(start)
         if release is not None:
             release(nbytes)
 
@@ -1128,9 +1152,12 @@ class _StreamingSplitState(_SplitObjectReadState):
             # remaining deferred-budget holds NOW, so the doomed
             # restore's other reads don't crawl through forced
             # admission against a starved budget until the finalizer
-            # surfaces the error.
+            # surfaces the error. The transfer no longer reads the
+            # part: its hold drops as on success, which gives the
+            # buffer back once the fold is done with it too.
             with self._lock:
                 self._failed = True
+            self._part_release(start, nbytes)
             self._release_assembly_cost()
             return
         # Deposit straight into the region, keyed by region-flat byte
@@ -1180,6 +1207,7 @@ class _StreamingSplitState(_SplitObjectReadState):
             with _cprof.consume_section():
                 with _cprof.substep(profile, "view", len(buf)):
                     if len(buf) != end - start:
+                        self._give_back_part(start)
                         raise RuntimeError(
                             f"Ranged sub-read returned {len(buf)} bytes for "
                             f"[{start}, {end}) — object shorter than the "
@@ -1292,6 +1320,16 @@ class _SubRangeConsumer(BufferConsumer):
 
     def set_cost_releaser(self, release: Callable[[int], None]) -> None:
         self._state.set_cost_releaser(release)
+
+    def reads_into_pool(self) -> bool:
+        # Streamed parts: each is let go once it has landed and been
+        # folded into its object's checksum.
+        return isinstance(
+            self._state, _StreamingSplitState
+        ) and self._state.reads_into_pool(self._end - self._start)
+
+    def hold_read_lease(self, lease: Any) -> None:
+        self._state.hold_part_lease(self._start, lease)
 
     @property
     def sort_key_bytes(self) -> int:

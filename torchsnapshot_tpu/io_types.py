@@ -22,7 +22,7 @@ import random
 import time
 from concurrent.futures import Executor
 from dataclasses import dataclass, field
-from typing import Callable, List, Optional, Union
+from typing import Any, Callable, List, Optional, Union
 
 from . import telemetry, tracing
 from .telemetry import metrics as _metric_names
@@ -319,6 +319,20 @@ class BufferConsumer(abc.ABC):
         consumer must invoke ``release(n)`` exactly once, when the
         deferred allocation is actually freed."""
 
+    def reads_into_pool(self) -> bool:
+        """Whether this ranged read's payload may land in a buffer of
+        the restores' staging pool (``staging_pool.py``): only where
+        nothing keeps a view of the payload once the consumer is done
+        with it (its bytes go to a device that copies them) and the
+        consumer gives the buffer back itself. False by default."""
+        return False
+
+    def hold_read_lease(self, lease: Any) -> None:
+        """Own ``lease``, the pooled buffer this consumer's payload is
+        read into (only where :meth:`reads_into_pool` said so): give it
+        back once nothing reads the payload any more, on every path."""
+        raise NotImplementedError
+
     def get_device_cost_bytes(self) -> int:
         """Device (HBM) bytes this consume deposits that outlive the
         consume call (streamed chunks awaiting assembly). The scheduler
@@ -360,6 +374,10 @@ class IOReq:
     # instead of draining `buf`. Reads: plugins that can, return the
     # payload here instead of memcpy-ing it into `buf`.
     data: Optional[BufferType] = None
+    # Ranged reads: a plug-in that can fill a buffer may call this for a
+    # writable one of the range's size, read the payload into it and
+    # set `data` to a view of what it read; one that cannot ignores it.
+    into: Optional[Callable[[], memoryview]] = None
 
 
 def io_payload(io_req: "IOReq") -> BufferType:
@@ -373,7 +391,7 @@ class StoragePlugin(abc.ABC):
     # How many concurrent IO ops this backend profits from, read by the
     # scheduler as its per-pipeline concurrency caps. Object stores
     # (GCS/S3) want many parallel streams both ways; the fs plugin writes
-    # one object at a time and reads through two streams (both measured,
+    # one object at a time and reads through four streams (both measured,
     # see its comments).
     max_write_concurrency: int = 16
     max_read_concurrency: int = 16

@@ -154,11 +154,27 @@ def h2d_chunk_bytes() -> int:
 
 def should_chunk_h2d(arr: Any, device: Any) -> bool:
     """Whether a host buffer is worth pushing through the chunked path."""
+    return _chunks_h2d(arr.nbytes, device)
+
+
+def _chunks_h2d(nbytes: int, device: Any) -> bool:
     if getattr(device, "platform", None) == "cpu" and not os.environ.get(
         "TPUSNAPSHOT_FORCE_CHUNKED_TRANSFER"
     ):
         return False
-    return arr.nbytes >= 2 * h2d_chunk_bytes()
+    return nbytes >= 2 * h2d_chunk_bytes()
+
+
+def h2d_put_copies(nbytes: int, device: Any) -> bool:
+    """Whether the overlap engine's put of a host buffer of ``nbytes``
+    leaves the device array no view of it, so the buffer may be refilled
+    once the put has landed: always across a link; on a host-backed
+    (CPU) device only through the chunked path, whose concatenate makes
+    a buffer of its own (a plain ``device_put`` there may alias the
+    numpy buffer)."""
+    return getattr(device, "platform", None) != "cpu" or _chunks_h2d(
+        nbytes, device
+    )
 
 
 def chunked_device_put(arr: np.ndarray, device: Any) -> Any:

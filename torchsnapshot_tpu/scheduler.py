@@ -38,6 +38,7 @@ scheduler.py:104-117).
 
 import asyncio
 import contextvars
+import functools
 import io
 import logging
 import os
@@ -578,6 +579,13 @@ class _ReadStage:
         # ``read_idle_s``.
         self.read_idle_s = 0.0
         self._idle_since: Optional[float] = None
+        # Where a consumer can take it, a ranged read lands in a buffer
+        # of the restores' staging pool (``IOReq.into``): the bytes read
+        # into one an earlier read had filled, and into a new one, are
+        # the report's ``read_pool_hit_bytes`` / ``read_pool_miss_bytes``.
+        self._pool = staging_pool.get_staging_pool()
+        self.pool_hit_bytes = 0
+        self.pool_miss_bytes = 0
 
     def start(self) -> None:
         _start_threads(
@@ -724,8 +732,28 @@ class _ReadStage:
             if reading:
                 await asyncio.wait(reading)
 
+    def _lease_into(self, rr: ReadReq, leases: List[Any]) -> memoryview:
+        """``IOReq.into`` of ``rr``, on the plug-in's thread: the buffer
+        its payload is read into, leased at the first call (a retried
+        read gets it again) and owned by the consumer from then on. It
+        never waits for the pool: this stage's host budget holds what
+        is read."""
+        start, end = rr.byte_range
+        if not leases:
+            lease = self._pool.acquire(end - start, wait=False)
+            rr.buffer_consumer.hold_read_lease(lease)
+            leases.append(lease)
+        return memoryview(leases[0].buffer)[: end - start]
+
     async def _read(self, rr: ReadReq, refund: int) -> None:
         io_req = IOReq(path=rr.path, byte_range=rr.byte_range)
+        leases: List[Any] = []
+        if (
+            self._pool is not None
+            and rr.byte_range is not None
+            and rr.buffer_consumer.reads_into_pool()
+        ):
+            io_req.into = functools.partial(self._lease_into, rr, leases)
         t0 = time.monotonic()
         if self.reads_in_flight == 0 and self._idle_since is not None:
             self.read_idle_s += t0 - self._idle_since
@@ -748,6 +776,11 @@ class _ReadStage:
             self._pending.clear()
             self._post(self._fail, failure)
         else:
+            if leases:
+                if leases[0].reused:
+                    self.pool_hit_bytes += leases[0].nbytes
+                else:
+                    self.pool_miss_bytes += leases[0].nbytes
             self._post(
                 self._deliver, rr, io_payload(io_req), refund, ended - t0
             )
@@ -1043,6 +1076,11 @@ async def execute_read_reqs(
         )
         stats["read_idle_s"] = stats.get("read_idle_s", 0.0) + stage.read_idle_s
         stats["read_streams"] = stage.streams
+        for key, nbytes in (
+            ("read_pool_hit_bytes", stage.pool_hit_bytes),
+            ("read_pool_miss_bytes", stage.pool_miss_bytes),
+        ):
+            stats[key] = stats.get(key, 0) + nbytes
     mbps = bytes_read / 1024 / 1024 / elapsed if elapsed > 0 else 0.0
     logger.info(
         "Rank %d finished loading (%d bytes). Throughput: %.2f MB/s",

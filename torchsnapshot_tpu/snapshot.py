@@ -1009,10 +1009,13 @@ class Snapshot:
         # in all (admissions wait side by side, so the seconds are
         # thread-seconds, not wall), the seconds between the first read
         # issued and the last returned with no plug-in read in flight
-        # and the fan-out the reads went through, and the fullest
-        # device's peak as the runtime reports it (None on a backend
-        # that reports none; a peak since the process began, so an upper
-        # bound on this restore's own). First, what the restore chose:
+        # and the fan-out the reads went through, the bytes read into a
+        # pooled buffer an earlier read had filled and into a new one
+        # (``IOReq.into``; neither where the plug-in allocates), and the
+        # fullest device's peak as the runtime reports it (None on a
+        # backend that reports none; a peak since the process began, so
+        # an upper bound on this restore's own). First, what the restore
+        # chose:
         # the leaves it selected (by app-state key or ``paths=``) and
         # their logical bytes.
         recorder.note(
@@ -1027,6 +1030,8 @@ class Snapshot:
             ),
             read_idle_s=round(read_stats.pop("read_idle_s", 0.0), 6),
             read_streams=read_stats.pop("read_streams", 0),
+            read_pool_hit_bytes=read_stats.pop("read_pool_hit_bytes", 0),
+            read_pool_miss_bytes=read_stats.pop("read_pool_miss_bytes", 0),
             device_peak_bytes=device_peak_bytes(),
         )
         ops = read_stats.get("ops") or {}

@@ -26,6 +26,15 @@ buffer actually returns to the pool — never per sub-read, never twice,
 whatever mix of executor threads, H2D-engine callbacks, and error paths
 races to release it.
 
+The restore's read destinations: a streamed part (``io_preparer.
+_StreamingSplitState``), whose bytes go to a device that copies them,
+is read straight into a buffer of this pool (``IOReq.into``, filled by
+the fs plug-in's ``readinto``), which goes back once the part has
+landed and been folded into its object's checksum. A restore then reads
+into pages the host has faulted in already, not into a fresh ``bytes``
+of 64 MiB a part. Those leases never wait (``acquire(wait=False)``): the
+read stage's host budget bounds them.
+
 The take side (:func:`get_take_staging_pool`): the host buffers a take
 assembles its chunked leaves in (``ArrayBufferStager._stage_phases``
 hands them to ``ops/transfer.parallel_device_get`` as ``out``) come from
@@ -225,13 +234,13 @@ class StagingPool:
         # the restore's consumers own every view of their buffers and
         # drop them before the release: so a free buffer that something
         # outside this pool still refers to leaves it instead of being
-        # handed out. And sixteen threads copy into a take's buffer
-        # where one fills the restore's: so a miss allocates untouched
-        # pages (``np.empty``), which the copies fault in side by side,
-        # not a ``bytearray`` that one thread zeroes under the lock. The
-        # ``tpusnapshot_restore_staging_pool_*`` metrics are the restore
-        # pool's alone; the takes' pool shows in its snapmem domain and
-        # in each take's report (``stage_phases``).
+        # handed out. The ``tpusnapshot_restore_staging_pool_*`` metrics
+        # are the restore pool's alone; the takes' pool shows in its
+        # snapmem domain and in each take's report (``stage_phases``).
+        # In both, a miss allocates untouched pages (``np.empty``),
+        # which whoever fills the buffer faults in outside the pool's
+        # lock: sixteen copying threads side by side in a take, a
+        # plug-in's ``readinto`` in a restore.
         self.take_side = take_side
         self.max_wait_s = (
             max_wait_s
@@ -257,14 +266,20 @@ class StagingPool:
 
     # ------------------------------------------------------------ acquire
     def acquire(
-        self, nbytes: int, profile: Optional["_cprof.PhaseProfile"] = None
+        self,
+        nbytes: int,
+        profile: Optional["_cprof.PhaseProfile"] = None,
+        wait: bool = True,
     ) -> StagingLease:
         """A buffer of exactly ``nbytes``, reused when the pool holds
         one. At capacity (outstanding + request past the cap while
         other leases are live) the call waits — bounded by
         ``max_wait_s`` — for a release, noting the wait into
         ``profile`` as the ``pool_wait`` sub-step; it then allocates
-        past the cap rather than ever deadlocking the pipeline."""
+        past the cap rather than ever deadlocking the pipeline.
+        ``wait=False`` never waits: for a caller whose leases another
+        budget already bounds (a restore's read destinations, held to
+        the read stage's host budget)."""
         _release_dropped()
         with self._cond:
             buf = self._take_free_locked(nbytes)
@@ -279,6 +294,7 @@ class StagingPool:
                 self._evict_free_locked(nbytes)
             if (
                 buf is None
+                and wait
                 and self.max_wait_s > 0
                 and self._must_wait_locked(nbytes)
             ):
@@ -297,11 +313,7 @@ class StagingPool:
             if reused:
                 self._count("hits", _metric_names.RESTORE_POOL_HITS)
             else:
-                buf = (
-                    np.empty(nbytes, np.uint8)
-                    if self.take_side
-                    else bytearray(nbytes)
-                )
+                buf = np.empty(nbytes, np.uint8)
                 self._count("misses", _metric_names.RESTORE_POOL_MISSES)
             self._in_use_bytes += nbytes
             self._publish_locked()

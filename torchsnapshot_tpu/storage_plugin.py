@@ -256,7 +256,10 @@ class RefRouterPlugin(StoragePlugin):
         if plugin is self._inner:
             await plugin.read(io_req)
             return
-        routed = IOReq(path=path, buf=io_req.buf, byte_range=io_req.byte_range)
+        routed = IOReq(
+            path=path, buf=io_req.buf, byte_range=io_req.byte_range,
+            into=io_req.into,
+        )
         await plugin.read(routed)
         io_req.data = routed.data
 

@@ -13,7 +13,7 @@ import errno
 import os
 import threading
 import time
-from typing import Optional, Set, Tuple
+from typing import Any, Optional, Set, Tuple
 
 from .. import telemetry, tracing
 from ..io_types import IOReq, StoragePlugin, emit_storage_op
@@ -23,6 +23,19 @@ def _payload_nbytes(io_req: IOReq) -> int:
     if io_req.data is not None:
         return len(io_req.data)
     return io_req.buf.getbuffer().nbytes
+
+
+def _read_into(f: Any, dest: memoryview) -> memoryview:
+    """Fill ``dest`` from ``f``; the view of what was read, shorter than
+    ``dest`` only where the file ended first (the consumer's length check
+    then names the object truncated)."""
+    got = 0
+    while got < len(dest):
+        n = f.readinto(dest[got:])
+        if not n:
+            break
+        got += n
+    return dest[:got]
 
 
 def _fsync_dir(path: str) -> None:
@@ -50,25 +63,22 @@ class FSStoragePlugin(StoragePlugin):
     # at 8, against 110 ms free) for a drain that ends about a second
     # sooner.
     max_write_concurrency = 1
-    # Two read streams. Measured on the same host (PERF.md section 5,
-    # PR 35, the sweep of this cap at 1/2/4/8/16 under a restore of
-    # 4.08 GB in 72 parts of 64 MiB, three to seven runs a value): the
-    # directory is one serial pipe (1.07-1.20 GB/s at any count, PR 30),
-    # an `open` completes only once the other streams' data has passed,
-    # and the restore takes 3.89-4.08 s at 1, 3.90-3.93 s at 2 (4.09
-    # and 4.21 in two runs of seven), 3.93-4.09 s at 4, 3.83-4.11 s at 8
-    # and 3.84-3.99 s at 16 (4.05-4.21 s before the read stage fed
-    # itself): flat, as plain reads are. What the count decides is where
-    # the loss sits. One stream leaves the pipe empty between two reads
-    # (60 gaps of 4.7 ms); a second keeps a request waiting behind the
-    # one in flight; every further one only lands more parts at one
-    # instant, so the streams fall into step (16 gaps of 15 ms at 4) and
-    # the burst that the last reads leave takes longer to verify and to
-    # reach the device after the pipe has gone quiet (0.03 s at 1 and 2,
-    # 0.13-0.20 s at 4, 0.37-0.45 s at 16), while each stream holds
-    # 64 MiB more of host memory (high water 0.4 / 0.8 / 2.2 / 3.1 /
-    # 3.9 GB). Storage whose streams add up wants more: measure there.
-    max_read_concurrency = 2
+    # Four read streams. Measured on the same host (PERF.md section 5,
+    # PR 38): what set the read rate was the reader's memory, not the
+    # directory. Plain reads of 72 parts of 64 MiB into a new `bytes`
+    # each give 1.10-1.17 GB/s at 1, 2 and 4 streams, as PR 30 read; into
+    # buffers whose pages were touched before, 1.96-2.31 / 3.42-3.81 /
+    # 4.85-5.74 GB/s (into fresh `np.empty` pages 0.84-0.89 at any
+    # count). With parts read into the restores' staging pool
+    # (`IOReq.into`), the restore of 4.08 GB in gpt3-6.7b.kill_resume
+    # takes a median 2.63 s at 1 stream, 2.11-2.22 s at 2 and 1.58 s at
+    # 4 (one run of 11-17 cycles a value; 4.11-4.20 s at the parent's
+    # 2 streams of fresh `bytes`), with the read budget's high water at
+    # 0.34 / 0.47-0.60 / 0.81-1.07 GB, inside the pool's 1 GiB: every
+    # part of a process's second and later restores reads into a
+    # reused buffer. More streams were not measured; they would pass
+    # the pool's capacity.
+    max_read_concurrency = 4
 
     def __init__(self, root: str) -> None:
         self.root = root
@@ -237,7 +247,10 @@ class FSStoragePlugin(StoragePlugin):
                 if io_req.byte_range is not None:
                     start, end = io_req.byte_range
                     f.seek(start)
-                    payload = f.read(end - start)
+                    if io_req.into is not None:
+                        payload = _read_into(f, io_req.into())
+                    else:
+                        payload = f.read(end - start)
                 else:
                     payload = f.read()
         # Return via `data`: zero-copy for consumers. Callers that want the
