@@ -1,0 +1,14 @@
+"""Milliseconds of the profiled kill -> resume cycle in which the device
+ran no operation while the restore consumed what it read (the
+scheduler's ``consume`` span, a working ``consume.*`` sub-step), and
+no transfer was under way.
+
+None where the trace holds no anchor of the program's roots or no
+busy intervals, or the program recorded no span
+(``perfbench/idle_by_phase.py``)."""
+
+from perfbench.idle_by_phase import RESTORE, idle_ms
+
+
+def read(obs):
+    return idle_ms(obs, RESTORE, "consume")

@@ -437,9 +437,7 @@ class Snapshot:
             from .incremental import apply_incremental
 
             watch.set_phase("incremental")
-            with recorder.phase("incremental"), tracing.span(
-                "Snapshot.incremental", path=path
-            ):
+            with recorder.phase("incremental"):
                 base_paths_meta, inc_stats = apply_incremental(
                     manifest,
                     pending_write_reqs,
@@ -477,9 +475,7 @@ class Snapshot:
             from . import chunkstore
 
             watch.set_phase("chunk")
-            with recorder.phase("chunk"), tracing.span(
-                "Snapshot.chunkstore", path=path
-            ):
+            with recorder.phase("chunk"):
                 chunk_ctx = chunkstore.apply_chunkstore(
                     manifest,
                     pending_write_reqs,

@@ -44,7 +44,7 @@ Durability contract (the ledger is *metadata*, not ephemeral export):
 Like every telemetry write, appends are best-effort at the call sites:
 a ledger failure warns and never fails the commit it describes — but
 within ``append`` the storage write lands BEFORE any success signal
-(log line / ``ledger_appended`` trace instant), the same
+(the records counter), the same
 durability-before-publish ordering snapcheck's SNAP002 enforces.
 
 Record schema (``format_version`` 1); nullable fields are null when the
@@ -323,17 +323,13 @@ async def aappend(storage: Any, record: Dict[str, Any]) -> None:
     rooted at the ledger root). Read-validate-rewrite under the
     process-wide append lock: the current object's valid prefix plus
     the new line is written back through the plugin's atomic replace.
-    The write lands before the success instant — durability before
-    publish."""
-    from .. import tracing
-
+    The write lands before the records counter moves — durability
+    before publish."""
     with _APPEND_LOCK:
-        await _aappend_locked(storage, record, tracing)
+        await _aappend_locked(storage, record)
 
 
-async def _aappend_locked(
-    storage: Any, record: Dict[str, Any], tracing: Any
-) -> None:
+async def _aappend_locked(storage: Any, record: Dict[str, Any]) -> None:
     from ..utils.env import env_int
 
     raw = await _aread_raw(storage)
@@ -367,11 +363,6 @@ async def _aappend_locked(
     REGISTRY.counter(
         _m.LEDGER_RECORDS_TOTAL, kind=str(record.get("kind", "?"))
     ).inc()
-    tracing.instant(
-        "ledger_appended",
-        kind=str(record.get("kind", "?")),
-        step=record.get("step") if record.get("step") is not None else -1,
-    )
 
 
 def _with_goodput_window(

@@ -41,6 +41,18 @@ causal chain (``telemetry/merge.py`` draws the arrows and computes the
 cross-process critical path). Context generation is independent of
 whether THIS process records events: a tracing-off client still
 propagates ids so a tracing-on server can attribute its spans.
+
+The profiler's clock (anchors): while recording, each take/restore root
+(:func:`trace_scope`) also enters a ``jax.profiler.TraceAnnotation``
+named ``tpusnapshot.<kind>`` that carries ``ts_us``, this file's clock
+at the instant the annotation began (µs since :func:`enable`, the
+span file's ``ts`` base), and ``trace``, the root's trace id. A JAX
+profile taken meanwhile therefore holds, for every root it saw, one
+point known on both clocks: ``offset_ns = annotation_start_ns -
+ts_us * 1e3``, and every span and interval of the file (all of them
+read ``time.monotonic()``) lands on the profiler's nanoseconds at
+``ts_us * 1e3 + offset_ns``, beside the device's operations. Nothing
+assumes that the profiler's clock is the wall clock.
 """
 
 import atexit
@@ -117,12 +129,31 @@ def trace_scope(kind: str):
     """Stamp a fresh trace id for one take/restore root. Yields the id.
     Nested roots (a restore issued inside another operation) get their
     own id — the innermost root wins, which is what per-operation
-    attribution wants."""
-    token = _TRACE_CTX.set(new_trace_id(kind))
+    attribution wants. While recording, the root also enters its
+    profiler anchor (module docstring)."""
+    trace_id = new_trace_id(kind)
+    token = _TRACE_CTX.set(trace_id)
     try:
-        yield _TRACE_CTX.get()
+        if _events is None:
+            yield trace_id
+        else:
+            with _anchor(kind, trace_id):
+                yield trace_id
     finally:
         _TRACE_CTX.reset(token)
+
+
+def _anchor(kind: str, trace_id: str):
+    """The root's ``tpusnapshot.<kind>`` profiler annotation. The clock
+    is read last, so that the annotation's own start (taken as it is
+    made) follows it by the call alone."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(
+        f"tpusnapshot.{kind}",
+        trace=trace_id,
+        ts_us=(time.monotonic() - _t0) * 1e6,
+    )
 
 
 @contextmanager
