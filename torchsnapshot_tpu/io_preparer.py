@@ -728,6 +728,19 @@ class _TargetRegion:
                     self.buffer = np.empty(self.sizes, dtype=self.dtype)
             return self.buffer
 
+    def adopt(
+        self,
+        view: np.ndarray,
+        lease: Optional[staging_pool.StagingLease] = None,
+    ) -> None:
+        """Take ``view``, a chunk's payload that exactly covers this
+        region, as the buffer, with no copy; ``lease``, the pooled
+        buffer under it, goes back by :meth:`release_lease` as a buffer
+        from :meth:`ensure_buffer` does."""
+        with self._buf_lock:
+            self.buffer = view
+            self._lease = lease
+
     def release_lease(self) -> None:
         """Return the pooled backing (if any) — only safe once no
         pending transfer still reads from ``buffer``."""
@@ -736,6 +749,21 @@ class _TargetRegion:
             self.buffer = None if lease is not None else self.buffer
         if lease is not None:
             lease.release()
+
+
+def _covers_region(
+    view_shape: List[int],
+    region: _TargetRegion,
+    region_slices: Tuple[slice, ...],
+    view_slices: Tuple[slice, ...],
+) -> bool:
+    """Whether a chunk of ``view_shape`` whose overlap with ``region`` is
+    ``region_slices`` / ``view_slices`` is exactly the region."""
+    return list(view_shape) == list(region.sizes) and all(
+        sl.start == 0 and sl.stop == dim
+        for slices in (region_slices, view_slices)
+        for sl, dim in zip(slices, region.sizes)
+    )
 
 
 class _ChunkCopyConsumer(BufferConsumer):
@@ -752,6 +780,7 @@ class _ChunkCopyConsumer(BufferConsumer):
         on_done: Optional[Callable[[], None]] = None,
         allow_adopt: bool = True,
         region_notify: Optional[Callable[[_TargetRegion], None]] = None,
+        whole_object: Optional[str] = None,
     ) -> None:
         # copies: (region, region_slices, view_slices)
         self._view_shape = view_shape
@@ -771,11 +800,58 @@ class _ChunkCopyConsumer(BufferConsumer):
         self._region_notify = region_notify
         self._cost = int(np.dtype(dtype).itemsize * np.prod(view_shape))
         self._profile = _cprof.current()
+        # The stored object's location where this consumer's read
+        # returns it whole (None: a range of it, a part or a content
+        # chunk): its length is checked, and it names the object.
+        self._whole_object = whole_object
+        # Whether the chunk exactly covers its one region, whose buffer
+        # it can then be (adoption, below).
+        self._covers = len(copies) == 1 and _covers_region(
+            view_shape, *copies[0]
+        )
+        # The pooled buffer the payload was read into (IOReq.into), until
+        # the region adopts it or it goes back.
+        self._read_lease: Optional[staging_pool.StagingLease] = None
+
+    def reads_into_pool(self) -> bool:
+        # A whole object, stored as its bytes, that becomes its region's
+        # buffer: the region gives the buffer back once the put that
+        # copies it has landed (early put or finalize).
+        return (
+            self._whole_object is not None
+            and self._compression is None
+            and self._allow_adopt
+            and self._covers
+            and self._copies[0][0]._poolable
+        )
+
+    def hold_read_lease(self, lease: Any) -> None:
+        self._read_lease = lease
 
     async def consume_buffer(
         self, buf: BufferType, executor: Optional[Executor] = None
     ) -> None:
         def _copy() -> None:
+            # Owned here from now on: adopted by the region, or given
+            # back on every other path (a raise included).
+            lease, self._read_lease = self._read_lease, None
+            adopted = False
+            try:
+                adopted = _scatter(lease)
+            finally:
+                if lease is not None and not adopted:
+                    lease.release()
+
+        def _scatter(lease: Optional[staging_pool.StagingLease]) -> bool:
+            """Verify and place the payload; whether a region adopted it."""
+            if self._whole_object is not None and self._compression is None:
+                nbytes = memoryview(buf).nbytes
+                if nbytes != self._cost:
+                    raise RuntimeError(
+                        f"Read of {self._whole_object!r} returned {nbytes} "
+                        f"bytes where its entry holds {self._cost}: the "
+                        f"stored object is truncated or was replaced"
+                    )
             with _cprof.substep(self._profile, "verify", len(buf)):
                 verify_checksum(buf, self._checksum)
             if self._compression is not None:
@@ -790,27 +866,20 @@ class _ChunkCopyConsumer(BufferConsumer):
                 for region, region_slices, view_slices in self._copies:
                     if (
                         self._allow_adopt
-                        and len(self._copies) == 1
+                        and self._covers
                         and region.buffer is None
-                        and list(view.shape) == list(region.sizes)
-                        and all(
-                            sl.start == 0 and sl.stop == dim
-                            for sl, dim in zip(region_slices, region.sizes)
-                        )
-                        and all(
-                            sl.start == 0 and sl.stop == dim
-                            for sl, dim in zip(view_slices, view.shape)
-                        )
                     ):
                         # The chunk exactly covers this region: adopt the
                         # zero-copy view instead of memcpy-ing into a
                         # staging buffer (np.frombuffer views are
-                        # read-only, which device_put accepts).
-                        region.buffer = view
-                    else:
-                        region.ensure_buffer(self._profile)[
-                            region_slices
-                        ] = view[view_slices]
+                        # read-only, which device_put accepts), with the
+                        # pooled buffer under it where there is one.
+                        region.adopt(view, lease)
+                        return True
+                    region.ensure_buffer(self._profile)[
+                        region_slices
+                    ] = view[view_slices]
+            return False
 
         def _copy_and_signal() -> None:
             with _cprof.consume_section():
@@ -1638,11 +1707,12 @@ class ArrayRestorePlan:
         # instead of copying it — donating such a region's pooled
         # backing would let a later restore overwrite the "restored"
         # array through the alias. Pool region buffers only when every
-        # consumer device actually copies across a link.
+        # consumer device's put copies them: across a link, or through
+        # the chunked path (the test the early put and finalize choose
+        # their put by).
         for region in self._regions:
-            if any(
-                getattr(d, "platform", None) == "cpu"
-                for d in region.devices
+            if not all(
+                h2d_put_copies(region.nbytes, d) for d in region.devices
             ):
                 region._poolable = False
         self._chunks = chunks
@@ -1950,7 +2020,10 @@ class ArrayRestorePlan:
                 # Non-contiguous overlap somewhere: read the chunk once and
                 # scatter into every overlapping region. Whole-object reads
                 # can verify the stored checksum (ranged reads cannot).
-                def _whole_consumer(allow_adopt: bool = True):
+                def _whole_consumer(
+                    allow_adopt: bool = True,
+                    whole_object: Optional[str] = None,
+                ):
                     for region, _rs, _ov in copies:
                         region.pending_copies += 1
                     return _ChunkCopyConsumer(
@@ -1965,6 +2038,7 @@ class ArrayRestorePlan:
                         on_done=self._on_req_done,
                         allow_adopt=allow_adopt,
                         region_notify=self._note_region_copy,
+                        whole_object=whole_object,
                     )
 
                 n_logical += 1
@@ -1985,7 +2059,9 @@ class ArrayRestorePlan:
                     reqs.append(
                         ReadReq(
                             path=location,
-                            buffer_consumer=_whole_consumer(),
+                            buffer_consumer=_whole_consumer(
+                                whole_object=location
+                            ),
                         )
                     )
         with self._lock:

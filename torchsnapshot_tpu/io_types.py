@@ -320,11 +320,13 @@ class BufferConsumer(abc.ABC):
         deferred allocation is actually freed."""
 
     def reads_into_pool(self) -> bool:
-        """Whether this ranged read's payload may land in a buffer of
-        the restores' staging pool (``staging_pool.py``): only where
-        nothing keeps a view of the payload once the consumer is done
-        with it (its bytes go to a device that copies them) and the
-        consumer gives the buffer back itself. False by default."""
+        """Whether this read's payload may land in a buffer of the
+        restores' staging pool (``staging_pool.py``): only where nothing
+        keeps a view of the payload once the consumer is done with it
+        (its bytes go to a device that copies them) and the consumer
+        gives the buffer back itself. The buffer is of the read's range,
+        or for a whole object of :meth:`get_consuming_cost_bytes`. False
+        by default."""
         return False
 
     def hold_read_lease(self, lease: Any) -> None:
@@ -374,9 +376,10 @@ class IOReq:
     # instead of draining `buf`. Reads: plugins that can, return the
     # payload here instead of memcpy-ing it into `buf`.
     data: Optional[BufferType] = None
-    # Ranged reads: a plug-in that can fill a buffer may call this for a
-    # writable one of the range's size, read the payload into it and
-    # set `data` to a view of what it read; one that cannot ignores it.
+    # Reads: a plug-in that can fill a buffer may call this for a
+    # writable one of the range's size (of a whole object, of the size
+    # its entry gives), read the payload into it and set `data` to a
+    # view of what it read; one that cannot ignores it.
     into: Optional[Callable[[], memoryview]] = None
 
 

@@ -252,9 +252,11 @@ async def execute_write_reqs(
         kind="take",
     ):
         # The burst is predicted not to fit: the assembly buffers that
-        # earlier takes left in their pool are the one thing this
-        # pipeline can give the host back first.
+        # earlier takes left in their pool, and the read buffers that
+        # earlier restores left in theirs, are what this pipeline can
+        # give the host back first.
         staging_pool.trim_take_staging_pool()
+        staging_pool.trim_restore_staging_pool()
     try:
         while pending or staged or staging or io_tasks:
             # Dispatch staging while the budget allows; always keep at
@@ -579,13 +581,16 @@ class _ReadStage:
         # ``read_idle_s``.
         self.read_idle_s = 0.0
         self._idle_since: Optional[float] = None
-        # Where a consumer can take it, a ranged read lands in a buffer
-        # of the restores' staging pool (``IOReq.into``): the bytes read
-        # into one an earlier read had filled, and into a new one, are
-        # the report's ``read_pool_hit_bytes`` / ``read_pool_miss_bytes``.
+        # Where a consumer can take it, a read lands in a buffer of the
+        # restores' staging pool (``IOReq.into``): the bytes read into
+        # one an earlier read had filled, into a new one and into memory
+        # the plug-in allocated are the report's
+        # ``read_pool_hit_bytes`` / ``read_pool_miss_bytes`` /
+        # ``read_unpooled_bytes``, which add up to the bytes read.
         self._pool = staging_pool.get_staging_pool()
         self.pool_hit_bytes = 0
         self.pool_miss_bytes = 0
+        self.unpooled_bytes = 0
 
     def start(self) -> None:
         _start_threads(
@@ -732,28 +737,34 @@ class _ReadStage:
             if reading:
                 await asyncio.wait(reading)
 
-    def _lease_into(self, rr: ReadReq, leases: List[Any]) -> memoryview:
+    def _lease_into(
+        self, rr: ReadReq, nbytes: int, leases: List[Any]
+    ) -> memoryview:
         """``IOReq.into`` of ``rr``, on the plug-in's thread: the buffer
         its payload is read into, leased at the first call (a retried
         read gets it again) and owned by the consumer from then on. It
         never waits for the pool: this stage's host budget holds what
         is read."""
-        start, end = rr.byte_range
         if not leases:
-            lease = self._pool.acquire(end - start, wait=False)
+            lease = self._pool.acquire(nbytes, wait=False)
             rr.buffer_consumer.hold_read_lease(lease)
             leases.append(lease)
-        return memoryview(leases[0].buffer)[: end - start]
+        return memoryview(leases[0].buffer)[:nbytes]
 
     async def _read(self, rr: ReadReq, refund: int) -> None:
         io_req = IOReq(path=rr.path, byte_range=rr.byte_range)
         leases: List[Any] = []
-        if (
-            self._pool is not None
-            and rr.byte_range is not None
-            and rr.buffer_consumer.reads_into_pool()
-        ):
-            io_req.into = functools.partial(self._lease_into, rr, leases)
+        if self._pool is not None and rr.buffer_consumer.reads_into_pool():
+            # A range's destination is of its length; a whole object's,
+            # which is read into the pool only where it is stored as its
+            # bytes, of what its consumer consumes.
+            if rr.byte_range is not None:
+                nbytes = rr.byte_range[1] - rr.byte_range[0]
+            else:
+                nbytes = rr.buffer_consumer.get_consuming_cost_bytes()
+            io_req.into = functools.partial(
+                self._lease_into, rr, nbytes, leases
+            )
         t0 = time.monotonic()
         if self.reads_in_flight == 0 and self._idle_since is not None:
             self.read_idle_s += t0 - self._idle_since
@@ -776,14 +787,16 @@ class _ReadStage:
             self._pending.clear()
             self._post(self._fail, failure)
         else:
-            if leases:
-                if leases[0].reused:
-                    self.pool_hit_bytes += leases[0].nbytes
-                else:
-                    self.pool_miss_bytes += leases[0].nbytes
-            self._post(
-                self._deliver, rr, io_payload(io_req), refund, ended - t0
-            )
+            payload = io_payload(io_req)
+            # What the consumes' loop counts as read (``_deliver``).
+            nbytes = len(payload)
+            if not leases:
+                self.unpooled_bytes += nbytes
+            elif leases[0].reused:
+                self.pool_hit_bytes += nbytes
+            else:
+                self.pool_miss_bytes += nbytes
+            self._post(self._deliver, rr, payload, refund, ended - t0)
 
 
 async def execute_read_reqs(
@@ -1079,6 +1092,7 @@ async def execute_read_reqs(
         for key, nbytes in (
             ("read_pool_hit_bytes", stage.pool_hit_bytes),
             ("read_pool_miss_bytes", stage.pool_miss_bytes),
+            ("read_unpooled_bytes", stage.unpooled_bytes),
         ):
             stats[key] = stats.get(key, 0) + nbytes
     mbps = bytes_read / 1024 / 1024 / elapsed if elapsed > 0 else 0.0

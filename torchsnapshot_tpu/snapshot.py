@@ -48,7 +48,7 @@ import time
 import uuid
 from typing import Any, Callable, Dict, List, Optional, Set, Tuple
 
-from . import telemetry, tracing
+from . import staging_pool, telemetry, tracing
 from .coord import Coordinator, barrier_compat, get_coordinator
 from .telemetry import consume_profile as _phase_profile
 from .telemetry import export as telemetry_export
@@ -899,6 +899,7 @@ class Snapshot:
 
         global_keys = _gather_keys(coordinator, sorted(app_state.keys()))
         budget = get_process_memory_budget_bytes(coordinator)
+        staging_pool.begin_restore(budget)
         n_selected = 0
         verify_jobs: List[Tuple[str, Entry, Any]] = []
         for key in global_keys:
@@ -1006,8 +1007,9 @@ class Snapshot:
         # thread-seconds, not wall), the seconds between the first read
         # issued and the last returned with no plug-in read in flight
         # and the fan-out the reads went through, the bytes read into a
-        # pooled buffer an earlier read had filled and into a new one
-        # (``IOReq.into``; neither where the plug-in allocates), and the
+        # pooled buffer an earlier read had filled, into a new one
+        # (``IOReq.into``) and into memory the plug-in allocated (the
+        # three add up to the bytes read), and the
         # fullest device's peak as the runtime reports it (None on a
         # backend that reports none; a peak since the process began, so
         # an upper bound on this restore's own). First, what the restore
@@ -1028,6 +1030,7 @@ class Snapshot:
             read_streams=read_stats.pop("read_streams", 0),
             read_pool_hit_bytes=read_stats.pop("read_pool_hit_bytes", 0),
             read_pool_miss_bytes=read_stats.pop("read_pool_miss_bytes", 0),
+            read_unpooled_bytes=read_stats.pop("read_unpooled_bytes", 0),
             device_peak_bytes=device_peak_bytes(),
         )
         ops = read_stats.get("ops") or {}
@@ -1943,6 +1946,7 @@ class Snapshot:
                 )
             entry = available[logical_path]
             budget = get_local_memory_budget_bytes()
+            staging_pool.begin_restore(budget)
             if isinstance(entry, (ListEntry, DictEntry)):
                 # Container: read every leaf beneath it and inflate the
                 # subtree (templates supply placements leaf-by-leaf only

@@ -30,10 +30,22 @@ The restore's read destinations: a streamed part (``io_preparer.
 _StreamingSplitState``), whose bytes go to a device that copies them,
 is read straight into a buffer of this pool (``IOReq.into``, filled by
 the fs plug-in's ``readinto``), which goes back once the part has
-landed and been folded into its object's checksum. A restore then reads
-into pages the host has faulted in already, not into a fresh ``bytes``
-of 64 MiB a part. Those leases never wait (``acquire(wait=False)``): the
-read stage's host budget bounds them.
+landed and been folded into its object's checksum; so is a whole object
+that exactly covers a device region whose puts copy
+(``io_preparer._ChunkCopyConsumer``), whose buffer the region adopts
+and gives back once its put has landed. A restore then reads into pages
+the host has faulted in already, not into a fresh ``bytes`` an object.
+Those leases never wait (``acquire(wait=False)``): the read stage's host
+budget bounds them. A restore reads its objects by size, largest first,
+so a later restore finds its buffers only where the pool kept what each
+size held at once: unless ``TPUSNAPSHOT_RESTORE_STAGING_POOL_BYTES`` is
+set, the cap follows those bytes from a restore's start, summed over the
+sizes, up to the restore's host budget (:func:`begin_restore`), and does
+not fall. One cap that holds only what a restore holds at one instant
+keeps the sizes it read last, which the next restore reads last, so its
+first reads of every size miss again. What is kept between restores
+goes with :func:`trim_restore_staging_pool`, which a take also calls
+where memwatch's forecast predicts an overcommit.
 
 The take side (:func:`get_take_staging_pool`): the host buffers a take
 assembles its chunked leaves in (``ArrayBufferStager._stage_phases``
@@ -61,9 +73,10 @@ it.
 Env knobs (the restore side's; the take side has none):
 
 - ``TPUSNAPSHOT_RESTORE_STAGING_POOL_BYTES`` — pool capacity (default
-  1 GiB). Bounds both the retained free set and the point past which
-  new acquisitions wait for a release. ``0`` disables pooling entirely
-  (callers fall back to plain allocations).
+  1 GiB; unset, a restore raises it to what it held at once, above;
+  set, it stays as set). Bounds both the retained free set and the
+  point past which new acquisitions wait for a release. ``0`` disables
+  pooling entirely (callers fall back to plain allocations).
 - ``TPUSNAPSHOT_RESTORE_POOL_WAIT_S`` — max seconds an acquisition
   waits at capacity before allocating past the cap anyway (default 5).
   The cap is a pressure valve, not a correctness limit: the scheduler's
@@ -72,6 +85,7 @@ Env knobs (the restore side's; the take side has none):
 """
 
 import collections
+import os
 import sys
 import threading
 import time
@@ -252,6 +266,15 @@ class StagingPool:
         self._free_bytes = 0
         self._in_use_bytes = 0
         self._high_water_bytes = 0
+        # By size: the leases out now, and the most out at once since
+        # the last ``retain_held`` (with their bytes summed), up to
+        # whose bound the cap follows those bytes.
+        self._leased: Dict[int, int] = {}
+        self._held: Dict[int, int] = {}
+        self._held_bytes = 0
+        self._retain_bound = 0
+        # Whether the cap was set by the user (a restore never raises it).
+        self.cap_is_set = False
         # snapmem: retained + leased bytes against the pool cap. Leased
         # bytes are pinned (a live restore holds them); retained free
         # buffers are evictable by design. Residual tracking watches
@@ -282,6 +305,8 @@ class StagingPool:
         the read stage's host budget)."""
         _release_dropped()
         with self._cond:
+            if not self.take_side:
+                self._note_lease_locked(nbytes)
             buf = self._take_free_locked(nbytes)
             if buf is None and not self.take_side:
                 # No exact-size hit: retained free buffers of OTHER
@@ -313,11 +338,54 @@ class StagingPool:
             if reused:
                 self._count("hits", _metric_names.RESTORE_POOL_HITS)
             else:
-                buf = np.empty(nbytes, np.uint8)
+                try:
+                    buf = np.empty(nbytes, np.uint8)
+                except BaseException:
+                    if not self.take_side:
+                        self._unnote_lease_locked(nbytes)
+                    raise
                 self._count("misses", _metric_names.RESTORE_POOL_MISSES)
             self._in_use_bytes += nbytes
             self._publish_locked()
         return StagingLease(self, buf, nbytes, reused)
+
+    def _note_lease_locked(self, nbytes: int) -> None:
+        """Count a lease of ``nbytes`` out (the restores' pool), and
+        raise the cap (up to the bound ``retain_held`` set) to what the
+        leases of each size held at once: before a miss makes room,
+        which then takes none of what the sizes held at once keep."""
+        out = self._leased.get(nbytes, 0) + 1
+        self._leased[nbytes] = out
+        if out > self._held.get(nbytes, 0):
+            self._held[nbytes] = out
+            self._held_bytes += nbytes
+            keep = min(self._held_bytes, self._retain_bound)
+            if keep > self.capacity_bytes:
+                self.capacity_bytes = keep
+                self._mem_domain.set_cap(keep)
+
+    def _unnote_lease_locked(self, nbytes: int) -> None:
+        out = self._leased.get(nbytes, 0) - 1
+        if out > 0:
+            self._leased[nbytes] = out
+        else:
+            self._leased.pop(nbytes, None)
+
+    def retain_held(self, bound: int) -> None:
+        """From now on keep, up to ``bound`` bytes, the buffers of each
+        size that are leased at once from this call on (see
+        :func:`begin_restore`): the cap rises to those bytes, summed
+        over the sizes, so that a restore like this one finds every
+        buffer it holds at once. Counted per size and not at one
+        instant, since a restore reads each Stateful's objects by size,
+        largest first: the buffers of the sizes it read first are what
+        the next one reads first. The cap never falls."""
+        with self._cond:
+            self._held = dict(self._leased)
+            self._held_bytes = sum(
+                size * out for size, out in self._held.items()
+            )
+            self._retain_bound = bound
 
     def _count(self, event: str, restore_metric: str) -> None:
         self._mem_domain.counter(event)
@@ -381,6 +449,8 @@ class StagingPool:
         _release_dropped()
         with self._cond:
             self._in_use_bytes -= nbytes
+            if not self.take_side:
+                self._unnote_lease_locked(nbytes)
             if self.take_side:
                 # The save that just wrote this buffer is what the next
                 # one will look like: the sizes unused for longest (the
@@ -455,8 +525,29 @@ def get_staging_pool() -> Optional[StagingPool]:
     with _pool_lock:
         if not _pool:
             cap = pool_capacity_bytes()
-            _pool.append(StagingPool(cap) if cap > 0 else None)
+            pool = StagingPool(cap) if cap > 0 else None
+            if pool is not None:
+                pool.cap_is_set = bool(os.environ.get(_POOL_BYTES_ENV_VAR))
+            _pool.append(pool)
         return _pool[0]
+
+
+def begin_restore(bound: int) -> None:
+    """A restore begins: unless the user set its cap, the restores' pool
+    keeps, up to ``bound`` (the restore's host budget), what each buffer
+    size of this restore holds at once (:meth:`StagingPool.retain_held`)."""
+    pool = get_staging_pool()
+    if pool is not None and not pool.cap_is_set:
+        pool.retain_held(bound)
+
+
+def trim_restore_staging_pool() -> int:
+    """Give back the host memory the restores' pool keeps between
+    restores (what one restore held at once, or the cap the user set);
+    the next restore reads into fresh pages again. Returns the bytes
+    let go."""
+    pool = get_staging_pool()
+    return pool.trim() if pool is not None else 0
 
 
 def reset_staging_pool() -> None:

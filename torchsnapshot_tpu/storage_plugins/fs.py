@@ -38,6 +38,18 @@ def _read_into(f: Any, dest: memoryview) -> memoryview:
     return dest[:got]
 
 
+def _read_whole_into(f: Any, dest: memoryview) -> Any:
+    """A whole object into ``dest``, which is of the size its entry
+    gives: the view of what was read, shorter where the file ended
+    first; where the file holds more, all of it in a new ``bytes``. The
+    consumer's length check names the object either way."""
+    got = _read_into(f, dest)
+    if len(got) < len(dest):
+        return got
+    rest = f.read()
+    return bytes(got) + rest if rest else got
+
+
 def _fsync_dir(path: str) -> None:
     fd = os.open(path, os.O_RDONLY)
     try:
@@ -251,6 +263,8 @@ class FSStoragePlugin(StoragePlugin):
                         payload = _read_into(f, io_req.into())
                     else:
                         payload = f.read(end - start)
+                elif io_req.into is not None:
+                    payload = _read_whole_into(f, io_req.into())
                 else:
                     payload = f.read()
         # Return via `data`: zero-copy for consumers. Callers that want the
