@@ -138,6 +138,34 @@ def restore_swaps_shards():
 
 
 @contextlib.contextmanager
+def restore_lands_one_replica():
+    """Every leaf held more than once comes back right in its first copy
+    and zeroed in the others, as the template gave them: the exchange
+    that lands a read block on every device holding it left out."""
+    real = CheckpointManager.restore
+
+    def restore(self, app_state, step=None, paths=None):
+        got = real(self, app_state, step=step, paths=paths)
+
+        def first_copy_only(x):
+            if not isinstance(x, jax.Array):
+                return x
+            blocks = [
+                s.data if s.replica_id == 0 else jnp.zeros_like(s.data)
+                for s in x.addressable_shards
+            ]
+            return jax.make_array_from_single_device_arrays(x.shape, x.sharding, blocks)
+
+        for target in app_state.values():
+            if _holds_arrays(target):
+                target.load_state_dict(jax.tree.map(first_copy_only, target.state_dict()))
+        return got
+
+    with _patched(CheckpointManager, "restore", restore):
+        yield
+
+
+@contextlib.contextmanager
 def corrupt_newest_object():
     """Once a save is durable, one byte in the middle of its largest
     object is flipped on disk."""
@@ -170,5 +198,6 @@ FAULTS = {
     "restore_lands_nothing": restore_lands_nothing,
     "restore_lands_half": restore_lands_half,
     "restore_swaps_shards": restore_swaps_shards,
+    "restore_lands_one_replica": restore_lands_one_replica,
     "corrupt_newest_object": corrupt_newest_object,
 }

@@ -233,6 +233,19 @@ class Run:
             self.diagnose(comparison=what, step=step, **entry)
         return len(differing)
 
+    def compare_copies(self, what: str, step: int, pinned, pending) -> int:
+        """Every copy of every leaf (``reference.make_copy_checksum_fn``)
+        against the sums pinned at save time; returns how many leaves
+        have a copy that differs, each such copy diagnosed."""
+        import numpy as np
+
+        differing = reference.differing_copies(
+            self.leaf_names, np.asarray(pinned), reference.copy_sums(pending)
+        )
+        for entry in differing:
+            self.diagnose(comparison=what, step=step, **entry)
+        return len({entry["leaf"] for entry in differing})
+
     def compare_restored(self, what: str, step: int, pinned, restored_tree) -> int:
         return self.compare_sums(what, step, pinned, self.checksum(restored_tree))
 

@@ -9,10 +9,9 @@ objects written tmp + fsync + rename by 16 writers (``storage_plugins/
 fs.py`` under ``scheduler.py``). Best of three passes: interference only
 subtracts. Sizes are pinned here, not taken from the environment.
 
-These are copies of the idea of ``ops/transfer.probe_h2d_gbps`` and
-``bench.py:_probe_d2h_gbps`` (both read ``jax.devices()[0]`` only and
-disagreed 2.9x in one run); the originals are listed in PERF.md's open
-questions.
+The idea is that of ``ops/transfer.probe_h2d_gbps``, which reads
+``jax.devices()[0]`` only; the H2D probe here is the benchmark's own, so
+that the program cannot change the ceiling it is held against.
 """
 
 import os

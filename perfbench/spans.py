@@ -1,8 +1,7 @@
 """Host spans of the program (``torchsnapshot_tpu.tracing``), reduced.
 
-``union_seconds`` is a copy of ``benchmarks/trace_report.py``'s, kept
-here so that no later PR can change the yardstick (the original is
-listed in PERF.md's open questions for removal).
+``union_seconds`` is the benchmark's own, so that no change to the
+program can change the yardstick.
 """
 
 import json

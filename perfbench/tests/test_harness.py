@@ -38,7 +38,8 @@ def test_cell_runs_and_prints_the_contracts_keys(name, dirs, capsys):
 
 
 @pytest.mark.parametrize(
-    "name", ["toy.save_in_loop", "toy.kill_resume", "toy-mixed.save_in_loop"]
+    "name",
+    ["toy.save_in_loop", "toy.kill_resume", "toy-mixed.save_in_loop", "toy-tp4.reshard_resume"],
 )
 def test_traced_run_reports_per_layer_metrics(name, dirs):
     line = run_toy(name, trace=True, **dirs)
@@ -46,10 +47,13 @@ def test_traced_run_reports_per_layer_metrics(name, dirs):
     cell = manifest.resolve_cell(toy_manifest(), name)
     named = {m["name"] for m in cell.per_layer}
     assert set(line["metrics"]) <= named
-    # No device plane on the CPU backend: the device reader finds nothing
-    # and is left out; it does not report a 0.
+    # No device plane on the CPU backend: the device trace's readers find
+    # nothing and are left out; they do not report a 0. Every other
+    # metric the cell lists is there.
+    traced = {m["name"] for m in cell.per_layer if m["source"] == "device_trace"}
     assert not any(n.startswith("device_idle_pct") for n in line["metrics"])
-    assert len(line["metrics"]) >= len(named) - 1
+    assert not traced & set(line["metrics"])
+    assert set(line["metrics"]) >= named - traced
     assert "setup_s" not in line["metrics"]
 
 
@@ -87,6 +91,15 @@ FAULTS_BY_CELL = [
     ("toy-mixed.kill_resume", "restore_lands_nothing"),
     ("toy-mixed.kill_resume", "restore_lands_half"),
     ("toy-mixed-tp4.save_in_loop", "restore_swaps_shards"),
+    # A restore onto another layout: the control, a state left as the
+    # template gave it, half of it landed, the shards exchanged wrongly,
+    # and the second copy of every leaf (dp = 1) left as the template
+    # gave it.
+    ("toy-tp4.reshard_resume", "lossy_save"),
+    ("toy-tp4.reshard_resume", "restore_lands_nothing"),
+    ("toy-tp4.reshard_resume", "restore_lands_half"),
+    ("toy-tp4.reshard_resume", "restore_swaps_shards"),
+    ("toy-tp4.reshard_resume", "restore_lands_one_replica"),
 ]
 # How each toy job spells the leaves of its app state on disk.
 LEAF_PREFIX = {"toy": "train/params/", "toy-mixed": "everything/"}
@@ -179,3 +192,33 @@ def test_cli_fails_where_only_the_benchmark_is(tmp_path):
     )
     assert done.returncode != 0
     assert done.stdout.strip() == ""
+
+
+def test_the_read_bytes_reader_on_recorded_cycles_and_on_none():
+    """``restore_read_bytes_ratio``: the mean bytes a cycle read over the
+    bytes of the stored objects; nothing where a cycle's count is
+    missing (a program whose report has no such field) or no loop
+    recorded one."""
+    path = os.path.join(manifest.BENCH_DIR, "layers", "restore_read_bytes_ratio.py")
+    read = manifest.load_module(path).read
+    cycles = [{"restore_s": 1.0, "read_bytes": 200}, {"restore_s": 1.0, "read_bytes": 400}]
+    assert read({"cycles": cycles, "stored_object_bytes": 200}) == 1.5
+    assert read({"cycles": cycles + [{"read_bytes": None}], "stored_object_bytes": 200}) is None
+    assert read({"cycles": [{"restore_s": 1.0}], "stored_object_bytes": 200}) is None
+    assert read({"cycles": cycles}) is None and read({}) is None
+
+
+def test_a_restore_onto_another_layout_reads_what_was_stored(dirs):
+    """The toy's state saved on dp1 x tp4 and restored on dp2 x tp2 in
+    every cycle: each cycle's bytes read are counted, the objects' bytes
+    are those of the state, and the first step runs on the new layout."""
+    line = run_toy("toy-tp4.reshard_resume", trace=True, **dirs)
+    assert line["correct"] is True, line
+    info = line["info"]
+    assert info["saved_layout"] == {"dp": 1, "tp": 4}
+    assert info["restore_layout"] == {"dp": 2, "tp": 2}
+    _, job = toy_job("toy-tp4.reshard_resume")
+    assert info["stored_object_bytes"] == job.state_bytes
+    assert len(info["read_bytes"]) == info["cycles"] > 0
+    assert all(n >= job.state_bytes for n in info["read_bytes"])
+    assert line["metrics"]["restore_read_bytes_ratio"]["value"] >= 1.0

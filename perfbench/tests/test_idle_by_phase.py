@@ -284,3 +284,36 @@ def test_the_readers_give_the_parts_in_ms_and_nothing_without_the_keys():
     ):
         for name in list(RESTORE_READERS) + list(SAVE_READERS):
             assert _reader(name)(nothing) is None, (name, nothing)
+
+
+def test_a_device_trace_hands_the_readers_both_keys(tmp_path, untraced_after, monkeypatch):
+    """``DeviceTrace.reduce`` gives what ``reduce_planes`` gave, unchanged,
+    and beside it the first device's busy intervals and the anchors of a
+    real profile, so that a traced run's readers read a number. The CPU
+    backend has no device plane: the recorded resume sample stands in
+    for the device planes of the profile that was taken."""
+    with open(os.path.join(DATA, "xplane_resume_sample.json")) as f:
+        planes = json.load(f)
+    with open(os.path.join(DATA, "xplane_samples_reduced.json")) as f:
+        pinned = json.load(f)["resume"]
+    path = str(tmp_path / "snap")
+    Snapshot.take(path, {"m": _Holder({"w": jnp.arange(1 << 12, dtype=jnp.float32)})})
+    spans_path = str(tmp_path / "spans.json")
+    tracing.enable(spans_path)
+    trace = xplane.DeviceTrace(str(tmp_path / "profile"), chips=1)
+    trace.start(time.monotonic())
+    Snapshot(path).restore({"m": _Holder({"w": jnp.zeros(1 << 12, jnp.float32)})})
+    trace.stop(time.monotonic())
+    tracing.disable()
+    monkeypatch.setattr(xplane, "load_xplane", lambda _: planes)
+
+    reduced = json.loads(json.dumps(trace.reduce()))
+    for key in ("busy_s", "devices_seen", "device_ops", "idle_gaps"):
+        assert reduced[key] == pinned[key], key
+    assert reduced["window_s"] == trace.window_s
+    assert reduced["busy_intervals"] == idle_by_phase.busy_intervals(planes)
+    assert [a[0] for a in reduced["anchors"]] == ["tpusnapshot.restore"]
+    obs = {"device": reduced, "spans": spans.read_spans(spans_path)}
+    parts = [_reader(name)(obs) for name in RESTORE_READERS]
+    assert all(part is not None and part >= 0 for part in parts)
+    assert sum(parts) == pytest.approx(1e3 * idle_by_phase.gap_seconds(reduced["busy_intervals"]))

@@ -19,7 +19,7 @@ import numpy as np
 import pytest
 
 from perfbench import harness, manifest, reference
-from perfbench.tests.toy import manifest_with
+from perfbench.tests.toy import manifest_of_a_later_pr, manifest_with
 
 CELL = "laguna-xs2-ep8.kill_resume"
 TOY_CELLS = {
@@ -81,8 +81,7 @@ def dirs(tmp_path):
 # ------------------------------------------------- the cell, as accepted
 
 
-def test_benchmark_resolves_the_new_cell_to_files_that_exist():
-    m = manifest.load_manifest()
+def check_the_cell_resolves_to_files_that_exist(m):
     cell = manifest.resolve_cell(m, CELL)
     assert cell.chips == 1 and cell.traffic_name == "kill_resume"
     assert cell.traffic["loop"] == "kill_resume" and cell.traffic["warm_steps"] == 3
@@ -96,6 +95,11 @@ def test_benchmark_resolves_the_new_cell_to_files_that_exist():
     assert [x["name"] for x in cell.per_layer] == [
         "restore_h2d_share", "read_busy_share", "first_step_after_restore_ms",
         "device_idle_pct.resume",
+        # the two readers of the template let go and of the device
+        # budget's waits, and the device's idle gaps by the host's stage
+        "restore_template_release_ms", "restore_device_wait_thread_s",
+        "resume_idle_h2d_ms", "resume_idle_consume_ms", "resume_idle_read_ms",
+        "resume_idle_outside_pipeline_ms",
     ]
     for path in cell.reader_paths.values():
         assert os.path.isfile(path)
@@ -105,8 +109,12 @@ def test_benchmark_resolves_the_new_cell_to_files_that_exist():
     assert files.count("perfbench/configs/laguna-xs2-ep8.json") == 1
 
 
-def test_the_configuration_keeps_every_published_key():
-    cell = manifest.resolve_cell(manifest.load_manifest(), CELL)
+def test_benchmark_resolves_the_new_cell_to_files_that_exist():
+    check_the_cell_resolves_to_files_that_exist(manifest.load_manifest())
+
+
+def check_the_configuration_keeps_every_published_key(m):
+    cell = manifest.resolve_cell(m, CELL)
     config = cell.config
     assert {k: config[k] for k in PUBLISHED_WIDTHS} == PUBLISHED_WIDTHS
     assert config["rope_parameters"] == PUBLISHED_ROPE
@@ -138,14 +146,19 @@ def test_the_configuration_keeps_every_published_key():
     assert cfg.rope_full.attention_factor == 1.4158883083359672
     assert cfg.rope_full.partial_rotary_factor == 0.5 and cfg.rope_full.factor == 64
     assert cfg.rope_sliding.factor is None and cfg.rope_sliding.theta == 10000
-    assert cfg.flash_attention and cfg.expert_capacity == 512 == 2 * 8192 * 8 // 256
+    # every seed the same work: the held experts run dense, never a data-dependent branch
+    assert cfg.flash_attention and cfg.expert_capacity == 0 and cfg.expert_dense_group == 8
 
 
-def test_the_state_is_the_one_the_cell_is_for():
+def test_the_configuration_keeps_every_published_key():
+    check_the_configuration_keeps_every_published_key(manifest.load_manifest())
+
+
+def check_the_state_is_the_one_the_cell_is_for(m):
     """Sizes from shapes alone (nothing is allocated): 692M parameters at
     14 B saved, above HBM/2, 241 leaves, a layer's leaves unlike by kind,
     the held experts as two fused stacked leaves, the largest 268 MB."""
-    cell = manifest.resolve_cell(manifest.load_manifest(), CELL)
+    cell = manifest.resolve_cell(m, CELL)
     job = manifest.load_module(cell.job_path).make_job(cell.config, jax.devices()[:1], 1)
     leaves = jax.tree.leaves(job.shapes)
     sizes = [int(np.prod(s.shape)) * np.dtype(s.dtype).itemsize for s in leaves]
@@ -175,6 +188,25 @@ def test_the_state_is_the_one_the_cell_is_for():
     assert job.shapes["params"]["embed"].shape == (12544, 2048)
     assert job.shapes["params"]["head"].shape == (2048, 12544)
     assert (job.batch, job.seq_len) == (1, 8192)
+
+
+def test_the_state_is_the_one_the_cell_is_for():
+    check_the_state_is_the_one_the_cell_is_for(manifest.load_manifest())
+
+
+# Every check above that reads the manifest, run again on a copy to
+# which a later change's configuration, cell and per-layer entry are
+# appended: none of them asks where an entry stands.
+MANIFEST_CHECKS = [
+    check_the_cell_resolves_to_files_that_exist,
+    check_the_configuration_keeps_every_published_key,
+    check_the_state_is_the_one_the_cell_is_for,
+]
+
+
+@pytest.mark.parametrize("check", MANIFEST_CHECKS, ids=lambda c: c.__name__)
+def test_every_manifest_check_holds_once_a_later_pr_has_appended(check):
+    check(manifest_of_a_later_pr())
 
 
 # ------------------------------------------------- the job's contract, toy
@@ -313,9 +345,13 @@ def test_a_traced_resume_under_a_faked_device_budget_reads_both(dirs, monkeypatc
     assert len(kept["spans"]["restore.release_template"]) == 2 * cycles
     assert _reader("restore_template_release_ms")(kept) > 0
     assert _reader("restore_device_wait_thread_s")(kept) >= 0
-    # the accepted metrics the cell lists are all there but the device's
-    named = {m["name"] for m in manifest.resolve_cell(toy_manifest(), name).per_layer}
-    assert set(crowded["metrics"]) == named - {"device_idle_pct.resume"}
+    # the metrics the cell lists are all there but the device trace's
+    # (no device plane on the CPU backend)
+    listed = manifest.resolve_cell(toy_manifest(), name).per_layer
+    named = {m["name"] for m in listed}
+    traced = {m["name"] for m in listed if m["source"] == "device_trace"}
+    assert "device_idle_pct.resume" in traced
+    assert set(crowded["metrics"]) == named - traced
 
 
 def test_the_readers_on_recorded_spans_and_on_none():

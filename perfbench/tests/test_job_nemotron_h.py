@@ -20,7 +20,7 @@ import numpy as np
 import pytest
 
 from perfbench import faults, harness, manifest, reference
-from perfbench.tests.toy import manifest_with
+from perfbench.tests.toy import manifest_of_a_later_pr, manifest_with
 
 CELL = "nemotron3-nano-30b-a3b-ep16.save_in_loop"
 TOY_CELLS = {
@@ -70,8 +70,7 @@ def dirs(tmp_path):
 # ------------------------------------------------- the cell, as accepted
 
 
-def test_benchmark_resolves_the_new_cell_to_files_that_exist():
-    m = manifest.load_manifest()
+def check_the_cell_resolves_to_files_that_exist(m):
     cell = manifest.resolve_cell(m, CELL)
     assert cell.chips == 1 and cell.traffic["loop"] == "save_in_loop"
     assert os.path.relpath(cell.job_path, manifest.CHECKOUT) == (
@@ -81,8 +80,12 @@ def test_benchmark_resolves_the_new_cell_to_files_that_exist():
     assert [x["name"] for x in cell.end_to_end] == ["loop_steps_per_s", "setup_s"]
     names = [x["name"] for x in cell.per_layer]
     like = [x["name"] for x in manifest.metrics_of(m, "per_layer", "gpt3-6.7b.save_in_loop")]
-    assert names == like + ["capture_host_stage_ms", "capture_d2h_share"]
-    assert len(like) == 12
+    own = ["capture_host_stage_ms", "capture_d2h_share"]
+    assert [n for n in names if n not in own] == like
+    assert set(names) - set(like) == set(own) and len(names) == len(like) + 2
+    # twelve, and the device's idle gaps by the host's stage on both sides
+    assert len(like) == 14
+    assert {"save_idle_staging_ms", "save_idle_outside_library_ms"} <= set(like)
     for path in cell.reader_paths.values():
         assert os.path.isfile(path)
     # one save a window, checked on the job's own layout
@@ -90,8 +93,12 @@ def test_benchmark_resolves_the_new_cell_to_files_that_exist():
     assert cell.traffic["check_layout"] is None and cell.config["mesh"] is None
 
 
-def test_the_configuration_keeps_every_published_width():
-    cell = manifest.resolve_cell(manifest.load_manifest(), CELL)
+def test_benchmark_resolves_the_new_cell_to_files_that_exist():
+    check_the_cell_resolves_to_files_that_exist(manifest.load_manifest())
+
+
+def check_the_configuration_keeps_every_published_width(m):
+    cell = manifest.resolve_cell(m, CELL)
     config = cell.config
     assert {k: config[k] for k in PUBLISHED_WIDTHS} == PUBLISHED_WIDTHS
     assert config["reduced"] == ["layers_held", "n_routed_experts", "vocab_size"]
@@ -107,11 +114,15 @@ def test_the_configuration_keeps_every_published_width():
         assert config["guarantees"] == json.load(f)["guarantees"]
 
 
-def test_the_state_is_the_one_the_cell_is_for():
+def test_the_configuration_keeps_every_published_width():
+    check_the_configuration_keeps_every_published_width(manifest.load_manifest())
+
+
+def check_the_state_is_the_one_the_cell_is_for(m):
     """Sizes from shapes alone (nothing is allocated): 667M parameters at
     14 B saved, 289 leaves, 64 of them under 1 KB, the largest a float32
     embedding moment of 176 MB, the held experts as two stacked leaves."""
-    cell = manifest.resolve_cell(manifest.load_manifest(), CELL)
+    cell = manifest.resolve_cell(m, CELL)
     job = manifest.load_module(cell.job_path).make_job(cell.config, jax.devices()[:1], 1)
     leaves = jax.tree.leaves(job.shapes)
     sizes = [int(np.prod(s.shape)) * np.dtype(s.dtype).itemsize for s in leaves]
@@ -125,6 +136,10 @@ def test_the_state_is_the_one_the_cell_is_for():
     assert experts["up"].shape == (8, 2688, 1856) and experts["down"].shape == (8, 1856, 2688)
     assert experts["router"].shape == (2688, 128)
     assert {str(s.dtype) for s in leaves} == {"bfloat16", "float32", "int32"}
+
+
+def test_the_state_is_the_one_the_cell_is_for():
+    check_the_state_is_the_one_the_cell_is_for(manifest.load_manifest())
 
 
 # ------------------------------------------------- the job's contract, toy
@@ -227,14 +242,16 @@ def test_a_traced_run_reads_the_capture_when_nothing_can_be_cloned(dirs, monkeyp
     assert staged["metrics"]["capture_fallbacks"]["value"] >= 1
     assert staged["metrics"]["capture_host_stage_ms"]["value"] > 0
     assert staged["metrics"]["capture_d2h_share"]["value"] > 0
-    named = {m["name"] for m in manifest.resolve_cell(toy_manifest(), name).per_layer}
-    assert set(staged["metrics"]) == named - {"device_idle_pct.save"}  # no device plane here
+    listed = manifest.resolve_cell(toy_manifest(), name).per_layer
+    named = {m["name"] for m in listed}
+    traced = {m["name"] for m in listed if m["source"] == "device_trace"}
+    assert "device_idle_pct.save" in traced
+    assert set(staged["metrics"]) == named - traced  # no device plane here
 
 
-def test_the_new_readers_return_nothing_where_the_program_has_no_such_span():
+def check_the_new_readers_return_nothing_where_the_program_has_no_such_span(m):
     """On the parent of this PR the span does not exist: the readers say
     None and the line leaves the metrics out."""
-    m = manifest.load_manifest()
     cell = manifest.resolve_cell(m, CELL)
     obs = {
         "saves": [{"step": 3, "blocked_s": 0.1, "durable_s": 2.0}],
@@ -251,6 +268,27 @@ def test_the_new_readers_return_nothing_where_the_program_has_no_such_span():
     read = manifest.load_module(cell.reader_paths["capture_d2h_share"]).read
     assert read(with_span) == pytest.approx(20.0)  # 2 GB/s of 10
     assert read({**with_span, "probes": None}) is None
+
+
+def test_the_new_readers_return_nothing_where_the_program_has_no_such_span():
+    m = manifest.load_manifest()
+    check_the_new_readers_return_nothing_where_the_program_has_no_such_span(m)
+
+
+# Every check above that reads the manifest, run again on a copy to
+# which a later change's configuration, cell and per-layer entry are
+# appended: none of them asks where an entry stands.
+MANIFEST_CHECKS = [
+    check_the_cell_resolves_to_files_that_exist,
+    check_the_configuration_keeps_every_published_width,
+    check_the_state_is_the_one_the_cell_is_for,
+    check_the_new_readers_return_nothing_where_the_program_has_no_such_span,
+]
+
+
+@pytest.mark.parametrize("check", MANIFEST_CHECKS, ids=lambda c: c.__name__)
+def test_every_manifest_check_holds_once_a_later_pr_has_appended(check):
+    check(manifest_of_a_later_pr())
 
 
 # ----------------------------------------------------------- the control

@@ -25,7 +25,7 @@ import numpy as np
 import pytest
 
 from perfbench import faults, harness, manifest, reference
-from perfbench.tests.toy import manifest_with
+from perfbench.tests.toy import manifest_of_a_later_pr, manifest_with
 from torchsnapshot_tpu import CheckpointManager
 
 CELL = "sdar-30b-a3b-ep8.warm_start"
@@ -85,8 +85,7 @@ def dirs(tmp_path):
 # ------------------------------------------------- the cell, as accepted
 
 
-def test_benchmark_resolves_the_new_cell_to_files_that_exist():
-    m = manifest.load_manifest()
+def check_the_cell_resolves_to_files_that_exist(m):
     cell = manifest.resolve_cell(m, CELL)
     assert cell.chips == 1 and cell.traffic_name == "warm_start"
     assert cell.traffic["loop"] == "warm_start" and cell.traffic["warm_steps"] == 3
@@ -103,6 +102,11 @@ def test_benchmark_resolves_the_new_cell_to_files_that_exist():
     # where this cell lands a part; PR 30's seven stay with the GPT-3 cell
     assert [x["name"] for x in cell.per_layer] == [
         "read_busy_share", "first_step_after_restore_ms", "device_idle_pct.resume",
+        # the two readers of what this loop records, and the device's
+        # idle gaps by the host's stage
+        "restore_selected_h2d_share", "fresh_state_ms",
+        "resume_idle_h2d_ms", "resume_idle_consume_ms", "resume_idle_read_ms",
+        "resume_idle_outside_pipeline_ms",
     ]
     for path in cell.reader_paths.values():
         assert os.path.isfile(path)
@@ -112,14 +116,18 @@ def test_benchmark_resolves_the_new_cell_to_files_that_exist():
     assert cell.config["job"] == "mixed_adamw" and cell.config["model"] == "sdar"
     files = [c["file"] for c in m["configs"]]
     assert files.count("perfbench/configs/sdar-30b-a3b-ep8.json") == 1
-    # and the entries stand at the end of their lists
-    assert m["configs"][-1]["name"] == "sdar-30b-a3b-ep8"
-    assert m["workloads"][-1]["name"] == CELL
-    assert m["configs"][-1]["source"] == cell.config["source"]
+    # the configuration and the cell stand once each, wherever they stand
+    (config,) = [c for c in m["configs"] if c["name"] == "sdar-30b-a3b-ep8"]
+    assert [w["name"] for w in m["workloads"]].count(CELL) == 1
+    assert config["source"] == cell.config["source"]
 
 
-def test_the_configuration_keeps_every_published_key():
-    cell = manifest.resolve_cell(manifest.load_manifest(), CELL)
+def test_benchmark_resolves_the_new_cell_to_files_that_exist():
+    check_the_cell_resolves_to_files_that_exist(manifest.load_manifest())
+
+
+def check_the_configuration_keeps_every_published_key(m):
+    cell = manifest.resolve_cell(m, CELL)
     config = cell.config
     reduced = {"num_experts": 16, "vocab_size": 18992}
     assert {k: config[k] for k in PUBLISHED} == {**PUBLISHED, **reduced}
@@ -155,12 +163,16 @@ def test_the_configuration_keeps_every_published_key():
     assert cfg.expert_dense_group == 8 and cfg.remat
 
 
-def test_the_state_is_the_one_the_cell_is_for():
+def test_the_configuration_keeps_every_published_key():
+    check_the_configuration_keeps_every_published_key(manifest.load_manifest())
+
+
+def check_the_state_is_the_one_the_cell_is_for(m):
     """Sizes from shapes alone (nothing is allocated): 645.6M parameters
     at 14 B saved, above HBM/2, 277 leaves, of which the ``model``
     Stateful (what the cell restores) holds 138 and 3.87 GB; the held
     experts as two stacked leaves a layer, the largest 201 MB."""
-    cell = manifest.resolve_cell(manifest.load_manifest(), CELL)
+    cell = manifest.resolve_cell(m, CELL)
     job = manifest.load_module(cell.job_path).make_job(cell.config, jax.devices()[:1], 1)
     leaves = jax.tree.leaves(job.shapes)
     nbytes = lambda s: int(np.prod(s.shape)) * np.dtype(s.dtype).itemsize
@@ -200,6 +212,25 @@ def test_the_state_is_the_one_the_cell_is_for():
     assert job.shapes["params"]["embed"].shape == (18992, 2048)
     assert job.shapes["params"]["head"].shape == (2048, 18992)
     assert (job.batch, job.seq_len) == (1, 4096)  # 2 x 4096 positions a step
+
+
+def test_the_state_is_the_one_the_cell_is_for():
+    check_the_state_is_the_one_the_cell_is_for(manifest.load_manifest())
+
+
+# Every check above that reads the manifest, run again on a copy to
+# which a later change's configuration, cell and per-layer entry are
+# appended: none of them asks where an entry stands.
+MANIFEST_CHECKS = [
+    check_the_cell_resolves_to_files_that_exist,
+    check_the_configuration_keeps_every_published_key,
+    check_the_state_is_the_one_the_cell_is_for,
+]
+
+
+@pytest.mark.parametrize("check", MANIFEST_CHECKS, ids=lambda c: c.__name__)
+def test_every_manifest_check_holds_once_a_later_pr_has_appended(check):
+    check(manifest_of_a_later_pr())
 
 
 # ------------------------------------------------- the job's contract, toy
@@ -555,26 +586,13 @@ def test_the_readers_on_recorded_observations_and_on_none():
 
 
 def test_a_traced_run_reads_both_through_a_manifest_that_lists_them(dirs):
-    """The two readers have no entry in ``BENCHMARK.json``
-    (``test_phase_layers.py`` pins its last 13 per-layer names): listed
-    in a copy, the harness finds them by name and a traced run of the
-    toy cell reports both beside the accepted metrics."""
-    m = toy_manifest()
-    for name, unit, better in (
-        ("restore_selected_h2d_share", "%", "higher"), ("fresh_state_ms", "ms", "lower"),
-    ):
-        m["per_layer"].append(
-            {"name": name, "unit": unit, "better": better, "source": "host_clock",
-             "layer": "restore consume + H2D", "moves": "resume_s",
-             "workloads": ["toy-sdar.warm_start"]}
-        )
-    line = run_toy("toy-sdar.warm_start", dirs, trace=True, m=m)
+    """The two readers' entries in ``BENCHMARK.json`` list the cell: the
+    harness finds them by name and a traced run of the toy cell reports
+    both beside the accepted metrics."""
+    line = run_toy("toy-sdar.warm_start", dirs, trace=True)
     assert line["correct"] is True, line
     assert set(line["metrics"]) == {
         "read_busy_share", "first_step_after_restore_ms",
         "restore_selected_h2d_share", "fresh_state_ms",
-    }  # all but the device's: the CPU backend has no device plane
+    }  # all but the device trace's: the CPU backend has no device plane
     assert all(v["value"] > 0 for v in line["metrics"].values())
-    # and without the entries a traced run reports the accepted three less one
-    plain = run_toy("toy-sdar.warm_start", dirs, trace=True)
-    assert set(plain["metrics"]) == {"read_busy_share", "first_step_after_restore_ms"}

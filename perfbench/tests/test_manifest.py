@@ -11,6 +11,7 @@ from perfbench import manifest
 from perfbench.tests.toy import (
     CELLS_OF_A_LATER_PR,
     TOY_CELLS,
+    manifest_of_a_later_pr,
     manifest_with,
     toy_job,
     toy_manifest,
@@ -29,14 +30,22 @@ ACCEPTED_CONFIGS = ("gpt3-6.7b", "gpt3-6.7b-tp4")
 GPT3_6_7B_WIDTHS = {"d_model": 4096, "n_heads": 32, "d_head": 128, "d_ff": 16384, "max_seq_len": 2048}
 
 
-@pytest.fixture(scope="module", params=["accepted", "with_a_later_prs_cells"])
+@pytest.fixture(
+    scope="module",
+    params=["accepted", "with_a_later_prs_cells", "with_a_later_prs_entries"],
+)
 def m(request):
-    """Every check on the manifest runs twice: on ``BENCHMARK.json`` as it
-    is, and on a copy that holds what a later ``model_config`` PR adds (a
-    configuration naming a job of its own, with two cells), so that no
-    check here has to be edited when such a PR comes."""
+    """Every check on the manifest runs three times: on ``BENCHMARK.json``
+    as it is, on a copy that holds what a later configuration brings (a
+    configuration naming a job of its own, with two cells), and on a
+    copy with one configuration, one cell, that cell's name in an
+    end-to-end list and one per-layer entry appended at the ends of the
+    lists, so that no check here has to be edited when such entries
+    come."""
     if request.param == "accepted":
         return manifest.load_manifest()
+    if request.param == "with_a_later_prs_entries":
+        return manifest_of_a_later_pr()
     return manifest_with(CELLS_OF_A_LATER_PR)
 
 

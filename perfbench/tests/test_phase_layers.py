@@ -16,7 +16,7 @@ import time
 import pytest
 
 from perfbench import harness, manifest, phase_spans
-from perfbench.tests.toy import toy_manifest
+from perfbench.tests.toy import manifest_of_a_later_pr, toy_manifest
 
 STAGE_METRICS = [
     "capture_clone_ms",
@@ -173,11 +173,13 @@ def test_thread_seconds_count_every_thread_and_the_union_counts_the_clock():
     assert read(obs) == 1.5
 
 
-def test_the_new_entries_stand_at_the_end_and_name_their_cells():
-    m = manifest.load_manifest()
+def check_the_entries_name_their_cells(m):
+    """Each of the thirteen entries found by name, once, wherever it
+    stands in ``per_layer``."""
     names = [x["name"] for x in m["per_layer"]]
-    assert names[-13:] == STAGE_METRICS + RESTORE_METRICS
-    for x in m["per_layer"][-13:]:
+    for name in STAGE_METRICS + RESTORE_METRICS:
+        assert names.count(name) == 1, name
+    for x in [x for x in m["per_layer"] if x["name"] in STAGE_METRICS + RESTORE_METRICS]:
         assert x["source"] == "program_span"
         if x["name"] in STAGE_METRICS:
             assert x["workloads"] == ["gpt3-6.7b-tp4.save_in_loop"]
@@ -185,3 +187,13 @@ def test_the_new_entries_stand_at_the_end_and_name_their_cells():
         else:
             assert x["workloads"] == ["gpt3-6.7b.kill_resume"]
             assert x["moves"] == "resume_s"
+
+
+def test_the_new_entries_stand_at_the_end_and_name_their_cells():
+    check_the_entries_name_their_cells(manifest.load_manifest())
+
+
+# The check above, run again on a copy to which a later change's
+# configuration, cell and per-layer entry are appended.
+def test_every_manifest_check_holds_once_a_later_pr_has_appended():
+    check_the_entries_name_their_cells(manifest_of_a_later_pr())

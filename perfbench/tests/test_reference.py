@@ -43,6 +43,47 @@ def test_sharded_leaves_sum_to_the_same():
         np.testing.assert_array_equal(np.asarray(fn([placed])), plain)
 
 
+@pytest.mark.parametrize(
+    "layout,spec,copies",
+    [({"dp": 2, "tp": 2}, P(None, "tp"), 2), ({"dp": 2, "tp": 2}, P("tp", "dp"), 1),
+     ({"dp": 2, "tp": 2}, P(), 4), ({"dp": 1, "tp": 4}, P("tp", None), 1)],
+)
+def test_every_copy_sums_to_the_whole_leaf(layout, spec, copies):
+    """Each copy of a leaf, its blocks summed where they lie, gives the
+    numpy sums of the whole leaf: a leaf on dp2 x tp2 sharded over tp
+    alone has two copies, one replicated over the mesh four."""
+    mesh = make_mesh(layout)
+    x = np.random.default_rng(2).standard_normal((24, 8)).astype(np.float32)
+    h = np.arange(6, dtype=np.int32)
+    placed = [jax.device_put(x, NamedSharding(mesh, spec)), jnp.asarray(h)]
+    got = reference.copy_sums(reference.make_copy_checksum_fn()(placed))
+    want = reference.checksums_numpy([x, h])
+    assert reference.differing_copies(reference.leaf_names([x, h]), want, got) == []
+    assert sorted(r for leaf, r in got if leaf == 0) == list(range(copies))
+    assert sorted(r for leaf, r in got if leaf == 1) == [0]
+
+
+def test_a_second_copy_that_differs_is_named():
+    """The blocks of the second copy (dp = 1) zeroed, the first left
+    right: that copy, and it alone, differs, with the devices that hold
+    it."""
+    mesh = make_mesh({"dp": 2, "tp": 2})
+    x = np.random.default_rng(3).standard_normal((16, 8)).astype(np.float32)
+    placed = jax.device_put(x, NamedSharding(mesh, P(None, "tp")))
+    blocks = [
+        s.data if s.replica_id == 0 else jnp.zeros_like(s.data)
+        for s in placed.addressable_shards
+    ]
+    broken = jax.make_array_from_single_device_arrays(x.shape, placed.sharding, blocks)
+    got = reference.copy_sums(reference.make_copy_checksum_fn()([broken]))
+    differing = reference.differing_copies(
+        ["x"], reference.checksums_numpy([x]), got
+    )
+    second = sorted(s.device.id for s in placed.addressable_shards if s.replica_id == 1)
+    assert [(d["leaf"], d["copy"], d["devices"]) for d in differing] == [("x", 1, second)]
+    assert reference.differing_copies(["x", "y"], reference.checksums_numpy([x, x]), got)
+
+
 @pytest.mark.parametrize("change", ["bit", "swap", "bf16"])
 def test_sums_notice(change):
     t = tree()

@@ -28,6 +28,13 @@ TOY_CELLS = {
         4,
         "gpt3-6.7b.save_in_loop",
     ),
+    # A restore onto another layout: saved on dp1 x tp4, restored on dp2 x tp2.
+    "toy-tp4.reshard_resume": (
+        "toy-tp4",
+        "toy_reshard_resume",
+        4,
+        "gpt3-6.7b-tp4.reshard_resume",
+    ),
 }
 
 
@@ -69,6 +76,46 @@ def manifest_with(cells) -> dict:
             for metric in m[section]:
                 if like in metric.get("workloads", ()):
                     metric["workloads"].append(name)
+    return m
+
+
+# What a later change appends to each list of ``BENCHMARK.json``: a
+# configuration (``data/configs/toy-appended.json``, a copy of a toy
+# file), one cell on a job and a loop kind that are there, that cell's
+# name in an end-to-end metric's list, and a per-layer entry naming it.
+# Every check of the accepted entries has to hold on this copy as on the
+# manifest itself: none may ask where an entry stands.
+APPENDED_CELL = "toy-appended.kill_resume"
+
+
+def manifest_of_a_later_pr() -> dict:
+    m = copy.deepcopy(manifest.load_manifest())
+    m["paths"].append(DATA)
+    m["configs"].append(
+        {
+            "name": "toy-appended",
+            "source": "none: a toy for the tests",
+            "file": f"{DATA}/configs/toy-appended.json",
+            "reduced": [],
+            "why": "an entry appended after every accepted one",
+        }
+    )
+    m["workloads"].append(
+        {
+            "name": APPENDED_CELL,
+            "config": "toy-appended",
+            "traffic": "toy_kill_resume",
+            "chips": 1,
+            "why": "toy",
+        }
+    )
+    (resume,) = [x for x in m["end_to_end"] if x["name"] == "resume_s"]
+    resume["workloads"].append(APPENDED_CELL)
+    m["per_layer"].append(
+        {"name": "toy_reader", "unit": "count", "better": "lower",
+         "source": "program_counter", "layer": "device", "moves": "resume_s",
+         "workloads": [APPENDED_CELL]}
+    )
     return m
 
 

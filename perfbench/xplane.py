@@ -186,7 +186,15 @@ class DeviceTrace:
         path = find_xplane(self.log_dir)
         if path is None:
             return None
-        reduced = reduce_planes(load_xplane(path), self.chips)
+        planes = load_xplane(path)
+        reduced = reduce_planes(planes, self.chips)
         if reduced is not None:
+            from perfbench import idle_by_phase  # it imports this module
+
             reduced["window_s"] = self.window_s
+            # What the idle-by-phase readers read besides: the first
+            # device's busy intervals and the program's anchors.
+            reduced.update(
+                idle_by_phase.device_keys(planes, idle_by_phase.load_anchors(path))
+            )
         return reduced
