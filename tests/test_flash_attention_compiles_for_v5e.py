@@ -5,7 +5,8 @@ may ask for). Forward and both backward kernels, 8192 positions in
 1024-row tiles, head 128, grouped-query: the sliding layers' 64 heads
 over 8 under a window of 512, the full layers' 48 over 8 plain causal;
 and as the SDAR cell runs them: 32 heads over 4 under the block-diffusion
-mask of two halves of 4096 in blocks of 4.
+mask of two halves of 4096 in blocks of 4; and as the LFM2 cell runs
+them: 32 heads over 8 at a head width of 64, plain causal.
 
 Nothing runs: a compile that passes is no chip run. Skipped where no
 such topology can be described. The topology is described inside a
@@ -38,22 +39,23 @@ def one_chip():
 
 
 @pytest.mark.parametrize(
-    "heads,kv_heads,mask",
+    "heads,kv_heads,mask,head",
     [
-        (64, 8, {"window": 512}),
-        (48, 8, {}),
-        (32, 4, {"diffusion": (SEQ // 2, 4)}),
-        (32, 4, {"diffusion": (SEQ // 2, 3)}),  # a block that is no shift
+        (64, 8, {"window": 512}, HEAD),
+        (48, 8, {}, HEAD),
+        (32, 4, {"diffusion": (SEQ // 2, 4)}, HEAD),
+        (32, 4, {"diffusion": (SEQ // 2, 3)}, HEAD),  # a block that is no shift
+        (32, 8, {}, 64),
     ],
     ids=["sliding_64_heads", "full_48_heads", "block_diffusion_32_heads",
-         "block_diffusion_block_of_3"],
+         "block_diffusion_block_of_3", "full_32_heads_of_64"],
 )
 def test_forward_and_backward_kernels_compile_at_the_cell_s_widths(
-    one_chip, heads, kv_heads, mask
+    one_chip, heads, kv_heads, mask, head
 ):
     block = resolve_flash_block(SEQ)
     assert block == 1024
-    spec = lambda h, d=HEAD, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+    spec = lambda h, d=head, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
         (1, h, SEQ, d), dtype, sharding=one_chip
     )
     q, kv = spec(heads), spec(kv_heads)

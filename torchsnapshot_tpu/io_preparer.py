@@ -38,7 +38,7 @@ import os
 import threading
 import time
 from concurrent.futures import Executor
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -525,6 +525,110 @@ class ArrayBufferStager(BufferStager):
         return self._nbytes
 
 
+class CloneFit(NamedTuple):
+    """Whether an async take's device clones fit beside what the process
+    itself needs of the device, on the device where they fit worst.
+
+    The need is the device's ``peak_bytes_in_use``: after a loop's first
+    steps it holds the state and a step's temporaries, so the clones fit
+    only where that peak, the clones and one more copy of the device's
+    largest shard (the margin :func:`template_crowds_device` keeps) lie
+    within ``bytes_limit``. A clone that merely allocates leaves the
+    next step without its temporaries. The library's own earlier clones
+    are not counted in the peak (:func:`clones_released`). ``fits`` is
+    None where a device reports no memory, or there is nothing on a
+    device to clone."""
+
+    fits: Optional[bool]
+    clone_bytes: int  # on the device where the clones fit worst
+    peak_bytes: Optional[int]  # the need there: its peak, own clones discounted
+    limit_bytes: Optional[int]
+    # (device, clone bytes, need) of every device, for clones_released
+    devices: Tuple[Tuple[Any, int, int], ...] = ()
+
+
+class _CloneMark(NamedTuple):
+    peak_bytes: int  # the device's peak when a take let its clones go
+    clone_bytes: int
+    need_bytes: int  # what that take's fit test counted as the need
+
+
+# A device's peak is a high-water mark with no reset: once a take's clones
+# lived there beside a step, the peak holds them. Each device's last such
+# take leaves its mark here, and a later fit test that reads the same peak
+# counts the need as the larger of that take's need and the peak less its
+# clones. A peak other than the mark rose after the clones were gone (or
+# the device's count began again): it is the process's own, and counts
+# whole.
+_clone_marks: Dict[Any, _CloneMark] = {}
+_clone_marks_lock = threading.Lock()
+
+
+def _device_sources(write_reqs: List[WriteReq]) -> Dict[int, Any]:
+    """The device arrays the array stagers hold, once each by identity (a
+    chunked array shares one source across its chunk stagers)."""
+    sources: Dict[int, Any] = {}
+    for wr in write_reqs:
+        stager = wr.buffer_stager
+        if isinstance(stager, ArrayBufferStager) and _is_jax_array(stager._data):
+            sources.setdefault(id(stager._data), stager._data)
+    return sources
+
+
+def _peak_and_limit(device: Any) -> Optional[Tuple[int, int]]:
+    try:
+        stats = device.memory_stats() or {}
+    except Exception:  # snapcheck: disable=swallowed-exception -- capability probe
+        return None
+    if "peak_bytes_in_use" not in stats or not stats.get("bytes_limit"):
+        return None
+    return int(stats["peak_bytes_in_use"]), int(stats["bytes_limit"])
+
+
+def _need(device: Any, peak: int) -> int:
+    with _clone_marks_lock:
+        mark = _clone_marks.get(device)
+    if mark is None or peak != mark.peak_bytes:
+        return peak
+    return max(mark.need_bytes, peak - mark.clone_bytes)
+
+
+def clone_fit(write_reqs: List[WriteReq]) -> CloneFit:
+    """:class:`CloneFit` of cloning every device array of ``write_reqs``."""
+    incoming: Dict[Any, int] = {}
+    largest: Dict[Any, int] = {}
+    for data in _device_sources(write_reqs).values():
+        for shard in data.addressable_shards:
+            nbytes = int(shard.data.nbytes)
+            incoming[shard.device] = incoming.get(shard.device, 0) + nbytes
+            largest[shard.device] = max(largest.get(shard.device, 0), nbytes)
+    readings = []  # (headroom, fits, clone bytes, need, limit) a device
+    devices = []
+    for device, nbytes in incoming.items():
+        memory = _peak_and_limit(device)
+        if memory is None:
+            return CloneFit(None, max(incoming.values()), None, None)
+        need, limit = _need(device, memory[0]), memory[1]
+        fits = need + nbytes + largest[device] <= limit
+        readings.append((limit - need - nbytes, fits, nbytes, need, limit))
+        devices.append((device, nbytes, need))
+    if not readings:
+        return CloneFit(None, 0, None, None)
+    _, _, nbytes, need, limit = min(readings, key=lambda r: r[0])
+    return CloneFit(all(r[1] for r in readings), nbytes, need, limit, tuple(devices))
+
+
+def clones_released(fit: CloneFit) -> None:
+    """Mark each device's peak once a take that cloned by ``fit`` has let
+    its clones go (its drain ended), so that later fit tests do not count
+    them as the process's need."""
+    for device, nbytes, need in fit.devices:
+        memory = _peak_and_limit(device)
+        if memory is not None:
+            with _clone_marks_lock:
+                _clone_marks[device] = _CloneMark(memory[0], nbytes, need)
+
+
 def device_clone_write_reqs(write_reqs: List[WriteReq]) -> bool:
     """Rebind every array stager to a private on-device copy of its data.
 
@@ -540,7 +644,7 @@ def device_clone_write_reqs(write_reqs: List[WriteReq]) -> bool:
     Returns False — with all partial clones released — if the device ran
     out of memory; the caller falls back to host staging.
     """
-    sources: Dict[int, Any] = {}
+    sources = _device_sources(write_reqs)
     rebinds: List[Tuple[ArrayBufferStager, int]] = []
     host_copies: Dict[int, Any] = {}
     for wr in write_reqs:
@@ -549,7 +653,6 @@ def device_clone_write_reqs(write_reqs: List[WriteReq]) -> bool:
             continue
         data = stager._data
         if _is_jax_array(data):
-            sources.setdefault(id(data), data)
             rebinds.append((stager, id(data)))
         elif isinstance(data, np.ndarray):
             # Dedupe by identity: a chunked dense array shares ONE

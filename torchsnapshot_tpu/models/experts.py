@@ -1,6 +1,7 @@
 """One chip's share of a mixture-of-experts layer: the router over all
 of the layer's experts and the part of the result that the experts held
-here give. What ``nemotron_h.py``, ``laguna.py`` and ``sdar.py`` run.
+here give. What ``nemotron_h.py``, ``laguna.py``, ``sdar.py`` and
+``lfm2_moe.py`` run.
 
 The layer is told which experts it holds (:class:`Routing`), scores
 every token over all of them at the router's published width (by
@@ -57,18 +58,26 @@ class Routing:
             raise ValueError(f"scoring is sigmoid or softmax, not {self.scoring!r}")
 
 
-def held_gates(x, router, bias, routing: Routing):
-    """``[tokens, held experts]`` float32: the weight with which each
-    held expert's result enters each token, 0 where the token's top k
-    (over all the router's experts) does not name it; and, as booleans,
-    which tokens are routed to which held expert. ``bias`` (or None) is
-    added to the scores for the choice alone."""
+def _choose(x, router, bias, routing: Routing):
+    """Every token's scores over all the router's experts, and the ids
+    of its top k: of the scores, or of the scores plus ``bias`` (or
+    None), which enters the choice alone."""
     score = jax.nn.softmax if routing.scoring == "softmax" else jax.nn.sigmoid
     scores = score(
         jnp.einsum("td,de->te", x.astype(_F32), router.astype(_F32))
     )
     choice = scores if bias is None else scores + bias.astype(_F32)
     _, chosen = jax.lax.top_k(choice, routing.top_k)
+    return scores, chosen
+
+
+def held_gates(x, router, bias, routing: Routing):
+    """``[tokens, held experts]`` float32: the weight with which each
+    held expert's result enters each token, 0 where the token's top k
+    (over all the router's experts) does not name it; and, as booleans,
+    which tokens are routed to which held expert. ``bias`` (or None) is
+    added to the scores for the choice alone."""
+    scores, chosen = _choose(x, router, bias, routing)
     weights = jnp.take_along_axis(scores, chosen, axis=-1)
     if routing.normalise:
         weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
@@ -76,6 +85,16 @@ def held_gates(x, router, bias, routing: Routing):
     held = jnp.asarray(routing.expert_ids, chosen.dtype)
     hit = chosen[:, :, None] == held[None, None, :]  # [T, K, held]
     return jnp.sum(jnp.where(hit, weights[:, :, None], 0.0), axis=1), jnp.any(hit, axis=1)
+
+
+def expert_loads(x, router, bias, routing: Routing):
+    """``int32 [the router's experts]``: for each, held here or not, the
+    tokens of ``x`` whose top k chose it; the choice :func:`held_gates`
+    makes."""
+    _, chosen = _choose(x, router, bias, routing)
+    return jnp.sum(
+        jax.nn.one_hot(chosen, router.shape[1], dtype=jnp.int32), axis=(0, 1)
+    )
 
 
 def experts_dense(x, gates, hidden: Callable, down, group: int = 0):

@@ -267,14 +267,15 @@ def ssd_chunked(x, dt, a, b, c, chunk):
     return y.reshape(batch, chunks * chunk, heads, p)[:, :t]
 
 
-def causal_conv1d(x, weight, bias):
+def causal_conv1d(x, weight, bias=None):
     """Depthwise causal convolution over time: ``out_t = sum_j w_j
-    x_{t-(K-1)+j} + bias``. ``x`` [B, T, C], ``weight`` [K, C]."""
+    x_{t-(K-1)+j} + bias`` (no bias where None). ``x`` [B, T, C],
+    ``weight`` [K, C]."""
     k = weight.shape[0]
     t = x.shape[1]
     padded = jnp.pad(x, [(0, 0), (k - 1, 0), (0, 0)])
     out = sum(padded[:, j : j + t] * weight[j] for j in range(k))
-    return out + bias
+    return out if bias is None else out + bias
 
 
 def mamba_mixer(x, blk, config: NemotronHConfig):

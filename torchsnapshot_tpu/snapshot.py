@@ -60,6 +60,9 @@ from .telemetry import report as flight
 from .flatten import flatten, inflate
 from .io_preparer import (
     ArrayBufferStager,
+    CloneFit,
+    clone_fit,
+    clones_released,
     device_clone_write_reqs,
     device_peak_bytes,
     forget_device_templates,
@@ -662,19 +665,24 @@ class Snapshot:
             watch.set_phase("prestage")
             try:
                 with recorder.phase("prestage"):
-                    staged_bytes = _prestage_write_reqs(
+                    staged_bytes, fit = _prestage_write_reqs(
                         pending_write_reqs,
                         budget,
                         stage=stage,
                         coordinator=coordinator,
                     )
-                # The route the cut was captured by, as a field of the
-                # take's report: no reader has to count a warning.
+                # The route the cut was captured by, and what decided
+                # it, as fields of the take's report: no reader has to
+                # count a warning.
                 recorder.note(
                     capture_route="host_staging"
                     if staged_bytes is not None
                     else "device_clones",
                     capture_host_staged_bytes=staged_bytes or 0,
+                    capture_clones_fit=fit.fits if fit else None,
+                    capture_clone_bytes=fit.clone_bytes if fit else None,
+                    capture_device_peak_bytes=fit.peak_bytes if fit else None,
+                    capture_device_limit_bytes=fit.limit_bytes if fit else None,
                 )
             except BaseException:
                 # Failures before the drain thread exists must still
@@ -772,6 +780,9 @@ class Snapshot:
                     # until it ages out).
                     if chunk_ctx is not None:
                         chunk_ctx.cleanup()
+                    if staged_bytes is None and fit is not None:
+                        # The clones are gone with the drain's stagers.
+                        clones_released(fit)
 
             try:
                 background.start(_drain)
@@ -2852,16 +2863,28 @@ async def _wait_for_metadata(
         delay = min(delay * 2, 1.0)
 
 
+def _note_capture_fit(fit: CloneFit) -> None:
+    """The decision's inputs as gauges of the metrics registry (0 where
+    the device reports no memory), beside the take report's fields."""
+    for name, value in (
+        (_metric_names.CAPTURE_CLONE_BYTES, fit.clone_bytes),
+        (_metric_names.CAPTURE_DEVICE_PEAK_BYTES, fit.peak_bytes),
+        (_metric_names.CAPTURE_DEVICE_LIMIT_BYTES, fit.limit_bytes),
+    ):
+        telemetry.gauge(name).set(value or 0)
+
+
 def _prestage_write_reqs(
     write_reqs: List[WriteReq],
     budget: int,
     stage: str = "auto",
     coordinator: Optional[Coordinator] = None,
-) -> Optional[int]:
+) -> Tuple[Optional[int], Optional[CloneFit]]:
     """Capture async take's consistent cut (device clones or host staging).
 
     Returns None when the cut is held by device clones, else the bytes
-    staged to the host before returning.
+    staged to the host before returning; and the fit test's
+    :class:`~.io_preparer.CloneFit` (None for ``stage="host"``).
 
     Device mode rebinds array stagers to on-device clones — the stall is
     one HBM copy, and the background drain stages from the clones (each
@@ -2869,6 +2892,13 @@ def _prestage_write_reqs(
     eagerly stages every buffer to host: concurrency is bounded by the
     staging thread pool; total retained host memory necessarily equals the
     per-process checkpoint size.
+
+    The clones are attempted only where they fit beside the process's
+    own peak (``io_preparer.clone_fit``): clones that merely allocate
+    would leave the next training step without room for its
+    temporaries. Where they do not fit, the take stages to the host
+    without the attempt; where the device reports no memory, it attempts
+    them and falls back if they run out of memory.
 
     The device-vs-host decision is *collective*: HBM pressure is
     rank-local, and a rank falling back (or raising) unilaterally between
@@ -2880,18 +2910,30 @@ def _prestage_write_reqs(
     """
     coordinator = get_coordinator(coordinator)
     cloned = False
+    fit = None
     if stage != "host":
-        # The attempt, kept or not: a capture that cannot clone pays for
-        # the clones that filled the device before it falls back.
-        clone_t0 = time.monotonic()
-        with tracing.span("capture.clone"):
-            cloned = device_clone_write_reqs(write_reqs)
-        profile = _phase_profile.current("stage")
-        if profile is not None:
-            profile.note("clone", time.monotonic() - clone_t0)
+        with tracing.span("capture.fit"):
+            fit = clone_fit(write_reqs)
+        _note_capture_fit(fit)
+        if fit.fits is not False:
+            # The attempt, kept or not: a capture that cannot clone pays
+            # for the clones that filled the device before it falls back.
+            clone_t0 = time.monotonic()
+            with tracing.span("capture.clone"):
+                cloned = device_clone_write_reqs(write_reqs)
+            profile = _phase_profile.current("stage")
+            if profile is not None:
+                profile.note("clone", time.monotonic() - clone_t0)
+        elif stage != "device":  # which raises below, collectively
+            logger.warning(
+                f"Device-staged snapshot does not fit beside the process's "
+                f"peak ({fit.clone_bytes} B of clones on {fit.peak_bytes} B "
+                f"of peak, limit {fit.limit_bytes} B); falling back to host "
+                f"staging."
+            )
     all_cloned = all(coordinator.all_gather_object(cloned))
     if all_cloned and stage != "host":
-        return None
+        return None, fit
     if stage == "device":
         # Collective raise: every rank saw the same gather and raises.
         raise RuntimeError(
@@ -2939,7 +2981,7 @@ def _prestage_write_reqs(
 
     with tracing.span("capture_host_stage", bytes=total):
         asyncio.run(_stage_all())
-    return total
+    return total, fit
 
 
 def _note_stage_phases(recorder: Any, profile: Any) -> None:

@@ -323,6 +323,11 @@ MANAGER_STEP_MARKER_SECONDS = "tpusnapshot_manager_step_marker_seconds"  # hist
 MANAGER_PRUNE_SECONDS = "tpusnapshot_manager_prune_seconds"  # hist
 MANAGER_STEPS_PRUNED = "tpusnapshot_manager_steps_pruned_total"  # counter
 TAKES_TOTAL = "tpusnapshot_takes_total"  # counter {mode}
+# An async take's capture decision (io_preparer.clone_fit), on the device
+# where the clones fit worst; 0 where the device reports no memory.
+CAPTURE_CLONE_BYTES = "tpusnapshot_capture_clone_bytes"  # gauge
+CAPTURE_DEVICE_PEAK_BYTES = "tpusnapshot_capture_device_peak_bytes"  # gauge
+CAPTURE_DEVICE_LIMIT_BYTES = "tpusnapshot_capture_device_limit_bytes"  # gauge
 RESTORES_TOTAL = "tpusnapshot_restores_total"  # counter
 GOODPUT_TRAIN_SECONDS = (
     "tpusnapshot_goodput_train_seconds_total"  # counter
